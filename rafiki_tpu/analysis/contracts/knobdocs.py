@@ -57,10 +57,8 @@ KNOB_DOCS = {
         "init after this many seconds",
     "RAFIKI_BENCH_DEADLINE_S": "bench.py wall-clock budget before the "
         "run is declared hung",
-    "RAFIKI_BENCH_PLATFORM": "force the bench platform (cpu/tpu) "
-        "instead of auto-detecting",
-    "RAFIKI_BENCH_SELFTEST_DEGRADED": "bench self-test hook: report a "
-        "degraded run (CI polarity check)",
+    "RAFIKI_BENCH_PLATFORM": "cpu = run the bench at the CPU smoke "
+        "scale; otherwise it needs a tpu device",
     "RAFIKI_BENCH_SELFTEST_FAIL": "bench self-test hook: fail "
         "deliberately (CI polarity check)",
     "RAFIKI_BENCH_SELFTEST_SLEEP_S": "bench self-test hook: sleep to "
@@ -206,8 +204,6 @@ KNOB_DOCS = {
         "the spawned worker",
     "RAFIKI_WORKER_SUB_JOB_ID": "sub-train-job the spawned worker "
         "executes",
-    "RAFIKI_XLA_CACHE_DIR": "XLA compilation cache directory "
-        "(docs/compile_cache.md)",
     "RAFIKI_XLA_CACHE_MIN_S": "minimum compile time before a program "
         "is worth caching",
 }

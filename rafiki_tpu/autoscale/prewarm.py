@@ -1,7 +1,7 @@
 """Compiled-pack pre-warming at job admission (docs/autoscale.md).
 
-BENCH_r02 puts the scale-up fixed cost in one number: ``compile_s=12.8``
-against ``canonical_trial_s=2.94`` — a cold scale-up spends 4× a
+The one round-2 chip datapoint puts the scale-up fixed cost in one
+number: ``compile_s=12.8`` against ``canonical_trial_s=2.94`` — a cold scale-up spends 4× a
 trial's work on XLA before doing anything. This module moves that cost
 to ADMISSION time: group a job's proposals by ``packing_key``, build
 each bucket's :class:`~rafiki_tpu.ops.train.PackedTrainLoop` once
@@ -66,7 +66,7 @@ def prewarm_models(model_cls: type, knobs_list: Sequence[Dict[str, Any]],
     if persist:
         # Cross-process half: compiled executables land in the
         # persistent XLA dir so a fresh worker process skips the
-        # compile too (RAFIKI_XLA_CACHE_DIR).
+        # compile too (JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache).
         enable_compilation_cache()
     from rafiki_tpu.ops.train import PackedTrainLoop
 

@@ -335,6 +335,43 @@ def make_mp_bus(manager=None):
     return _MpBus(manager)
 
 
+class _LeasedLock:
+    """A ``manager.Lock`` that survives its holder being SIGKILLed.
+
+    A process killed inside a ``with lock:`` section never releases, and
+    every other process then blocks in ``acquire`` forever — the whole
+    serving plane wedged by one dead worker (and, in the suite, a test
+    that SIGKILLs a worker hung until the run was cut). Every section of
+    the bus is a handful of manager round-trips (milliseconds; the chaos
+    delays sit outside the lock), so a lock still held after ``LEASE_S``
+    belongs to a dead process and the waiter breaks it. Two waiters
+    whose leases expire within one round-trip of each other can both
+    release; the cost is one transiently unguarded copy-on-write update
+    (at worst a lost beat or query, which leases and gather deadlines
+    already absorb), against a permanent wedge.
+    """
+
+    LEASE_S = 1.0
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        while not self._lock.acquire(True, self.LEASE_S):
+            telemetry.inc("bus.lock_broken")
+            try:
+                self._lock.release()
+            except Exception:  # another waiter broke it first
+                pass
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            self._lock.release()
+        except Exception:  # broken under us by a waiter that gave up
+            pass
+
+
 class _MpBus:
     """Cross-process bus over Manager dict/Lock proxies ONLY.
 
@@ -370,7 +407,7 @@ class _MpBus:
         self._worker_ts = manager.dict()  # "job|worker" -> epoch seconds
         self._expired = manager.dict()  # gathered/timed-out query ids
         self._expired_cap = self._EXPIRED_CAP  # instance-level for tests
-        self._lock = manager.Lock()
+        self._lock = _LeasedLock(manager.Lock())
 
     def __getstate__(self):
         state = self.__dict__.copy()

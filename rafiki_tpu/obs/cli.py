@@ -177,9 +177,8 @@ def cmd_profile(log_dir: str, key: Optional[str], as_json: bool,
         print(f"no perf records{f' matching {key!r}' if key else ''} "
               f"under {log_dir}", file=sys.stderr)
         return 1
-    if peak_flops is None:
-        from rafiki_tpu.obs.perf import profiler
-        peak_flops = profiler.PEAK_FLOPS_V5E_BF16
+    from rafiki_tpu.utils.backend import PEAK_BF16_FLOPS
+
     rows = []
     for h in hashes:
         c = costs.get(h, {})
@@ -202,11 +201,16 @@ def cmd_profile(log_dir: str, key: Optional[str], as_json: bool,
             row["arith_intensity"] = c["flops"] / c["bytes_accessed"]
         if c.get("flops") and warm:
             row["achieved_flops_s"] = c["flops"] / row["step_p50_s"]
-            # MFU claims a hardware peak: only meaningful when the
-            # steps ran on an accelerator. The journal can't know, so
-            # the report states its basis instead of guessing.
-            row["mfu_vs_peak"] = row["achieved_flops_s"] / peak_flops
-            row["peak_flops_basis"] = peak_flops
+            # MFU claims a hardware peak: --peak-flops states one
+            # outright; otherwise it is the peak on record for the
+            # device kind the cost capture ran on. A kind with no
+            # entry (the CPU included) gets no MFU, never a default.
+            row["device_kind"] = c.get("device_kind")
+            basis = (peak_flops if peak_flops is not None
+                     else PEAK_BF16_FLOPS.get(c.get("device_kind")))
+            if basis is not None:
+                row["mfu_vs_peak"] = row["achieved_flops_s"] / basis
+                row["peak_flops_basis"] = basis
         rows.append(row)
     if as_json:
         print(json.dumps({"programs": rows}, default=str))
@@ -227,8 +231,11 @@ def cmd_profile(log_dir: str, key: Optional[str], as_json: bool,
                   f"(min {row['step_min_s'] * 1e3:.3f}ms)")
         if row.get("achieved_flops_s"):
             print(f"  achieved: {row['achieved_flops_s']:.3e} FLOP/s "
-                  f"-> MFU {row['mfu_vs_peak'] * 100:.4f}% of "
-                  f"{row['peak_flops_basis']:.3g} peak")
+                  + (f"-> MFU {row['mfu_vs_peak'] * 100:.4f}% of "
+                     f"{row['peak_flops_basis']:.3g} peak"
+                     if "mfu_vs_peak" in row else
+                     f"(no MFU: no peak on record for device kind "
+                     f"{row['device_kind']!r}; pass --peak-flops)"))
     return 0
 
 
@@ -932,8 +939,8 @@ def cmd_tenants(log_dir: str, as_json: bool, check: bool) -> int:
 def main(argv: Optional[List[str]] = None) -> int:
     from rafiki_tpu.utils.backend import honor_env_platform
 
-    honor_env_platform()  # profile's peak-flops default imports the
-    # profiler package; pin the platform before anything can touch jax.
+    honor_env_platform()  # some verbs import jax-touching packages: a
+    # CPU request is applied before anything can use a backend.
     p = argparse.ArgumentParser(
         prog="python -m rafiki_tpu.obs",
         description="merge and query the per-process observability journals")
@@ -954,7 +961,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     sp.add_argument("key", nargs="?", default=None,
                     help="program key-hash prefix or key substring")
     sp.add_argument("--peak-flops", type=float, default=None,
-                    help="MFU denominator (default: v5e bf16 peak)")
+                    help="MFU denominator (default: the peak on record "
+                         "for the device kind the programs ran on)")
     sub.add_parser("slo", help="current SLO burn state + breach history")
     sub.add_parser("health",
                    help="numerics divergences + replay capsule inventory")

@@ -46,9 +46,6 @@ from rafiki_tpu.obs.perf.anomaly import EwmaMad
 
 ENV_COST_CAPTURE = "RAFIKI_PERF_COST_CAPTURE"
 
-#: v5e bf16 peak per chip — the MFU denominator bench.py also uses.
-PEAK_FLOPS_V5E_BF16 = 197e12
-
 #: Bounded stores: distinct programs per process / warm samples per program.
 MAX_PROGRAMS = 64
 STEP_RING = 256
@@ -104,7 +101,7 @@ class _ProgramStats:
             if flops and p50:
                 out["achieved_flops_s"] = flops / p50
                 peak = _peak_flops()
-                if peak:
+                if peak is not None:
                     out["mfu"] = flops / p50 / peak
         return out
 
@@ -113,7 +110,10 @@ _lock = threading.Lock()
 _programs: "OrderedDict[str, _ProgramStats]" = OrderedDict()
 _hbm_peak = 0.0
 _mem_broken = False
-_peak_cache: Optional[float] = None
+#: One-element list holding this process's peak (or None for a device
+#: kind with no peak on record), looked up once; empty until the first
+#: MFU question.
+_peak_cache: list = []
 
 
 def _get(key: Any, kind: str, k: int) -> _ProgramStats:
@@ -127,22 +127,27 @@ def _get(key: Any, kind: str, k: int) -> _ProgramStats:
     return stats
 
 
-def _peak_flops() -> Optional[float]:
-    """Peak FLOP/s for MFU — only claimed on an accelerator backend
-    (anything that isn't the host CPU; TPU-backed PJRT plugins register
-    under several names). On CPU the v5e constant is meaningless and
-    MFU reads as null."""
-    global _peak_cache
-    if _peak_cache is not None:
-        return _peak_cache or None
-    try:
-        import jax
+def _device_kind() -> str:
+    import jax
 
-        _peak_cache = (PEAK_FLOPS_V5E_BF16
-                       if jax.default_backend() != "cpu" else 0.0)
-    except Exception:
-        _peak_cache = 0.0
-    return _peak_cache or None
+    return jax.devices()[0].device_kind
+
+
+def _peak_flops() -> Optional[float]:
+    """Peak bf16 FLOP/s of this process's device kind, from the one
+    table in utils.backend. A kind the table does not list (the CPU
+    included) gets no MFU — and one ``perf/no_peak`` journal record
+    saying why — never another device's peak."""
+    if not _peak_cache:
+        from rafiki_tpu.utils.backend import PEAK_BF16_FLOPS
+
+        kind = _device_kind()
+        _peak_cache.append(PEAK_BF16_FLOPS.get(kind))
+        if _peak_cache[0] is None:
+            # lint: disable=RF014 — breadcrumb for a human reading why a profile has no MFU column
+            journal.record("perf", "no_peak", device_kind=kind,
+                           known=sorted(PEAK_BF16_FLOPS))
+    return _peak_cache[0]
 
 
 def _sample_device_mem() -> None:
@@ -191,8 +196,7 @@ def capture_cost(key: Any, jitted: Any, *args: Any,
         t0 = _time.monotonic()
         compiled = jitted.lower(*args).compile()
         cost["cost_capture_s"] = _time.monotonic() - t0
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
+        ca = compiled.cost_analysis() or {}
         cost["flops"] = float(ca.get("flops", 0.0)) or None
         cost["bytes_accessed"] = float(ca.get("bytes accessed", 0.0)) or None
         try:
@@ -215,7 +219,8 @@ def capture_cost(key: Any, jitted: Any, *args: Any,
                        flops=cost.get("flops"),
                        bytes_accessed=cost.get("bytes_accessed"),
                        peak_hbm_bytes=cost.get("peak_hbm_bytes"),
-                       cost_capture_s=cost.get("cost_capture_s"))
+                       cost_capture_s=cost.get("cost_capture_s"),
+                       device_kind=_device_kind())
     return cost or None
 
 
@@ -279,12 +284,12 @@ def snapshot() -> Dict[str, Any]:
 
 def reset() -> None:
     """Drop all profiler state (tests)."""
-    global _hbm_peak, _mem_broken, _peak_cache
+    global _hbm_peak, _mem_broken
     with _lock:
         _programs.clear()
         _hbm_peak = 0.0
         _mem_broken = False
-        _peak_cache = None
+        _peak_cache.clear()
 
 
 telemetry.register_collector("perf", snapshot)

@@ -54,9 +54,10 @@ SAMPLE_CAP = 512
 #: they appear in the journals).
 REQUIRED_KINDS = ("serving/hops", "gateway/config")
 
-#: v5e roofline constants for the cost-model service path: bf16 peak
-#: is shared with obs.perf.profiler; HBM bandwidth is the v5e
-#: datasheet number (~819 GB/s).
+#: The chip the cost-model service path plans for — a stated target,
+#: not a detected device: its bf16 peak comes from the one table in
+#: utils.backend; HBM bandwidth is the v5e datasheet number (~819 GB/s).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 HBM_BW_BYTES_S = 8.19e11
 HBM_BYTES_PER_CHIP = 1.6e10
 
@@ -268,8 +269,8 @@ class Calibration:
                 f"({len(self.cost)} row(s) present)")
         row = rows[0]
         if peak_flops is None:
-            from rafiki_tpu.obs.perf.profiler import PEAK_FLOPS_V5E_BF16
-            peak_flops = PEAK_FLOPS_V5E_BF16
+            from rafiki_tpu.utils.backend import peak_bf16_flops
+            peak_flops = peak_bf16_flops(TARGET_DEVICE_KIND)
         compute_s = float(row.get("flops") or 0.0) / (peak_flops * mfu)
         memory_s = float(row.get("bytes_accessed") or 0.0) / HBM_BW_BYTES_S
         return max(compute_s, memory_s)

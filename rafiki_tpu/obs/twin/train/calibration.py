@@ -42,6 +42,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from rafiki_tpu.obs import journal as journal_mod
 from rafiki_tpu.obs.twin.calibration import (HBM_BW_BYTES_S,
                                              HBM_BYTES_PER_CHIP,
+                                             TARGET_DEVICE_KIND,
                                              CalibrationError, _cap)
 
 TRAIN_CALIBRATION_VERSION = 1
@@ -338,8 +339,8 @@ class TrainCalibration:
                 f"({len(self.cost)} row(s) present)")
         row = rows[0]
         if peak_flops is None:
-            from rafiki_tpu.obs.perf.profiler import PEAK_FLOPS_V5E_BF16
-            peak_flops = PEAK_FLOPS_V5E_BF16
+            from rafiki_tpu.utils.backend import peak_bf16_flops
+            peak_flops = peak_bf16_flops(TARGET_DEVICE_KIND)
         width = max(1, int(row.get("k") or 1))
         ratio = float(k) / float(width)
         compute_s = (float(row.get("flops") or 0.0) * ratio

@@ -197,7 +197,13 @@ def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
         return (params, opt_state, step_i + 1, rng, hyper), metrics
 
     def eval_step(params, batch):
-        logits = apply_fn(params, batch)
+        # The barrier (an identity) keeps XLA from fusing the forward's
+        # tail with the argmax below. On the v5e (libtpu 0.0.34) that
+        # fusion is miscompiled when this step is vmapped over a pack
+        # whose width is a multiple of 8 at batch >= 256: members 0-3 of
+        # every 8 score as if they always answered class 0, while the
+        # same logits copied to the host are right (PERF.md, PR 22).
+        logits = jax.lax.optimization_barrier(apply_fn(params, batch))
         labels = batch["y"]
         mask = labels >= 0
         if "valid" in batch:

@@ -44,18 +44,11 @@ def make_ensemble_forward(apply_fn, mesh: Optional[Mesh] = None):
     # over "model". Under shard_map each chip vmaps over its local k/n
     # sub-ensemble with ordinary convs — embarrassingly parallel, no
     # collectives until the host gathers the output.
-    try:
-        from jax import shard_map  # jax >= 0.8 (check_rep renamed check_vma)
-        kw = {"check_vma": False}
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-        kw = {"check_rep": False}
-
-    body = shard_map(
+    body = jax.shard_map(
         fwd, mesh=mesh,
         in_specs=(P("model"), P()),
         out_specs=P("model"),
-        **kw,
+        check_vma=False,
     )
     return jax.jit(body)
 
@@ -78,6 +71,10 @@ class StackedEnsemble:
         stacked = stack_params(list(params_list))
         if mesh is not None:
             stacked = jax.device_put(stacked, NamedSharding(mesh, P("model")))
+        elif devices:
+            # One chip given: the stacked copy is committed to it, so
+            # the forward runs there and not on jax's default device.
+            stacked = jax.device_put(stacked, list(devices)[0])
         self._stacked = stacked
 
     def predict_proba(self, batch: dict) -> np.ndarray:

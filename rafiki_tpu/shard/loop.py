@@ -44,15 +44,6 @@ from rafiki_tpu.ops.train import (_make_step_fns, device_dataset_cap_bytes,
                                   get_program, mesh_cache_key)
 from rafiki_tpu.shard.plan import ShardPlan, group_mesh, path_str
 
-try:  # jax>=0.6 spells it jax.shard_map and renames check_rep
-    from jax import shard_map  # type: ignore[attr-defined]
-
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 class GroupAborted(RuntimeError):
     """A group member was lost; the epoch loop stopped at the epoch
@@ -167,14 +158,14 @@ class _ShardedProgram:
 
         P0 = P()
         self.train_epoch = jax.jit(
-            shard_map(train_epoch, mesh=mesh,
-                      in_specs=(spec_state, P0, P0, P0, P0),
-                      out_specs=(spec_state, P0), **_SHARD_MAP_KW),
+            jax.shard_map(train_epoch, mesh=mesh,
+                          in_specs=(spec_state, P0, P0, P0, P0),
+                          out_specs=(spec_state, P0), check_vma=False),
             donate_argnums=(0,))
         self.eval_epoch = jax.jit(
-            shard_map(eval_epoch, mesh=mesh,
-                      in_specs=(spec_state, P0, P0, P0),
-                      out_specs=(P0, P0), **_SHARD_MAP_KW))
+            jax.shard_map(eval_epoch, mesh=mesh,
+                          in_specs=(spec_state, P0, P0, P0),
+                          out_specs=(P0, P0), check_vma=False))
         self.init = jax.jit(make_state, out_shardings=self.state_sharding)
 
 

@@ -1,6 +1,7 @@
 """Content-addressed params store: chunk-level dedup for checkpoints.
 
-BENCH_r02's ``params_dump_s=2.94`` doubles a trial's fixed cost, and a
+A params dump as long as the trial itself (2.94 s on the one round-2
+chip datapoint) doubles a trial's fixed cost, and a
 sweep's checkpoints are the worst case: per-epoch snapshots of the
 same params tree differ by one epoch of updates, and pack-mates share
 most bytes early. :class:`CasParamsStore` keeps the

@@ -3,7 +3,7 @@ tracer behind a module-level functional API.
 
 Every subsystem writes through these functions; every reader (the
 ``GET /metrics`` endpoints on the admin and predictor apps, bench.py's
-embedded snapshot, ``scripts/tpu_watch.py``, tests) reads the SAME
+embedded snapshot, tests) reads the SAME
 state via :func:`snapshot`, so "what the bench reports" and "what the
 serving endpoint shows" can never drift apart.
 

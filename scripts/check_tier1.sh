@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# The tier-1 verify gate, verbatim from ROADMAP.md — builders, the TPU
-# watcher and CI must all run the IDENTICAL command so "tests pass"
+# The tier-1 verify gate, verbatim from ROADMAP.md — builders and CI
+# must all run the IDENTICAL command so "tests pass"
 # means the same thing everywhere. Edit ROADMAP.md and this file
 # together or not at all.
 #
@@ -32,9 +32,10 @@ env JAX_PLATFORMS=cpu python scripts/chaos_smoke.py > /tmp/_chaos_smoke.json \
 # /metrics?format=prom must line-parse (docs/observability.md). ~6s.
 env JAX_PLATFORMS=cpu python scripts/obs_smoke.py > /tmp/_obs_smoke.json \
   || { echo "TIER1 OBS SMOKE FAILED (see /tmp/_obs_smoke.json)"; exit 1; }
-# Perf-sentinel smoke: bench_report must gate both ways on the
-# BENCH_r* history, an uninjected packed round must profile clean
-# (obs profile reports packed-program MFU, zero anomalies/breaches),
+# Perf-sentinel smoke: bench_report must gate both ways (an errored
+# round is no-data, a 3x drop regresses), an uninjected packed round
+# must profile clean (obs profile joins the packed program's cost
+# against a stated peak, zero anomalies/breaches),
 # and an injected 0.25s epoch delay must land anomaly -> SLO breach
 # -> flight record (docs/perf.md). ~7s.
 env JAX_PLATFORMS=cpu python scripts/perf_smoke.py > /tmp/_perf_smoke.json \

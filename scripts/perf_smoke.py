@@ -3,10 +3,11 @@
 
 Three gates in one process (docs/perf.md):
 
-  1. **Bench regression gate** — scripts/bench_report.py over the real
-     BENCH_r*.json history must exit 0 (error-bearing rounds are
-     no-data, not regressions), and over a doctored two-round fixture
-     with a 3x throughput drop must exit nonzero naming the metric.
+  1. **Bench regression gate** — scripts/bench_report.py over a
+     history whose later rounds carry an ``error`` must exit 0
+     (error-bearing rounds are no-data, not regressions), and over a
+     doctored two-round fixture with a 3x throughput drop must exit
+     nonzero naming the metric.
 
   2. **Quiet run (no injection)** — a packed TrainWorker round under a
      fresh journal dir: cost capture (``perf/cost``) and step sampling
@@ -74,12 +75,27 @@ def _run(cmd, **kw):
 
 
 def check_bench_gate(problems, tmp):
-    """Gate 1: the report must pass real history and fail a doctored
-    regression — both directions, via the real CLI."""
+    """Gate 1: the report must pass a history whose later round errored
+    and fail a doctored regression — both directions, via the real
+    CLI."""
     report = os.path.join(REPO, "scripts", "bench_report.py")
-    real = _run([sys.executable, report])
+    hist_dir = os.path.join(tmp, "history")
+    os.makedirs(hist_dir)
+    history = []
+    for doc in (
+            {"n": 1, "cmd": "bench", "rc": 0, "tail": [], "parsed": {
+                "metric": "m", "value": 1200.0,
+                "headline": {"trials_per_hour": 1200.0}}},
+            {"n": 2, "cmd": "bench", "rc": 1, "tail": [], "parsed": {
+                "metric": "m", "value": 0.0,
+                "error": "RuntimeError: bench needs a tpu device"}}):
+        p = os.path.join(hist_dir, f"BENCH_r{doc['n']:02d}.json")
+        with open(p, "w") as f:
+            json.dump(doc, f)
+        history.append(p)
+    real = _run([sys.executable, report] + history)
     if real.returncode != 0:
-        problems.append(f"bench_report on real history exited "
+        problems.append(f"bench_report on an errored-round history exited "
                         f"{real.returncode}: {real.stderr.strip()[:200]}")
     try:
         verdict = json.loads(real.stdout or "{}").get("verdict")
@@ -199,9 +215,11 @@ def _tick_until_breach(deadline_s):
 
 
 def _profile_via_cli(log_dir):
-    """The real operator command from docs/perf.md, JSON mode."""
+    """The real operator command from docs/perf.md, JSON mode. The
+    smoke runs on the CPU, which has no peak on record, so the MFU join
+    is exercised against a stated ``--peak-flops`` basis."""
     proc = _run([sys.executable, "-m", "rafiki_tpu.obs", "--dir", log_dir,
-                 "--json", "profile"])
+                 "--json", "profile", "--peak-flops", "1e12"])
     if proc.returncode != 0:
         raise RuntimeError(f"obs profile exited {proc.returncode}: "
                            f"{proc.stderr.strip()[:200]}")
@@ -288,8 +306,8 @@ def main() -> int:
         out = {
             "bench_gate": bench,
             "quiet": {k: len(v) for k, v in quiet.items()},
-            "packed_mfu": (packed_rows[0].get("mfu_vs_peak")
-                           if packed_rows else None),
+            "packed_join_vs_stated_peak": (
+                packed_rows[0].get("mfu_vs_peak") if packed_rows else None),
             "injected": {k: len(v) for k, v in injected.items()},
             # lint: disable=RF007 — smoke artifact wall-clock
             "wall_s": round(time.monotonic() - t0, 3),

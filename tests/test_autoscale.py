@@ -366,7 +366,7 @@ def test_prewarm_primes_the_program_cache(tmp_path, monkeypatch):
     """A prewarmed packing key must make the NEXT PackedTrainLoop for
     the same key a program-cache hit — that hit is the 12.8s compile
     scale-up no longer pays."""
-    monkeypatch.setenv("RAFIKI_XLA_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     from rafiki_tpu.autoscale.prewarm import prewarm_models, probe_knobs
     from rafiki_tpu.chaos.scenarios import FF_SOURCE, TRAIN
     from rafiki_tpu.model.base import load_model_class

@@ -1,0 +1,204 @@
+"""The main path's programs compile for the chip — checked without one.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). These tests
+hand the jitted epoch programs of the three train lanes and the stacked
+serving forward the described v5e devices and real-width shapes
+(``jax.eval_shape`` — nothing is placed, nothing runs) and compile them:
+what the chip's compiler would refuse is refused here, at no chip time.
+A compile that passes is NOT a chip run and is never reported as one —
+``chip_smoke.py`` is the chip run.
+
+One file on purpose: the process that describes the topology keeps the
+TPU library until it exits, so a second file on another xdist worker
+would skip in silence. The topology is described inside a fixture, never
+at import (every worker imports this file).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-device compile is written to the persistent cache but
+    cannot be read back without a chip (the next one warns and compiles
+    again), so the cache is off while the topology fixture lives."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """``tree``'s shapes (from eval_shape) as arguments on ``sharding``."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _serial_state(fns, init, sharding):
+    """The abstract (params, opt_state, step, rng, hyper) a TrainLoop
+    carries, for ``fns`` of one JaxModel."""
+    rng = jax.random.PRNGKey(0)
+    params, opt_state = jax.eval_shape(init, rng)
+    hyper = {k: jax.ShapeDtypeStruct((), jnp.float32) for k in fns["hyper"]}
+    state = (params, opt_state, jax.ShapeDtypeStruct((), jnp.int32),
+             jax.eval_shape(lambda: rng), hyper)
+    return _on(sharding, state)
+
+
+def _peak_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def _vgg16():
+    from rafiki_tpu.models.vgg import Vgg
+
+    return Vgg(depth=16, width_mult=1.0, dropout=0.1, learning_rate=1e-3,
+               batch_size=256, epochs=1, seed=0)
+
+
+def test_serial_vgg16_epoch_and_eval_compile_for_v5e(one_chip):
+    """The canonical trial (bench.py): VGG16 depth 16 / width 1.0,
+    32x32x3, 50k train / 10k eval device-resident, batch 256."""
+    from rafiki_tpu.ops.train import Program, _ShardingPlan
+
+    model = _vgg16()
+    fns = model._loop_fns(10, (32, 32, 3))
+    prog = Program(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                   fns["optimizer"], _ShardingPlan.build(None))
+    state = _serial_state(fns, prog.init, one_chip)
+    n_steps = 50_000 // 256
+    train = prog.train_epoch.lower(
+        state, _spec((50_000, 32, 32, 3), jnp.float32, one_chip),
+        _spec((50_000,), jnp.int32, one_chip),
+        _spec((n_steps, 256), jnp.int32, one_chip),
+        _spec((n_steps,), jnp.float32, one_chip)).compile()
+    assert "convolution" in train.as_text()
+    assert _peak_bytes(train) < HBM_BYTES
+    evaluate = prog.eval_epoch.lower(
+        state[0], _spec((10_000, 32, 32, 3), jnp.float32, one_chip),
+        _spec((10_000,), jnp.int32, one_chip),
+        _spec((10_000 // 256, 256), jnp.int32, one_chip)).compile()
+    assert _peak_bytes(evaluate) < HBM_BYTES
+
+
+def test_packed_k4_feedforward_epoch_compiles_for_v5e(one_chip):
+    """The packed lane's program: k=4 FeedForward trials at the
+    template's largest shape knobs (3x256, batch 128) vmapped into one
+    epoch scan over MNIST-shaped data (60k x 28x28x1)."""
+    from rafiki_tpu.models.ff import FeedForward
+    from rafiki_tpu.ops.train import PackedProgram
+
+    k, batch = 4, 128
+    model = FeedForward(hidden_layers=3, hidden_units=256, learning_rate=1e-3,
+                        batch_size=batch, epochs=1, seed=0)
+    fns = model._loop_fns(10, (28, 28, 1))
+    prog = PackedProgram(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                         fns["optimizer"], k)
+    rngs = jnp.stack([jax.random.PRNGKey(i) for i in range(k)])
+    params, opt_state = jax.eval_shape(prog.init, rngs)
+    hyper = {h: jax.ShapeDtypeStruct((k,), jnp.float32) for h in fns["hyper"]}
+    state = _on(one_chip, (params, opt_state,
+                           jax.ShapeDtypeStruct((k,), jnp.int32),
+                           jax.eval_shape(lambda: rngs), hyper))
+    n_steps = 60_000 // batch
+    compiled = prog.train_epoch.lower(
+        state, _spec((60_000, 28, 28, 1), jnp.float32, one_chip),
+        _spec((60_000,), jnp.int32, one_chip),
+        _spec((n_steps, k, batch), jnp.int32, one_chip),
+        _spec((n_steps, k), jnp.float32, one_chip)).compile()
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
+def test_sharded_width4_transformer_epoch_compiles_for_v5e(topo):
+    """The sharded lane's program at width 4: the Transformer template's
+    largest setting on a four-device ("shard",) mesh of the described
+    chips. The compiled text must hold the lane's collectives
+    (all-gather in, no all-reduce needed: compute is replicated) and
+    each device's share must fit its HBM."""
+    from rafiki_tpu.models.transformer import Transformer
+    from rafiki_tpu.shard.loop import _ShardedProgram
+    from rafiki_tpu.shard.plan import ShardPlan
+
+    width, batch, n, length = 4, 64, 4096, 16
+    model = Transformer(embed_dim=128, num_heads=4, num_layers=2,
+                        learning_rate=1e-3, batch_size=batch, epochs=1, seed=0)
+    model._dataset_meta = {"vocab": 81}
+    fns = model._loop_fns(5, (length,))
+    mesh = Mesh(np.asarray(topo.devices[:width]), ("shard",))
+    prog = _ShardedProgram(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                           fns["optimizer"], mesh, ShardPlan(width=width),
+                           dynamic_lr=True,
+                           hyper_keys=tuple(sorted(fns["hyper"])))
+    rng = jax.random.PRNGKey(0)
+    hyper = {h: jnp.float32(0.0) for h in sorted(fns["hyper"])}
+    abs_state = jax.eval_shape(prog.init, rng, rng, hyper)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abs_state, prog.state_sharding)
+    rep = NamedSharding(mesh, P())
+    n_steps = n // batch
+    compiled = prog.train_epoch.lower(
+        state, _spec((n, length), jnp.int32, rep),
+        _spec((n,), jnp.int32, rep),
+        _spec((n_steps, batch), jnp.int32, rep),
+        _spec((n_steps,), jnp.float32, rep)).compile()
+    assert "all-gather" in compiled.as_text()
+    assert _peak_bytes(compiled) < HBM_BYTES
+    sharded = [s for s in jax.tree.leaves(prog.state_sharding)
+               if s.spec != P()]
+    assert sharded, "width-4 plan sharded no leaf of the transformer state"
+
+
+def test_stacked_top2_vgg16_serving_forward_compiles_for_v5e(one_chip):
+    """The stacked serving route: the top-2 VGG16 trials' params stacked
+    on a leading axis, one vmapped forward per 64-query batch."""
+    from rafiki_tpu.parallel.ensemble import make_ensemble_forward
+
+    model = _vgg16()
+    fns = model._loop_fns(10, (32, 32, 3))
+    params = jax.eval_shape(fns["init_fn"], jax.random.PRNGKey(0))
+    stacked = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((2,) + a.shape, a.dtype,
+                                       sharding=one_chip), params)
+    fwd = make_ensemble_forward(fns["apply_eval"])
+    compiled = fwd.lower(
+        stacked, {"x": _spec((64, 32, 32, 3), jnp.float32, one_chip)}).compile()
+    assert _peak_bytes(compiled) < HBM_BYTES

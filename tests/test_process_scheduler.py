@@ -84,7 +84,7 @@ def test_workers_populate_persistent_xla_cache(env, tmp_path, monkeypatch):
     (worker/main.py): after a job, compiled executables are on disk for
     future processes to load instead of recompiling."""
     cache_dir = tmp_path / "xla-cache"
-    monkeypatch.setenv("RAFIKI_XLA_CACHE_DIR", str(cache_dir))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
     monkeypatch.setenv("RAFIKI_XLA_CACHE_MIN_S", "0")  # CPU compiles are fast
     store, params, model = env
     job = _make_job(store, model, {"MODEL_TRIAL_COUNT": 1})
