@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from rafiki_tpu import telemetry
 from rafiki_tpu.model.knobs import KnobConfig, Knobs, validate_knobs
 from rafiki_tpu.model.dataset import Dataset, dataset_utils
 
@@ -452,43 +453,6 @@ class JaxModel(BaseModel):
 
         if not models:
             return []
-        lead = models[0]
-        keys = {id(m): m.packing_key(lead._prepared_dataset(dataset_uri))
-                for m in models}
-        if len(set(map(repr, keys.values()))) != 1:
-            raise ValueError("train_packed models do not share a packing key; "
-                             "bucket with packing_key() first")
-        for m in models:
-            if m._mesh is not None:
-                raise ValueError("packed trials are single-device; mesh is set")
-            if m._start_epoch > 0:
-                raise ValueError("packed trials cannot resume from checkpoint")
-        ds = lead._prepared_dataset(dataset_uri)
-        if ds.mask is not None:
-            raise ValueError("packed training does not support masked datasets")
-        num_classes, input_shape = lead._dataset_arch(ds)
-        epochs, batch_size = lead.epochs, lead.batch_size
-
-        # One set of traced closures (the lead's — program_key equality
-        # makes them interchangeable), k hyper dicts/seeds.
-        fns = lead._loop_fns(num_classes, input_shape)
-        hypers = []
-        for m in models:
-            m._planned_steps = epochs * max(1, ds.size // batch_size)
-            m._dataset_meta = dict(ds.meta)
-            mf = m._loop_fns(num_classes, input_shape)
-            hypers.append(mf["hyper"])
-        packed = PackedTrainLoop(
-            fns["init_fn"], fns["apply_eval"], fns["loss_fn"], fns["optimizer"],
-            seeds=[m._seed for m in models], hypers=hypers,
-            program_key=fns["program_key"],
-            packing_key=repr(keys[id(lead)]))
-
-        histories: List[List[Dict[str, float]]] = [[] for _ in models]
-        arch = (num_classes, tuple(input_shape))
-        planned = epochs * max(1, ds.size // batch_size)
-        portable = _portable_meta(dict(ds.meta))
-        pack_hypers = {i: hypers[i] for i in range(len(models))}
 
         def install_detached(mi: int, state, epoch: int) -> None:
             """Evicted member keeps training-equivalent state through an
@@ -503,19 +467,63 @@ class JaxModel(BaseModel):
             m._arch = arch
             m._epochs_done = epoch
 
-        slots = list(range(len(models)))  # slot j <-> packed member j
-        epochs_done = {mi: 0 for mi in slots}  # epochs COMPLETED so far
-        # Replay-capsule context (docs/health.md): member_info resolves
-        # a LIVE slot to its trial's knobs/seed at trip time (slots and
-        # models mutate as members leave and backfills arrive).
-        packed.health.set_context(
-            model=lead._health_model_identity(), train_uri=dataset_uri,
-            batch_size=batch_size, planned_steps=planned,
-            member_info=lambda j: {
-                "model": dict(lead._health_model_identity(),
-                              knobs=dict(models[slots[j]].knobs)),
-                "seed": models[slots[j]]._seed,
-            })
+        # The pack's initialisation, a leaf phase of the hand-over between
+        # rounds (docs/telemetry.md): key check, data set, the vmapped
+        # init and the hyper-parameters' upload, up to the first epoch.
+        # ``program.build`` (a cold round's cache miss) nests inside it
+        # as a plain span: this one is the leaf.
+        with telemetry.span("trial_pack.init", leaf=True, k=len(models)):
+            lead = models[0]
+            keys = {id(m): m.packing_key(lead._prepared_dataset(dataset_uri))
+                    for m in models}
+            if len(set(map(repr, keys.values()))) != 1:
+                raise ValueError("train_packed models do not share a packing key; "
+                                 "bucket with packing_key() first")
+            for m in models:
+                if m._mesh is not None:
+                    raise ValueError("packed trials are single-device; mesh is set")
+                if m._start_epoch > 0:
+                    raise ValueError("packed trials cannot resume from checkpoint")
+            ds = lead._prepared_dataset(dataset_uri)
+            if ds.mask is not None:
+                raise ValueError("packed training does not support masked datasets")
+            num_classes, input_shape = lead._dataset_arch(ds)
+            epochs, batch_size = lead.epochs, lead.batch_size
+
+            # One set of traced closures (the lead's — program_key equality
+            # makes them interchangeable), k hyper dicts/seeds.
+            fns = lead._loop_fns(num_classes, input_shape)
+            hypers = []
+            for m in models:
+                m._planned_steps = epochs * max(1, ds.size // batch_size)
+                m._dataset_meta = dict(ds.meta)
+                mf = m._loop_fns(num_classes, input_shape)
+                hypers.append(mf["hyper"])
+            packed = PackedTrainLoop(
+                fns["init_fn"], fns["apply_eval"], fns["loss_fn"], fns["optimizer"],
+                seeds=[m._seed for m in models], hypers=hypers,
+                program_key=fns["program_key"],
+                packing_key=repr(keys[id(lead)]))
+
+            histories: List[List[Dict[str, float]]] = [[] for _ in models]
+            arch = (num_classes, tuple(input_shape))
+            planned = epochs * max(1, ds.size // batch_size)
+            portable = _portable_meta(dict(ds.meta))
+            pack_hypers = {i: hypers[i] for i in range(len(models))}
+
+            slots = list(range(len(models)))  # slot j <-> packed member j
+            epochs_done = {mi: 0 for mi in slots}  # epochs COMPLETED so far
+            # Replay-capsule context (docs/health.md): member_info resolves
+            # a LIVE slot to its trial's knobs/seed at trip time (slots and
+            # models mutate as members leave and backfills arrive).
+            packed.health.set_context(
+                model=lead._health_model_identity(), train_uri=dataset_uri,
+                batch_size=batch_size, planned_steps=planned,
+                member_info=lambda j: {
+                    "model": dict(lead._health_model_identity(),
+                                  knobs=dict(models[slots[j]].knobs)),
+                    "seed": models[slots[j]]._seed,
+                })
         rnd = 0
         while slots:
             # Serial parity: trial i's shuffle seed is seed_i + its OWN
@@ -702,12 +710,18 @@ class JaxModel(BaseModel):
         # on the steady-state throughput path via the async saver, and
         # per-leaf device_get costs ~2x the packed fetch.
         cast = get_config().serving_params_dtype == "bfloat16"
-        payload = {
-            "arch": self._arch,
-            "packed": dump_pytree(self._loop.params, cast_f32_to_bf16=cast),
-            "dataset_meta": _portable_meta(self._dataset_meta),
-        }
-        return pickle.dumps(payload)
+        # The device's side of a dump: this trial's leaves sliced out of
+        # the pack (``params`` of a slice view), the cast, device to host.
+        with telemetry.span("persist.fetch", leaf=True):
+            packed = dump_pytree(self._loop.params, cast_f32_to_bf16=cast)
+        # The host's side starts here; the worker's ``params_store.save``
+        # is a second ``persist.write``.
+        with telemetry.span("persist.write", leaf=True):
+            return pickle.dumps({
+                "arch": self._arch,
+                "packed": packed,
+                "dataset_meta": _portable_meta(self._dataset_meta),
+            })
 
     def load_parameters(self, blob: bytes) -> None:
         import jax

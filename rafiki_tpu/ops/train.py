@@ -43,6 +43,12 @@ from rafiki_tpu import telemetry
 from rafiki_tpu.obs.health import DivergenceError, HealthMonitor
 from rafiki_tpu.obs.health import sentinel as _sentinel
 
+# One clock (docs/telemetry.md): this module owns jax for the train
+# path, so it hands the span tracer the profiler's annotation. A leaf
+# span then shows on its thread's line of a running jax.profiler trace;
+# with no session open TraceAnnotation is a flag test.
+telemetry.install_annotator(jax.profiler.TraceAnnotation)
+
 Batch = Dict[str, np.ndarray]
 Params = Any
 # Canonical loss signature: (params, batch, rng, hyper) -> (loss, metrics).
@@ -155,6 +161,25 @@ def effective_lr(hyper: Dict[str, jnp.ndarray], step_i) -> jnp.ndarray:
     return hyper["lr"] * frac
 
 
+# Fixed scope names of the step outside the model's own modules (flax
+# scopes the forward pass by module name). Metadata only: they reach the
+# lowered program's locations and the profiler's operation names, never
+# the arithmetic. Listed in docs/telemetry.md.
+SCOPE_GATHER = "rafiki.batch_gather"
+SCOPE_LOSS = "rafiki.loss"
+SCOPE_OPTIMIZER = "rafiki.optimizer"
+SCOPE_HEALTH = "rafiki.health"
+SCOPE_EVAL_COUNT = "rafiki.eval_count"
+STEP_SCOPES = (SCOPE_GATHER, SCOPE_LOSS, SCOPE_OPTIMIZER, SCOPE_HEALTH,
+               SCOPE_EVAL_COUNT)
+
+
+def _gather_batch(X, Y, ib) -> Dict[str, jnp.ndarray]:
+    """One step's batch out of the device-resident data set."""
+    with jax.named_scope(SCOPE_GATHER):
+        return {"x": jnp.take(X, ib, axis=0), "y": jnp.take(Y, ib, axis=0)}
+
+
 def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
                    optimizer: optax.GradientTransformation,
                    dynamic_lr: bool):
@@ -174,8 +199,9 @@ def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
             # prefix); every element is the same step multiplier.
             poison = poison[0]
         rng, sub = jax.random.split(rng)
-        (loss, metrics), grads = jax.value_and_grad(loss4, has_aux=True)(
-            params, batch, sub, hyper)
+        with jax.named_scope(SCOPE_LOSS):
+            (loss, metrics), grads = jax.value_and_grad(loss4, has_aux=True)(
+                params, batch, sub, hyper)
         if poison is not None:
             # Chaos ``train.nan`` carrier (docs/chaos.md): the poison is
             # a per-step f32 multiplier, 1.0 everywhere except the
@@ -183,17 +209,20 @@ def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
             # unpoisoned steps — and unpoisoned pack members, whose
             # whole column is ones — stay bit-identical to a clean run.
             grads = jax.tree.map(lambda g: g * poison.astype(g.dtype), grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        if dynamic_lr:
-            lr = effective_lr(hyper, step_i)
-            updates = jax.tree.map(lambda u: (-lr).astype(u.dtype) * u, updates)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            if dynamic_lr:
+                lr = effective_lr(hyper, step_i)
+                updates = jax.tree.map(
+                    lambda u: (-lr).astype(u.dtype) * u, updates)
+            params = optax.apply_updates(params, updates)
         # Health sentinels ride the metric dict as device scalars —
         # unconditionally, so every cached program shares one trace and
         # one metric structure; they read the step's intermediates but
         # never touch the rng chain or the update math (bit-neutral).
-        metrics = dict(metrics, loss=loss,
-                       **_sentinel.bundle(loss, grads, updates, params))
+        with jax.named_scope(SCOPE_HEALTH):
+            health = _sentinel.bundle(loss, grads, updates, params)
+        metrics = dict(metrics, loss=loss, **health)
         return (params, opt_state, step_i + 1, rng, hyper), metrics
 
     def eval_step(params, batch):
@@ -209,9 +238,10 @@ def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
         if "valid" in batch:
             v = batch["valid"]
             mask = jnp.logical_and(mask, v.reshape(v.shape + (1,) * (mask.ndim - v.ndim)))
-        labels_safe = jnp.where(mask, labels, 0)
-        correct = (jnp.argmax(logits, axis=-1) == labels_safe) & mask
-        return correct.sum(), mask.sum()
+        with jax.named_scope(SCOPE_EVAL_COUNT):
+            labels_safe = jnp.where(mask, labels, 0)
+            correct = (jnp.argmax(logits, axis=-1) == labels_safe) & mask
+            return correct.sum(), mask.sum()
 
     def predict(params, batch):
         logits = apply_fn(params, batch)
@@ -266,8 +296,7 @@ class Program:
             # never carry the poison multiply.
             def body(st, xs):
                 ib, pz = xs
-                batch = {"x": jnp.take(X, ib, axis=0),
-                         "y": jnp.take(Y, ib, axis=0)}
+                batch = _gather_batch(X, Y, ib)
                 if pz is not None:
                     batch["_health_poison"] = pz
                 return train_step(st, batch)
@@ -278,13 +307,13 @@ class Program:
             # its epoch-boundary summary (docs/health.md).
             rest, health = _sentinel.split(ms)
             out = {k: v[-1] for k, v in rest.items()}
-            out.update(_sentinel.reduce_epoch(health))
+            with jax.named_scope(SCOPE_HEALTH):
+                out.update(_sentinel.reduce_epoch(health))
             return state, out
 
         def eval_epoch(params, X, Y, idx):
             def body(carry, ib):
-                batch = {"x": jnp.take(X, ib, axis=0),
-                         "y": jnp.take(Y, ib, axis=0)}
+                batch = _gather_batch(X, Y, ib)
                 c, n = eval_step(params, batch)
                 return (carry[0] + c, carry[1] + n), None
 
@@ -588,7 +617,8 @@ class TrainLoop:
         # sentinel's step-time distribution. No-op when capsules are
         # off, and skipped on the python path (no index matrix there,
         # so no replayable capsule to bank state for).
-        snap = self.health.snapshot_state(self.state) if fast else None
+        with telemetry.span("train.health_snapshot", leaf=True):
+            snap = self.health.snapshot_state(self.state) if fast else None
         t_epoch = time.monotonic()
         # Chaos site INSIDE the timed region (unlike collective.step
         # above): an injected delay here inflates the measured epoch
@@ -840,8 +870,7 @@ class PackedProgram:
             # cannot perturb its pack-mates (ones-column = bit-exact).
             def body(st, xs):
                 ib, pz = xs
-                batch = {"x": jnp.take(X, ib, axis=0),
-                         "y": jnp.take(Y, ib, axis=0)}
+                batch = _gather_batch(X, Y, ib)
                 if pz is not None:
                     batch["_health_poison"] = pz
                 return v_train(st, batch)
@@ -851,14 +880,14 @@ class PackedProgram:
             # health series reduces per member on-device.
             rest, health = _sentinel.split(ms)
             out = {key: v[-1] for key, v in rest.items()}
-            out.update(_sentinel.reduce_epoch(health))
+            with jax.named_scope(SCOPE_HEALTH):
+                out.update(_sentinel.reduce_epoch(health))
             return state, out
 
         def packed_eval_epoch(params, X, Y, idx):
             # idx: (n_steps, batch) — eval order is shared (no shuffle).
             def body(carry, ib):
-                batch = {"x": jnp.take(X, ib, axis=0),
-                         "y": jnp.take(Y, ib, axis=0)}
+                batch = _gather_batch(X, Y, ib)
                 c, n = v_eval(params, batch)
                 return (carry[0] + c, carry[1] + n), None
 
@@ -1052,42 +1081,65 @@ class PackedTrainLoop:
                 f"the epoch would run zero steps")
         # Pre-epoch stacked-state snapshot for replay capsules (sliced
         # per sick member only on trip); banked before the timer so the
-        # copy never pollutes step_s. See TrainLoop.run_epoch.
-        snap = self.health.snapshot_state(self.state)
-        t_epoch = time.monotonic()
-        # Same in-timed-region chaos site as the serial loop: injected
-        # delays here are visible to the anomaly detector.
-        from rafiki_tpu import chaos as _chaos
-
-        _chaos.hook("train.epoch", key=str(self._perf_key))
+        # copy never pollutes step_s. See TrainLoop.run_epoch. With
+        # capsules on (the default) it is a device-to-host copy of the
+        # whole stacked state, so it is a leaf phase of its own.
+        with telemetry.span("train.health_snapshot", leaf=True):
+            snap = self.health.snapshot_state(self.state)
         n_steps = dataset.size // batch_size
-        # (n_steps, k, batch): step-major so lax.scan walks steps while
-        # each trial keeps its own serial-identical permutation.
-        idx = np.stack([
-            np.random.default_rng(int(s)).permutation(dataset.size)
-            [: n_steps * batch_size].reshape(n_steps, batch_size)
-            for s in epoch_seeds], axis=1).astype(np.int32)
-        poison = self._chaos_poison(n_steps)
-        if self._fits_device_fast_path(dataset):
-            X, Y = get_device_dataset(dataset)
-            if not getattr(self, "_warm", False):
-                from rafiki_tpu.obs.perf import profiler as _profiler
+        cold = not getattr(self, "_warm", False)
+        t_epoch = time.monotonic()
+        # The epoch as the host waits for it: from here to the metrics on
+        # the host (jax returns at enqueue, so anything read before the
+        # device_get is the dispatch). A leaf phase: the hand-over
+        # between two rounds ends where this span starts.
+        with telemetry.span("train.packed_epoch", leaf=True, cold=cold,
+                            k=self.k, steps=n_steps):
+            # Same in-timed-region chaos site as the serial loop: injected
+            # delays here are visible to the anomaly detector.
+            from rafiki_tpu import chaos as _chaos
 
-                _profiler.capture_cost(self._perf_key,
-                                       self.program.train_epoch,
-                                       self.state, X, Y, idx, poison,
-                                       kind="packed", k=self.k)
-            self.state, metrics = self.program.train_epoch(
-                self.state, X, Y, idx, poison)
-            self._record_epoch(t_epoch)
-            host = {key: np.asarray(jax.device_get(v)) for key, v in metrics.items()}
-            rows = [{key: float(v[i]) for key, v in host.items()}
-                    for i in range(self.k)]
-            return self._health_check(rows, t_epoch, epoch_seeds, idx,
-                                      poison, snap)
+            _chaos.hook("train.epoch", key=str(self._perf_key))
+            # (n_steps, k, batch): step-major so lax.scan walks steps while
+            # each trial keeps its own serial-identical permutation.
+            idx = np.stack([
+                np.random.default_rng(int(s)).permutation(dataset.size)
+                [: n_steps * batch_size].reshape(n_steps, batch_size)
+                for s in epoch_seeds], axis=1).astype(np.int32)
+            poison = self._chaos_poison(n_steps)
+            if self._fits_device_fast_path(dataset):
+                metrics = self._epoch_on_device(dataset, idx, poison, cold)
+            else:
+                metrics = self._epoch_by_steps(dataset, idx, poison)
+            host = {key: np.asarray(jax.device_get(v))
+                    for key, v in metrics.items()}
+        self._record_epoch(t_epoch)
+        rows = [{key: float(v[i]) for key, v in host.items()}
+                for i in range(self.k)]
+        return self._health_check(rows, t_epoch, epoch_seeds, idx,
+                                  poison, snap)
+
+    def _epoch_on_device(self, dataset, idx, poison, cold: bool):
+        """The whole epoch as one program over the device-resident data
+        set; returns the metrics still on the device."""
+        X, Y = get_device_dataset(dataset)
+        if cold:
+            from rafiki_tpu.obs.perf import profiler as _profiler
+
+            _profiler.capture_cost(self._perf_key,
+                                   self.program.train_epoch,
+                                   self.state, X, Y, idx, poison,
+                                   kind="packed", k=self.k)
+        self.state, metrics = self.program.train_epoch(
+            self.state, X, Y, idx, poison)
+        return metrics
+
+    def _epoch_by_steps(self, dataset, idx, poison):
+        """The epoch one step program at a time, batches gathered on the
+        host (data sets over the device cap); metrics on the device."""
         metrics = None
         health_steps = []
-        for t in range(n_steps):
+        for t in range(idx.shape[0]):
             ib = idx[t]  # (k, batch)
             batch = {"x": jnp.asarray(dataset.x[ib]),
                      "y": jnp.asarray(dataset.y[ib])}
@@ -1098,19 +1150,12 @@ class PackedTrainLoop:
             # syncs once, at the epoch-boundary reduction below.
             health_steps.append({k: v for k, v in metrics.items()
                                  if k.startswith(_sentinel.PREFIX)})
-        self._record_epoch(t_epoch)
-        reduced = _sentinel.reduce_epoch(
+        out = {key: v for key, v in metrics.items()
+               if not key.startswith(_sentinel.PREFIX)}
+        out.update(_sentinel.reduce_epoch(
             {k: jnp.stack([h[k] for h in health_steps])
-             for k in health_steps[0]})
-        host = {key: np.asarray(jax.device_get(v))
-                for key, v in metrics.items()
-                if not key.startswith(_sentinel.PREFIX)}
-        host.update({key: np.asarray(jax.device_get(v))
-                     for key, v in reduced.items()})
-        rows = [{key: float(v[i]) for key, v in host.items()}
-                for i in range(self.k)]
-        return self._health_check(rows, t_epoch, epoch_seeds, idx,
-                                  poison, snap)
+             for k in health_steps[0]}))
+        return out
 
     def _chaos_poison(self, n_steps: int) -> np.ndarray:
         """Per-member ``train.nan`` poison plane: each live member is a
@@ -1155,8 +1200,8 @@ class PackedTrainLoop:
         dt = time.monotonic() - t0
         cold = not getattr(self, "_warm", False)
         self._warm = True
-        telemetry.observe("train.packed_cold_epoch_s" if cold
-                          else "train.packed_epoch_s", dt)
+        # (The epoch's wall itself is the ``train.packed_epoch`` span,
+        # tagged ``cold``; /metrics exports its aggregate.)
         # Goodput ledger: same convention as the serial loop — the cold
         # (compile-paying) epoch is overhead, warm epochs are productive.
         ledger.add("compile_s" if cold else "step_s", dt)

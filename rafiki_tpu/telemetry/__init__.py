@@ -13,6 +13,8 @@ Write API (cheap, thread-safe, never raises into callers):
     add_gauge("scheduler.active_workers", +1)
     observe("predictor.gather_s", 0.01)  histograms (bounded reservoir)
     with span("trial.train", trial_id=t): ...   nestable timed phases
+    with span("trial.log", leaf=True): ...      a leaf phase: also an event
+                                         in a running profiler trace
 
 Read API:
     snapshot()        -> one JSON-able dict (registry + span aggregates
@@ -38,7 +40,7 @@ __all__ = [
     "inc", "set_gauge", "add_gauge", "observe", "span",
     "get_counter", "get_gauge", "get_registry", "get_tracer",
     "register_collector", "snapshot", "span_records", "dump_jsonl",
-    "reset", "current_span_id",
+    "reset", "current_span_id", "install_annotator",
 ]
 
 _registry = Registry()
@@ -72,8 +74,16 @@ def observe(name: str, value: float) -> None:
     _registry.observe(name, value)
 
 
-def span(name: str, **tags: Any) -> Span:
-    return _tracer.span(name, **tags)
+def span(name: str, leaf: bool = False, **tags: Any) -> Span:
+    """``leaf=True``: a phase that encloses no other leaf phase on its
+    thread; it is bridged into a running profiler trace (spans.py)."""
+    return _tracer.span(name, leaf=leaf, **tags)
+
+
+def install_annotator(factory) -> None:
+    """Called by the module that owns the process's profiler
+    (``ops/train.py`` for jax); this package imports none."""
+    _tracer.install_annotator(factory)
 
 
 def current_span_id():

@@ -10,6 +10,18 @@ Costs: two ``time`` calls plus one locked deque append per span — spans
 wrap phases (compile, epoch, persist, gather), never per-step device
 work.
 
+Leaf phases and the profiler's clock: ``span(name, leaf=True)`` marks a
+phase that encloses no other leaf phase on its thread. Such a span also
+enters the process's *annotator* (``Tracer.install_annotator``) — in a
+jax process ``jax.profiler.TraceAnnotation``, installed by
+``ops/train.py`` on import, so this package stays off jax — and the
+phase becomes an event on its thread's line of a running profiler
+trace, stamped by the profiler itself. Enclosing spans (``trial.total``,
+``trial_pack.train``, ``trial.persist``) are never bridged: a reader
+that names an idle gap after the host event covering most of it would
+name every gap after them. With no annotator installed a leaf span
+costs one attribute test more than a plain one.
+
 Exports:
   * per-name aggregates (count / total_s / min / max) for snapshots;
   * a bounded ring of finished span records for ``dump_jsonl`` — old
@@ -23,7 +35,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from rafiki_tpu.obs import context as _trace_context
 from rafiki_tpu.obs.journal import journal as _journal
@@ -32,13 +44,17 @@ from rafiki_tpu.obs.journal import journal as _journal
 class Span:
     """Context manager recording one timed, possibly-nested phase."""
 
-    __slots__ = ("name", "tags", "_tracer", "_t0", "_start_ts",
-                 "_parent", "_span_id", "_parent_id", "_trace_id")
+    __slots__ = ("name", "tags", "leaf", "_tracer", "_t0", "_start_ts",
+                 "_parent", "_span_id", "_parent_id", "_trace_id",
+                 "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, tags: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, tags: Dict[str, Any],
+                 leaf: bool = False):
         self._tracer = tracer
         self.name = name
         self.tags = tags
+        self.leaf = leaf
+        self._annotation = None
         self._t0 = 0.0
         self._start_ts = 0.0
         self._parent: Optional[str] = None
@@ -59,9 +75,24 @@ class Span:
         stack.append((self.name, self._span_id))
         self._start_ts = time.time()
         self._t0 = time.monotonic()
+        annotator = self._tracer._annotator if self.leaf else None
+        if annotator is not None:
+            # Telemetry never raises into its callers: a profiler that
+            # refuses an annotation costs the trace that one event.
+            try:
+                annotation = annotator(self.name)
+                annotation.__enter__()
+                self._annotation = annotation
+            except Exception:
+                pass
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(None, None, None)
+            except Exception:
+                pass
         dur = time.monotonic() - self._t0
         stack = self._tracer._stack()
         if stack and stack[-1][0] == self.name:
@@ -79,6 +110,15 @@ class Tracer:
         # name -> [count, total_s, min_s, max_s]
         self._agg: Dict[str, List[float]] = {}
         self._records: "deque[Dict[str, Any]]" = deque(maxlen=self._RECORD_CAP)
+        # name -> context manager; None until a module that owns a
+        # profiler installs one (see the module docstring).
+        self._annotator: Optional[Callable[[str], Any]] = None
+
+    def install_annotator(self, factory: Optional[Callable[[str], Any]]) -> None:
+        """``factory(name)`` returns a context manager that a leaf span
+        enters after its own clock starts and leaves before it stops.
+        None uninstalls."""
+        self._annotator = factory
 
     def _stack(self) -> list:
         """Per-thread stack of (name, span_id) tuples for open spans."""
@@ -93,14 +133,19 @@ class Tracer:
         stack = self._stack()
         return stack[-1][1] if stack else None
 
-    def span(self, name: str, **tags: Any) -> Span:
-        return Span(self, name, tags)
+    def span(self, name: str, leaf: bool = False, **tags: Any) -> Span:
+        return Span(self, name, tags, leaf)
 
     def _record(self, span: Span, dur_s: float, error: bool) -> None:
         rec: Dict[str, Any] = {
             "type": "span",
             "name": span.name,
             "ts": span._start_ts,
+            # ``ts`` is time.time() and may step; ``mono`` is the same
+            # start on time.monotonic(), so one thread's spans can be
+            # laid end to end (``mono`` + ``dur_s`` is the end).
+            "mono": span._t0,
+            "thread": threading.current_thread().name,
             "dur_s": round(dur_s, 6),
             "parent": span._parent,
             "span_id": span._span_id,
@@ -108,6 +153,8 @@ class Tracer:
         }
         if span._trace_id:
             rec["trace_id"] = span._trace_id
+        if span.leaf:
+            rec["leaf"] = True
         if span.tags:
             rec["tags"] = span.tags
         if error:

@@ -289,9 +289,10 @@ class TrainWorker:
             # much as a short trial's train+eval (device→host fetch +
             # serialize), so overlapping it nearly doubles short-trial
             # throughput.
-            self.advisor.feedback(score, knobs)
-            if self.curve is not None:
-                self.curve.note_scored(knobs, score)
+            with telemetry.span("trial.advisor_feedback", leaf=True):
+                self.advisor.feedback(score, knobs)
+                if self.curve is not None:
+                    self.curve.note_scored(knobs, score)
             telemetry.inc("worker.trials_succeeded")
             if self._saver is not None:
                 self._saver.submit(tid, model, score, sink)
@@ -459,11 +460,16 @@ class TrainWorker:
         async persistence is on)."""
         t0 = time.monotonic()
         try:
+            # Encloses the leaf phases persist.fetch / persist.write
+            # (JaxModel.dump_parameters has the first of each) and
+            # persist.mark; on the saver thread when persistence is async.
             with telemetry.span("trial.persist", trial_id=tid):
                 blob = model.dump_parameters()
-                params_id = self.params_store.save(blob)
-                self.store.mark_trial_as_completed(tid, score, params_id)
-                self.params_store.delete_checkpoints(tid)  # superseded
+                with telemetry.span("persist.write", leaf=True):
+                    params_id = self.params_store.save(blob)
+                with telemetry.span("persist.mark", leaf=True):
+                    self.store.mark_trial_as_completed(tid, score, params_id)
+                    self.params_store.delete_checkpoints(tid)  # superseded
             # Persist runs on the saver thread (no bound entity there),
             # so the charge names its trial explicitly.
             # lint: disable=RF007 — checkpoint_s ledger charge, not a span
@@ -547,7 +553,7 @@ class TrainWorker:
                     if drained:
                         break
                     continue
-                with telemetry.span("trial.advisor_propose",
+                with telemetry.span("trial.advisor_propose", leaf=True,
                                     worker_id=self.worker_id):
                     knobs = self.advisor.propose()
                 # Slot-claim happens atomically inside the trial-row
@@ -620,22 +626,25 @@ class PackedTrialRunner:
         drained). Proposals whose packing key matches no other run
         serially; same-key groups run packed."""
         w = self.w
-        with telemetry.span("trial.advisor_propose", worker_id=w.worker_id):
+        with telemetry.span("trial.advisor_propose", leaf=True,
+                            worker_id=w.worker_id):
             batch = getattr(w.advisor, "propose_batch", None)
             proposals = (batch(self.pack) if batch is not None
                          else [w.advisor.propose() for _ in range(self.pack)])
         buckets: Dict[Any, List[Knobs]] = {}
         order: List[Any] = []
-        for kn in proposals:
-            try:
-                m = w.model_class(**kn)
-                key = repr(m.packing_key(m._prepared_dataset(w.train_uri)))
-            except Exception:
-                key = ("unpackable", id(kn))  # unique → runs serially
-            if key not in buckets:
-                order.append(key)
-                buckets[key] = []
-            buckets[key].append(kn)
+        # One throw-away model a proposal, for its packing key.
+        with telemetry.span("trial_pack.bucket", leaf=True):
+            for kn in proposals:
+                try:
+                    m = w.model_class(**kn)
+                    key = repr(m.packing_key(m._prepared_dataset(w.train_uri)))
+                except Exception:
+                    key = ("unpackable", id(kn))  # unique → runs serially
+                if key not in buckets:
+                    order.append(key)
+                    buckets[key] = []
+                buckets[key].append(kn)
         ran = 0
         for key in order:
             knobs_list = buckets[key]
@@ -659,16 +668,17 @@ class PackedTrialRunner:
         # whatever the budget still allows.
         rows: List["tuple[str, Knobs]"] = []
         drained = False
-        for kn in knobs_list:
-            trial = w.store.create_trial(
-                w.sub_id, w.model_class.__name__, kn,
-                worker_id=w.worker_id,
-                shape_sig=knob_config_signature(knob_config, kn),
-                service_id=w.service_id, budget_max=budget_max)
-            if trial is None:
-                drained = True
-                break
-            rows.append((trial["id"], kn))
+        with telemetry.span("trial.claim", leaf=True):
+            for kn in knobs_list:
+                trial = w.store.create_trial(
+                    w.sub_id, w.model_class.__name__, kn,
+                    worker_id=w.worker_id,
+                    shape_sig=knob_config_signature(knob_config, kn),
+                    service_id=w.service_id, budget_max=budget_max)
+                if trial is None:
+                    drained = True
+                    break
+                rows.append((trial["id"], kn))
         if not rows:
             return 0, True
         if len(rows) == 1:
@@ -718,7 +728,7 @@ class PackedTrialRunner:
                     telemetry.span("trial_pack.total", worker_id=w.worker_id,
                                    k=k), \
                     ledger.entity(pack_entity), w._device_scope():
-                with telemetry.span("trial_pack.build"):
+                with telemetry.span("trial_pack.build", leaf=True):
                     models = [w.model_class(**kn) for _, kn in rows]
 
                 t_pack0 = time.monotonic()
@@ -862,6 +872,8 @@ class PackedTrialRunner:
                         # with every member's epoch-N snapshot durable.
                         chaos.hook("worker.epoch", key=w.worker_id)
 
+                # Encloses the leaf phases trial_pack.init and
+                # train.packed_epoch (model/base.py, ops/train.py).
                 with telemetry.span("trial_pack.train"):
                     histories = w.model_class.train_packed(
                         models, w.train_uri, on_epoch=heartbeat,
@@ -879,7 +891,7 @@ class PackedTrialRunner:
                 # would spend exactly the wall the kill saved.
                 healthy_idx = [i for i, v in enumerate(verdicts)
                                if v is None and i not in killed]
-                with telemetry.span("trial_pack.evaluate"):
+                with telemetry.span("trial_pack.evaluate", leaf=True):
                     healthy_scores = (w.model_class.evaluate_packed(
                         [models[i] for i in healthy_idx], w.val_uri)
                         if healthy_idx else [])
@@ -928,7 +940,7 @@ class PackedTrialRunner:
             def sink(entry, _tid=tid):
                 w.store.add_trial_log(_tid, entry)
 
-            with logger.capture(sink):
+            with telemetry.span("trial.log", leaf=True), logger.capture(sink):
                 logger.define_plot("Training", ["loss", "acc"], x_axis="epoch")
                 for pos, h in enumerate(histories[i]):
                     logger.log(**h)
@@ -983,9 +995,11 @@ class PackedTrialRunner:
                     pass
                 continue
             score = float(scores[i])
-            w.advisor.feedback(score, kn)
-            if w.curve is not None:
-                w.curve.note_scored(kn, score)
+            # (A Gaussian-process advisor refits on every call.)
+            with telemetry.span("trial.advisor_feedback", leaf=True):
+                w.advisor.feedback(score, kn)
+                if w.curve is not None:
+                    w.curve.note_scored(kn, score)
             telemetry.inc("worker.trials_succeeded")
             telemetry.inc("worker.packed_trials")
             if w._saver is not None:
@@ -1060,7 +1074,10 @@ class _AsyncSaver:
             self._thread = threading.Thread(
                 target=self._loop, name=self._thread.name, daemon=True)
             self._thread.start()
-        self._q.put((trial_id, model, score, sink))
+        # Persist's share of the critical path: the caller blocked behind
+        # the one pending save.
+        with telemetry.span("trial.persist_wait", leaf=True):
+            self._q.put((trial_id, model, score, sink))
 
     def _loop(self) -> None:
         import contextlib
@@ -1094,7 +1111,8 @@ class _AsyncSaver:
 
     def flush(self) -> None:
         """Block until all submitted saves are durable."""
-        self._q.join()
+        with telemetry.span("trial.persist_wait", leaf=True):
+            self._q.join()
 
     def close(self) -> None:
         self.flush()
