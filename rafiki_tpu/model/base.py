@@ -700,6 +700,36 @@ class JaxModel(BaseModel):
 
     # -- params --------------------------------------------------------------
 
+    @classmethod
+    def stage_packed_dump(cls, models: List["JaxModel"]) -> None:
+        """Between ``train_packed`` and the members' dumps: where the
+        pack ended as a whole and its members are live slice views, ask
+        it for ONE host copy of its stacked parameters, cast to what a
+        dump stores. Dispatched here (the worker calls this before the
+        evaluation, so the transfer runs under it), consumed by the
+        first ``dump_parameters`` of the round; detached members keep
+        their own fetch. A dispatch the device refuses costs the round
+        its shortcut, not its trials: every member then fetches alone."""
+        from rafiki_tpu.config import get_config
+        from rafiki_tpu.ops.train import PackedSliceLoop
+
+        packed = next((m._loop.packed for m in models
+                       if isinstance(m._loop, PackedSliceLoop)), None)
+        if packed is None:
+            return
+        cast = get_config().serving_params_dtype == "bfloat16"
+        with telemetry.span("persist.dispatch", leaf=True, k=packed.k):
+            try:
+                packed.stage_host_params(cast)
+            except Exception:
+                import traceback
+
+                from rafiki_tpu.utils.events import events
+
+                telemetry.inc("persist.dispatch_errors")
+                events.emit("persist_dispatch_failed", k=packed.k,
+                            error=traceback.format_exc(limit=3))
+
     def dump_parameters(self) -> bytes:
         from rafiki_tpu.config import get_config
         from rafiki_tpu.utils.serial import dump_pytree
@@ -707,21 +737,38 @@ class JaxModel(BaseModel):
         if self._loop is None:
             raise RuntimeError("No parameters to dump: model not trained/loaded")
         # Packed single-transfer dump (utils/serial.py): persisting is
-        # on the steady-state throughput path via the async saver, and
-        # per-leaf device_get costs ~2x the packed fetch.
+        # on the steady-state throughput path via the async saver.
+        host_copy = getattr(self._loop, "host_copy", None)
+        if host_copy is not None:
+            # A member of a finished pack round: the device's side is the
+            # round's one stacked copy, waited for by whichever member
+            # is dumped first; everything else is the host's.
+            telemetry.inc("persist.members_from_round_copy")
+            if not host_copy.fetched:
+                with telemetry.span("persist.fetch", leaf=True):
+                    host_copy.fetch()
+            with telemetry.span("persist.write", leaf=True):
+                return self._params_blob(dump_pytree(
+                    host_copy.member(self._loop.index),
+                    cast_f32_to_bf16=False))
+        telemetry.inc("persist.members_fetched_alone")
         cast = get_config().serving_params_dtype == "bfloat16"
-        # The device's side of a dump: this trial's leaves sliced out of
-        # the pack (``params`` of a slice view), the cast, device to host.
+        # The device's side of a dump of one's own: the leaves (sliced
+        # out of the pack where ``params`` is a slice view's), the cast,
+        # device to host.
         with telemetry.span("persist.fetch", leaf=True):
             packed = dump_pytree(self._loop.params, cast_f32_to_bf16=cast)
         # The host's side starts here; the worker's ``params_store.save``
         # is a second ``persist.write``.
         with telemetry.span("persist.write", leaf=True):
-            return pickle.dumps({
-                "arch": self._arch,
-                "packed": packed,
-                "dataset_meta": _portable_meta(self._dataset_meta),
-            })
+            return self._params_blob(packed)
+
+    def _params_blob(self, packed: bytes) -> bytes:
+        return pickle.dumps({
+            "arch": self._arch,
+            "packed": packed,
+            "dataset_meta": _portable_meta(self._dataset_meta),
+        })
 
     def load_parameters(self, blob: bytes) -> None:
         import jax

@@ -971,6 +971,17 @@ class PackedTrainLoop:
         self.state = (params, opt_state, jnp.zeros((self.k,), jnp.int32),
                       rngs, hyper_dev)
 
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, value) -> None:
+        self._state = value
+        # The finished round's host copy of ``state[0]`` (see
+        # stage_host_params): whatever replaces the state drops it.
+        self.host_copy = None
+
     def _set_program(self) -> None:
         """(Re)fetch the PackedProgram at the CURRENT width self.k —
         the packed cache key includes k, so a width change after
@@ -1059,6 +1070,17 @@ class PackedTrainLoop:
         """Trial i's full (params, opt_state, step, rng, hyper) state,
         shaped exactly like a serial TrainLoop's."""
         return jax.tree.map(lambda a: a[i], self.state)
+
+    def stage_host_params(self, cast_f32_to_bf16: bool) -> None:
+        """Dispatch ONE device-to-host copy of the stacked parameters,
+        cast to what a dump stores, for a pack that has finished
+        training: every member's dump then reads ``host_leaf[i]`` views
+        of it (``PackedSliceLoop.host_copy``) and touches no device.
+        Returns at dispatch; the copy runs under whatever the device is
+        given next and is waited for where the first dump consumes it."""
+        from rafiki_tpu.utils.serial import StackedHostCopy
+
+        self.host_copy = StackedHostCopy(self.state[0], cast_f32_to_bf16)
 
     def slice(self, i: int) -> "PackedSliceLoop":
         return PackedSliceLoop(self, i)
@@ -1263,6 +1285,13 @@ class PackedSliceLoop:
     @property
     def state(self):
         return self.packed.trial_state(self.index)
+
+    @property
+    def host_copy(self):
+        """The round's host copy of the pack's stacked parameters, or
+        None: a dump of this member reads its ``member(index)`` (a
+        serial ``TrainLoop`` has no such attribute and fetches its own)."""
+        return self.packed.host_copy
 
     def evaluate(self, dataset, batch_size: int) -> float:
         # The packed evaluator scores all k trials in one pass; callers

@@ -3,17 +3,23 @@
 Why this exists: persisting a trial's parameters is on the steady-state
 throughput path (the async saver overlaps it with the next trial's
 training, so trial wall-clock is max(compute, persist) — see
-worker/train.py). Measured on the v5e chip, fetching VGG16's params
-costs ~2.6s at full precision while the host-side serialization costs
-~0.1s: the device→host transfer is bandwidth-bound and dominates. So:
+worker/train.py). On the chip a dump is dispatch, not bandwidth: the
+host link moved 2.9 GB in 0.59-0.72 s while one VGG16 member's 30 MB,
+sliced, cast and copied leaf by leaf, took 0.12-0.26 s (chip runs of
+PR 25, PERF.md section 6). So:
 
   * float32 leaves are optionally cast to bfloat16 ON DEVICE by a
     single jit'd elementwise tree-map (compiles in <1s; a device-side
     concat into one buffer was also tried and fetches slightly faster
     warm, but its 43-way concat took XLA:TPU ~2 minutes to compile —
-    not worth it), halving the bytes over the wire (~0.9s for VGG16);
+    not worth it), halving the bytes over the wire;
   * leaf transfers are started with ``copy_to_host_async`` before any
     is consumed, so the host walk overlaps the device DMA;
+  * leaves that are already numpy arrays stay on the host: a finished
+    pack round casts and copies its STACKED parameters once
+    (``PackedTrainLoop.stage_host_params``) and every member is dumped
+    from ``host_leaf[i]`` views of that copy — the same bytes, since
+    the f32 -> bf16 rounding is elementwise — with no device work;
   * the host side writes raw little-endian buffers — no msgpack.
 
 The bf16 cast is the DEFAULT for serving blobs and loses nothing:
@@ -69,25 +75,70 @@ def _flat_items(tree: Any):
     return sorted(flat.items())
 
 
+def _start_host_copies(leaves) -> None:
+    """Kick off every device->host copy before any is consumed."""
+    for v in leaves:
+        if hasattr(v, "copy_to_host_async"):
+            v.copy_to_host_async()
+
+
 def dump_pytree(tree: Any, cast_f32_to_bf16: bool = True) -> bytes:
-    """Serialize a pytree of arrays: raw buffers, pipelined transfers."""
+    """Serialize a pytree of arrays: raw buffers, pipelined transfers.
+    A numpy leaf is written as it is (unless ``jnp.asarray`` would narrow
+    its 64-bit dtype): with ``cast_f32_to_bf16`` off a tree that is
+    already on the host touches no device."""
     if cast_f32_to_bf16:
         tree = _cast_tree_bf16(tree)
     items = _flat_items(tree)
     spec = []
     leaves = []
     for k, v in items:
-        v = jnp.asarray(v)
+        if not (isinstance(v, np.ndarray)
+                and v.dtype == jax.dtypes.canonicalize_dtype(v.dtype)):
+            v = jnp.asarray(v)
         leaves.append(v)
         spec.append({"k": k, "shape": list(v.shape), "dtype": v.dtype.name})
     header = json.dumps(spec).encode()
-    # Kick off every device->host copy before consuming any.
-    for v in leaves:
-        if hasattr(v, "copy_to_host_async"):
-            v.copy_to_host_async()
+    _start_host_copies(leaves)
     parts = [MAGIC, len(header).to_bytes(8, "little"), header]
     parts.extend(np.ascontiguousarray(np.asarray(v)).tobytes() for v in leaves)
     return b"".join(parts)
+
+
+class StackedHostCopy:
+    """One device-to-host copy of a stacked pytree (leading axis: the
+    member), for dumping every member from the host.
+
+    Construction is the device's whole part: one jitted cast of the
+    stacked leaves to what a dump stores, then ``copy_to_host_async`` on
+    each — dispatched, not waited for. ``fetch`` is the only place
+    anything waits for the device; after it the device arrays are let go
+    and ``member(i)`` hands out ``host_leaf[i]`` views, which
+    ``dump_pytree(..., cast_f32_to_bf16=False)`` writes to the bytes a
+    dump of the member's own device slices gives. A ``fetch`` that raises
+    leaves the copy unfetched, so the next member's raises too.
+    """
+
+    def __init__(self, stacked: Any, cast_f32_to_bf16: bool = True):
+        if cast_f32_to_bf16:
+            stacked = _cast_tree_bf16(stacked)
+        _start_host_copies(jax.tree.leaves(stacked))
+        self._device = stacked
+        self._host = None
+
+    @property
+    def fetched(self) -> bool:
+        return self._host is not None
+
+    def fetch(self) -> None:
+        if self._host is None:
+            self._host = jax.tree.map(np.asarray, self._device)
+            self._device = None
+
+    def member(self, i: int) -> Any:
+        self.fetch()
+        # ``a[i, ...]``: a 0-d array, not a numpy scalar, for a (k,) leaf.
+        return jax.tree.map(lambda a: a[i, ...], self._host)
 
 
 def is_packed(blob: bytes) -> bool:

@@ -891,6 +891,12 @@ class PackedTrialRunner:
                 # would spend exactly the wall the kill saved.
                 healthy_idx = [i for i, v in enumerate(verdicts)
                                if v is None and i not in killed]
+                # Persist's only device work for this round, dispatched
+                # now so that the copy of the stacked parameters runs
+                # under the evaluation and is over before the next
+                # round's epoch program is queued.
+                w.model_class.stage_packed_dump(
+                    [models[i] for i in healthy_idx])
                 with telemetry.span("trial_pack.evaluate", leaf=True):
                     healthy_scores = (w.model_class.evaluate_packed(
                         [models[i] for i in healthy_idx], w.val_uri)
@@ -1046,23 +1052,50 @@ class PackedTrialRunner:
         ledger.add("checkpoint_s", time.monotonic() - t0)
 
 
+def _round_copy_of(model: BaseModel):
+    """The host copy of a finished pack round that this member's dump
+    reads (``JaxModel.stage_packed_dump``), or None where the dump makes
+    a device fetch of its own: a serial trial, a member detached from
+    its pack, a model that is not a ``JaxModel``."""
+    return getattr(getattr(model, "_loop", None), "host_copy", None)
+
+
 class _AsyncSaver:
     """One background thread persisting trial parameters off the
-    critical path. Bounded to one pending save: at most two param sets
-    are alive at once (the one being written and the one training), so
-    memory stays flat; a slow disk degrades to serial, never unbounded.
+    critical path, bounded by what a pending save keeps alive.
+
+    A save that fetches from the device on its own keeps a parameter set
+    alive there, so one such save may be pending: at most two sets at
+    once (the one being written and the one training), as ever. The
+    members of a finished pack round are dumped from the round's one
+    host copy and do no device work, so they queue behind each other
+    freely: ``submit`` blocks such a member only while a save of an
+    EARLIER round (or a save of the other kind) is unwritten, so at most
+    two rounds' host copies are alive: the one being written and the one
+    just evaluated. Either way memory stays flat and a slow disk
+    degrades to serial, never unbounded.
     """
 
     def __init__(self, worker: "TrainWorker"):
-        import queue
+        import collections
         import threading
 
         self._worker = worker
-        self._q: "queue.Queue" = queue.Queue(maxsize=1)
+        # Saves submitted and not yet written, oldest first: (trial_id,
+        # model, score, sink, round copy). The head stays while it is
+        # being written (``_writing``); None stops the thread.
+        self._unwritten: "collections.deque" = collections.deque()
+        self._writing = False
+        self._cv = threading.Condition()
         self._thread = threading.Thread(target=self._loop,
                                         name=f"saver-{worker.worker_id}",
                                         daemon=True)
         self._thread.start()
+
+    def _must_wait(self, round_copy) -> bool:
+        if round_copy is None:
+            return len(self._unwritten) - self._writing >= 1
+        return any(item[4] is not round_copy for item in self._unwritten)
 
     def submit(self, trial_id: str, model: BaseModel, score: float,
                sink=None) -> None:
@@ -1074,49 +1107,68 @@ class _AsyncSaver:
             self._thread = threading.Thread(
                 target=self._loop, name=self._thread.name, daemon=True)
             self._thread.start()
+        round_copy = _round_copy_of(model)
         # Persist's share of the critical path: the caller blocked behind
-        # the one pending save.
-        with telemetry.span("trial.persist_wait", leaf=True):
-            self._q.put((trial_id, model, score, sink))
+        # the one pending save, or behind the round before its own.
+        with telemetry.span("trial.persist_wait", leaf=True), self._cv:
+            self._cv.wait_for(lambda: not self._must_wait(round_copy))
+            self._unwritten.append((trial_id, model, score, sink, round_copy))
+            self._cv.notify_all()
 
     def _loop(self) -> None:
+        while self._save_next():
+            pass
+
+    def _save_next(self) -> bool:
+        """Write the oldest save. A call of its own so that what it holds
+        (the model, the round's host copy) goes when it returns: an idle
+        saver keeps nothing alive."""
         import contextlib
 
-        while True:
-            item = self._q.get()
+        with self._cv:
+            self._cv.wait_for(lambda: self._unwritten)
+            item = self._unwritten[0]
             if item is None:
-                self._q.task_done()
-                return
-            trial_id, model, score, sink = item
+                self._unwritten.popleft()
+                return False
+            self._writing = True
+            self._cv.notify_all()
+        trial_id, model, score, sink, _round_copy = item
+        try:
+            # Re-enter the trial's log capture on this thread so
+            # logger.log() calls during dump still land in TrialLog.
+            scope = (logger.capture(sink) if sink is not None
+                     else contextlib.nullcontext())
+            with scope:
+                self._worker._persist(trial_id, model, score)
+        except Exception:
+            # _persist already contains failures; the saver thread
+            # must never die — but what it absorbs gets counted
+            # (RF006: a silent swallow in a long-running loop hides
+            # every failure the loop will ever have).
+            telemetry.inc("worker.saver_errors")
+        finally:
             try:
-                # Re-enter the trial's log capture on this thread so
-                # logger.log() calls during dump still land in TrialLog.
-                scope = (logger.capture(sink) if sink is not None
-                         else contextlib.nullcontext())
-                with scope:
-                    self._worker._persist(trial_id, model, score)
+                model.destroy()
+            # lint: disable=RF006 — a throwing user destroy() must not kill the saver; nothing to recover
             except Exception:
-                # _persist already contains failures; the saver thread
-                # must never die — but what it absorbs gets counted
-                # (RF006: a silent swallow in a long-running loop hides
-                # every failure the loop will ever have).
-                telemetry.inc("worker.saver_errors")
-            finally:
-                try:
-                    model.destroy()
-                # lint: disable=RF006 — a throwing user destroy() must not kill the saver; nothing to recover
-                except Exception:
-                    pass
-                self._q.task_done()
+                pass
+            with self._cv:
+                self._unwritten.popleft()
+                self._writing = False
+                self._cv.notify_all()
+        return True
 
     def flush(self) -> None:
         """Block until all submitted saves are durable."""
-        with telemetry.span("trial.persist_wait", leaf=True):
-            self._q.join()
+        with telemetry.span("trial.persist_wait", leaf=True), self._cv:
+            self._cv.wait_for(lambda: not self._unwritten)
 
     def close(self) -> None:
         self.flush()
-        self._q.put(None)
+        with self._cv:
+            self._unwritten.append(None)
+            self._cv.notify_all()
         self._thread.join(timeout=10)
 
 

@@ -147,6 +147,29 @@ def test_packed_k4_feedforward_epoch_compiles_for_v5e(one_chip):
     assert _peak_bytes(compiled) < HBM_BYTES
 
 
+def test_a_rounds_stacked_cast_of_16_vgg16s_compiles_for_v5e(one_chip):
+    """Persist's one device program a pack round (ISSUE 26): the stacked
+    parameters of the benchmark cell's 16 VGG16s cast to the stored
+    bfloat16 in one call, the output half the argument's bytes."""
+    from rafiki_tpu.ops.train import PackedProgram
+    from rafiki_tpu.utils.serial import _cast_tree_bf16
+
+    k = 16
+    fns = _vgg16()._loop_fns(10, (32, 32, 3))
+    prog = PackedProgram(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                         fns["optimizer"], k)
+    rngs = jnp.stack([jax.random.PRNGKey(i) for i in range(k)])
+    params, _opt_state = jax.eval_shape(prog.init, rngs)
+    assert {a.shape[0] for a in jax.tree.leaves(params)} == {k}
+    compiled = _cast_tree_bf16.lower(_on(one_chip, params)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes > 16 * 14.9e6 * 4
+    # (to a tile's padding: 0.4808 GB out for 0.9616 GB in)
+    assert ma.output_size_in_bytes == pytest.approx(
+        ma.argument_size_in_bytes / 2, rel=1e-3)
+    assert _peak_bytes(compiled) < HBM_BYTES
+
+
 def test_sharded_width4_transformer_epoch_compiles_for_v5e(topo):
     """The sharded lane's program at width 4: the Transformer template's
     largest setting on a four-device ("shard",) mesh of the described

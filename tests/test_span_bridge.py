@@ -163,12 +163,13 @@ PACK, ROUNDS = 4, 3
 
 #: The hand-over phases of the worker's thread (ISSUE 25's table, and the
 #: health plane's pre-epoch copy of the state, which the CPU rehearsal
-#: found uncovered), and the saver's.
+#: found uncovered; ISSUE 26's dispatch of a round's one copy of the
+#: stacked parameters, before the evaluation), and the saver's.
 WORKER_PHASES = {"trial.log", "trial.advisor_feedback", "trial.persist_wait",
                  "trial.advisor_propose", "trial_pack.bucket", "trial.claim",
                  "trial_pack.build", "trial_pack.init",
                  "train.health_snapshot", "train.packed_epoch",
-                 "trial_pack.evaluate"}
+                 "persist.dispatch", "trial_pack.evaluate"}
 SAVER_PHASES = {"persist.fetch", "persist.write", "persist.mark"}
 
 
