@@ -21,6 +21,7 @@ class UserType(str, enum.Enum):
 class TaskType(str, enum.Enum):
     IMAGE_CLASSIFICATION = "IMAGE_CLASSIFICATION"
     POS_TAGGING = "POS_TAGGING"
+    LANGUAGE_MODELING = "LANGUAGE_MODELING"
     GENERIC = "GENERIC"
 
 

@@ -283,7 +283,8 @@ class JaxModel(BaseModel):
         self._loop = TrainLoop(
             fns["init_fn"], fns["apply_eval"], fns["loss_fn"], fns["optimizer"],
             mesh=self._mesh, seed=self._seed, hyper=fns["hyper"],
-            program_key=fns["program_key"])
+            program_key=fns["program_key"], eval_count=fns.get("eval_count"),
+            epoch_program=self.epoch_program())
         self._arch = (num_classes, tuple(input_shape))
 
     def _input_dtype(self):
@@ -367,6 +368,19 @@ class JaxModel(BaseModel):
         with a custom ``preprocess`` (whose output may depend on
         per-trial knobs) are excluded."""
         return cls.preprocess is JaxModel.preprocess
+
+    @classmethod
+    def epoch_program(cls) -> bool:
+        """Whether an epoch over a data set that fits the device runs as
+        ONE program, a scan over its steps (the default: it removes a
+        dispatch and a host feed a step). A template whose step takes
+        seconds gains nothing from that and returns False: its epochs
+        then run step by step through one compiled step, so that trials
+        whose train sets differ in length share an executable that takes
+        minutes to build, and the health plane's copy of the whole train
+        state to the host before the epoch (the replay capsule's, which
+        the step-by-step path does not write) is not made."""
+        return True
 
     def shard_plan(self, ds: Dataset):
         """Group-sharding plan for one trial of this template, or None
@@ -730,6 +744,38 @@ class JaxModel(BaseModel):
                 events.emit("persist_dispatch_failed", k=packed.k,
                             error=traceback.format_exc(limit=3))
 
+    def release_train_state(self) -> None:
+        """Between a serial trial's evaluation and its dump (the worker
+        calls it once the score is in): where the next trial could not
+        train beside this one's state, the dump is staged now
+        (``TrainLoop.release_to_host``: one cast copy on its way to the
+        host) and the state let go, so the saver's pending dump holds a
+        bfloat16 copy and not a whole state beside the next trial's.
+        Decided by what the code sees: the device's peaks so far (this
+        trial's state and what its programs reserved for gradients and
+        activations) plus one more state against the device's limit. Smaller models keep their
+        state and the saver fetches on its own, as ever; a device that
+        reports no limit (the CPU) stages nothing."""
+        import jax
+
+        from rafiki_tpu.config import get_config
+        from rafiki_tpu.ops.train import TrainLoop
+
+        loop = self._loop
+        if not isinstance(loop, TrainLoop) or loop.state is None \
+                or loop.plan.mesh is not None:
+            return
+        leaf = jax.tree.leaves(loop.state[0])[0]
+        stats = next(iter(leaf.devices())).memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        # (a program's temporaries are reserved, not "in use": both peaks)
+        peak = stats.get("peak_bytes_in_use", 0) + stats.get("peak_bytes_reserved", 0)
+        if not limit or peak + loop.state_bytes() <= limit:
+            return
+        cast = get_config().serving_params_dtype == "bfloat16"
+        with telemetry.span("persist.dispatch", leaf=True):
+            loop.release_to_host(cast)
+
     def dump_parameters(self) -> bytes:
         from rafiki_tpu.config import get_config
         from rafiki_tpu.utils.serial import dump_pytree
@@ -742,15 +788,18 @@ class JaxModel(BaseModel):
         if host_copy is not None:
             # A member of a finished pack round: the device's side is the
             # round's one stacked copy, waited for by whichever member
-            # is dumped first; everything else is the host's.
-            telemetry.inc("persist.members_from_round_copy")
+            # is dumped first; everything else is the host's. (Or a
+            # serial trial whose state was let go for its staged copy:
+            # ``release_train_state``; it has no index.)
+            index = getattr(self._loop, "index", None)
+            telemetry.inc("persist.members_from_round_copy" if index is not None
+                          else "persist.serial_from_staged_copy")
             if not host_copy.fetched:
                 with telemetry.span("persist.fetch", leaf=True):
                     host_copy.fetch()
             with telemetry.span("persist.write", leaf=True):
                 return self._params_blob(dump_pytree(
-                    host_copy.member(self._loop.index),
-                    cast_f32_to_bf16=False))
+                    host_copy.member(index), cast_f32_to_bf16=False))
         telemetry.inc("persist.members_fetched_alone")
         cast = get_config().serving_params_dtype == "bfloat16"
         # The device's side of a dump of one's own: the leaves (sliced
@@ -764,6 +813,7 @@ class JaxModel(BaseModel):
             return self._params_blob(packed)
 
     def _params_blob(self, packed: bytes) -> bytes:
+        telemetry.inc("persist.blob_bytes", len(packed))
         return pickle.dumps({
             "arch": self._arch,
             "packed": packed,

@@ -21,6 +21,7 @@ TPU-native design:
 URI schemes:
   synthetic://images?classes=10&w=28&h=28&c=1&n=2048&seed=0
   synthetic://corpus?vocab=200&tags=10&n=512&len=24&seed=0
+  synthetic://tokens?vocab=20480&n=16&len=8192&seed=0
   /path/to/dataset.zip        (zip of images + images.csv, reference format)
   /path/to/dataset.npz        (npz with arrays x, y)
   file:///path/to/dataset.zip
@@ -197,6 +198,37 @@ def synthetic_text(vocab=80, classes=5, n=256, length=16, seed=0, noise=0.1,
     x = np.where(sig, sig_tok, noise_tok).astype(np.int32)
     return Dataset(x, y, classes,
                    meta={"kind": "text", "synthetic": True, "vocab": vocab})
+
+
+def synthetic_tokens(vocab=256, n=16, length=96, seed=0, follow=0.5,
+                     dist=0) -> Dataset:
+    """A token stream for next-token language modelling: ``n`` documents
+    of ``length`` tokens over ids 0..vocab-1, one a sequence (no packing,
+    no mask). x is a document's tokens and y the token that follows each
+    (``length + 1`` are drawn), so every position is scored; ``classes``
+    is the vocabulary.
+
+    Learnable at two depths. Unigram: a document's free draws follow a
+    Zipf law over a seeded permutation of the ids (``dist``), so the most
+    frequent id alone is right about 1/H(vocab) of the time, and a few
+    optimizer steps find it. Bigram: with probability ``follow`` a token
+    is a fixed function of the one before it (a seeded permutation of the
+    ids, ``dist`` again), which a model learns only as far as it has seen
+    the pairs. ``seed`` seeds the draws alone, as in synthetic_images."""
+    law = np.random.default_rng(dist + 7_000_003)
+    rank_of = law.permutation(vocab)            # id of each Zipf rank
+    successor = law.permutation(vocab)          # the bigram rule
+    p = 1.0 / np.arange(1, vocab + 1, dtype=np.float64)
+    p /= p.sum()
+    rng = np.random.default_rng(seed + 1_000_003)
+    free = rank_of[rng.choice(vocab, size=(n, length + 1), p=p)]
+    bound = rng.uniform(size=(n, length + 1)) < follow
+    toks = free.copy()
+    for t in range(1, length + 1):
+        toks[:, t] = np.where(bound[:, t], successor[toks[:, t - 1]], free[:, t])
+    toks = toks.astype(np.int32)
+    return Dataset(toks[:, :-1].copy(), toks[:, 1:].copy(), int(vocab),
+                   meta={"kind": "tokens", "synthetic": True, "vocab": int(vocab)})
 
 
 # ---------------------------------------------------------------------------
@@ -402,6 +434,12 @@ class DatasetUtils:
                     kw["length"] = kw.pop("len")
                 return synthetic_text(**{k: kw[k] for k in kw if k in
                                          ("vocab", "classes", "n", "length", "seed", "noise", "dist")})
+            if parsed.netloc == "tokens":
+                kw = dict(q)
+                if "len" in kw:
+                    kw["length"] = kw.pop("len")
+                return synthetic_tokens(**{k: kw[k] for k in kw if k in
+                                           ("vocab", "n", "length", "seed", "follow", "dist")})
             raise ValueError(f"Unknown synthetic dataset: {parsed.netloc!r}")
         path = _resolve_path(uri)
         if path.endswith(".npz"):

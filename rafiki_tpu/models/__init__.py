@@ -9,6 +9,10 @@ examples/models/ (SURVEY.md §2 "Example models", unverified paths):
   PosBigramHmm ← BigramHmm.py      (bigram HMM POS tagger)
   Transformer  — no reference analog: text-classifier encoder, the
                  zoo's sharded-lane citizen (docs/sharding.md)
+  KimiLinear   — no reference analog: a hybrid linear-attention (gated
+                 delta rule) / latent-attention sparse-expert language
+                 model, one chip's share of an expert-parallel
+                 deployment; the zoo's language-modelling citizen
 """
 
 from rafiki_tpu.models.ff import FeedForward
@@ -30,6 +34,7 @@ MODEL_REGISTRY = {
     "PosBiLstm": ("rafiki_tpu.models.pos_bilstm", "PosBiLstm"),
     "PosBigramHmm": ("rafiki_tpu.models.pos_hmm", "PosBigramHmm"),
     "Transformer": ("rafiki_tpu.models.transformer", "Transformer"),
+    "KimiLinear": ("rafiki_tpu.models.kimi_linear", "KimiLinear"),
 }
 
 

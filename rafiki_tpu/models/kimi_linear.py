@@ -1,0 +1,635 @@
+"""Hybrid linear-attention sparse-expert language model template.
+
+No reference analog: the reference zoo stops at CNNs and a BiLSTM
+tagger. The block is the one published for Kimi Linear
+(arXiv:2510.26692; ``model_type`` ``kimi_linear``): pre-norm residual
+layers whose token mixer is either *KDA* — a gated delta-rule linear
+attention with a per-channel decay — or *MLA* — multi-head latent
+attention without positions — and whose feed-forward part is dense in
+the leading layers and a sigmoid top-k router over sparse experts, plus
+one shared expert, in the rest.
+
+What the template adds to the zoo, by mechanism:
+
+* ``kda_chunked``: the delta rule in its chunked (WY / UT-transform)
+  form. Within a chunk the rank-one updates are folded into one unit
+  lower-triangular system, inverted by block substitution in log depth
+  (matrix products only); across chunks a ``lax.scan`` carries the
+  ``[d_k, d_v]`` state. (The recurrence token by token is the benchmark's
+  reference, ``benchmark/references/kimi_linear.py``; tests compare the two.)
+* ``mla_attention``: causal softmax attention over latent-projected
+  keys and values, computed a block of queries at a time so that no
+  ``[T, T]`` score array outlives its block.
+* ``expert_layer``: the router keeps every output and its experts per
+  token; the layer is *told which expert ids it holds* and computes
+  their part of the result for the tokens routed to them, without a
+  capacity limit: the routed rows are sorted by expert and multiplied as
+  one ragged product. What absent experts would add is left out.
+* the loss and the score are taken a block of the sequence at a time, so
+  the ``[tokens, vocab]`` logits never exist whole; every layer is
+  recomputed in the backward pass (``nn.remat``).
+
+TPU notes: matrix products take bfloat16 operands and accumulate in
+float32; parameters, the recurrent state, decays, normalisations, the
+router and the softmaxes stay float32. Sequences are fixed length, one
+document a sequence (`synthetic://tokens`). A trial of this template
+fills a chip by itself, so it is not packable; it runs in the serial
+lane.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from rafiki_tpu.model.base import JaxModel
+from rafiki_tpu.model.knobs import FixedKnob, FloatKnob
+
+F32 = jnp.float32
+L2_EPS = 1e-6        # l2norm's epsilon (assumed; FLA's kernels use 1e-6)
+EXP_CLIP = 80.0      # |log-decay| a chunk half may span before float32 overflows
+LOSS_BLOCK = 1024    # tokens of a sequence whose logits exist at one time
+ATTN_BLOCK = 256     # queries whose scores exist at one time
+KDA_GROUP = 16       # chunks whose insides exist at one time
+BF16 = jnp.bfloat16
+
+# Named scopes of the block (docs/telemetry.md), beside ops/train.py's
+# ``rafiki.*`` ones. Metadata only.
+SCOPE_KDA, SCOPE_MLA = "kda", "mla"
+SCOPE_ROUTE, SCOPE_EXPERTS, SCOPE_SHARED = "moe.route", "moe.experts", "moe.shared"
+SCOPE_LM_LOSS = "lm.loss"
+
+
+def _mm(a, b, spec: str, out=F32):
+    """A matrix product with bfloat16 operands, accumulated in float32;
+    ``out``: the dtype it is kept in (bfloat16 for the wide activations
+    that live until the backward pass)."""
+    return jnp.einsum(spec, a.astype(BF16), b.astype(BF16),
+                      preferred_element_type=F32).astype(out)
+
+
+def rms_norm(x, scale, eps: float):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def l2norm(x):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+# -- KDA: the gated delta rule -------------------------------------------------
+
+def unit_lower_inverse(A):
+    """(I + A)^-1 for strictly lower-triangular ``A`` [..., C, C], C a
+    power of two: block forward substitution in log2(C) levels. A level
+    pairs the inverted diagonal blocks of the one before,
+    [[X, 0], [M, Y]]^-1 = [[X^-1, 0], [-Y^-1 M X^-1, Y^-1]], so the whole
+    is matrix products (float32 at full precision) and no loop over rows."""
+    C = A.shape[-1]
+    lead = A.shape[:-2]
+    hi = jax.lax.Precision.HIGHEST
+    inv = jnp.ones(lead + (C, 1, 1), F32)           # 1x1 blocks of the unit diagonal
+    b = 1
+    while b < C:
+        nb = C // (2 * b)
+        blocks = jnp.moveaxis(jnp.diagonal(
+            A.reshape(lead + (nb, 2 * b, nb, 2 * b)), axis1=-4, axis2=-2), -1, -3)
+        m21 = blocks[..., b:, :b]
+        pair = inv.reshape(lead + (nb, 2, b, b))
+        x, y = pair[..., 0, :, :], pair[..., 1, :, :]
+        low = -jnp.matmul(jnp.matmul(y, m21, precision=hi), x, precision=hi)
+        top = jnp.concatenate([x, jnp.zeros_like(x)], axis=-1)
+        inv = jnp.concatenate([top, jnp.concatenate([low, y], axis=-1)], axis=-2)
+        b *= 2
+    return inv.reshape(lead + (C, C))
+
+
+def kda_chunked(q, k, v, a, beta, chunk: int = 64):
+    """S_t = (I - b_t k_t k_t^T) Diag(exp a_t) S_(t-1) + b_t k_t v_t^T,
+    o_t = S_t^T q_t (``q``, ``k``, ``a``: [B, T, H, dk]; ``v``: [B, T, H, dv];
+    ``beta``: [B, T, H]) in chunks of ``chunk`` tokens (a power of two). Within a chunk, with g the running sum of ``a``: the updates
+    u_t = b_t (v_t - S_(t-1)^T Diag(alpha_t) k_t) solve (I + A) U = b V -
+    (b K e^g) S_0, where A_ts = b_t sum_d k_td k_sd e^(g_td - g_sd) for
+    s < t; then o = (Q e^g) S_0 + tril((Q e^g)(K e^-g)^T) U and
+    S_C = e^(g_C) S_0 + (K e^(g_C - g))^T U. The factors e^g and e^-g are
+    taken about the chunk's middle token and clipped at e^80, which is
+    exact while half a chunk's summed log-decay stays above -80 (2.5 a
+    token at chunk 64; the initial range ends at 1.6). A ragged tail is
+    padded with tokens that leave the state as it is (b = 0, a = 0)."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = int(chunk)
+    pad = (-T) % C
+    if pad:
+        q, k, v, a = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for x in (q, k, v, a))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (T + pad) // C
+
+    def chunks(x):  # [B, T, H, d] -> [n, B, H, C, d]
+        return jnp.transpose(x.reshape((B, n, C, H) + x.shape[3:]),
+                             (1, 0, 3, 2) + tuple(range(4, x.ndim + 1)))
+
+    q, k, v, a = (chunks(x) for x in (q, k, v, a))
+    beta = chunks(beta[..., None])                         # [n, B, H, C, 1]
+    row, col = np.arange(C)[:, None], np.arange(C)[None, :]
+
+    def step(S, xs):
+        w, u0, aqk, qe, kt, dc = xs
+        u = u0 - _mm(w, S, "bhtk,bhkv->bhtv")
+        o = _mm(qe, S, "bhtk,bhkv->bhtv") + _mm(aqk, u, "bhts,bhsv->bhtv")
+        S = S * dc + _mm(kt, u, "bhtk,bhtv->bhkv")
+        return S, o
+
+    @jax.checkpoint
+    def group(S, xs):
+        """``KDA_GROUP`` chunks: what is parallel over chunks for all of
+        them at once, then the state through them one by one. Recomputed
+        in the backward pass, so only the state at group boundaries and
+        the layer's q, k, v, a, beta outlive it."""
+        q, k, v, a, beta = (x.astype(F32) for x in xs)
+        g = jnp.cumsum(a, axis=-2)                         # <= 0, falling
+        mid = g[..., C // 2 - 1: C // 2, :]
+        up = jnp.exp(jnp.minimum(g - mid, EXP_CLIP))       # e^(g_t - g_mid)
+        down = jnp.exp(jnp.minimum(mid - g, EXP_CLIP))     # e^(g_mid - g_s)
+        eg = jnp.exp(g)
+        k_down = k * down
+        A = jnp.where(row > col, _mm(beta * k * up, k_down, "...td,...sd->...ts"), 0.0)
+        Tm = unit_lower_inverse(A)
+        W = _mm(Tm, beta * k * eg, "...ts,...sd->...td")   # [G, B, H, C, dk]
+        U0 = _mm(Tm, beta * v, "...ts,...sd->...td")       # [G, B, H, C, dv]
+        Aqk = jnp.where(row >= col, _mm(q * up, k_down, "...td,...sd->...ts"), 0.0)
+        g_last = g[..., -1:, :]
+        k_tail = k * jnp.exp(g_last - g)                   # e^(g_C - g_s) <= 1
+        decay = jnp.swapaxes(jnp.exp(g_last), -1, -2)      # [G, B, H, dk, 1]
+        return jax.lax.scan(step, S, (W, U0, Aqk, q * eg, k_tail, decay))
+
+    G = next(x for x in range(min(KDA_GROUP, n), 0, -1) if n % x == 0)
+    grouped = tuple(x.reshape((n // G, G) + x.shape[1:]) for x in (q, k, v, a, beta))
+    _S, o = jax.lax.scan(group, jnp.zeros((B, H, dk, dv), F32), grouped)
+    o = jnp.transpose(o.reshape((n,) + o.shape[2:]), (1, 0, 3, 2, 4))
+    return o.reshape(B, n * C, H, dv)[:, :T]
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution: ``x`` [B, T, D], ``w`` [K, D]; tap
+    K-1 is the current token's."""
+    K = w.shape[0]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    T = x.shape[1]
+    return sum(xp[:, j: j + T, :] * w[j] for j in range(K))
+
+
+# -- MLA: latent attention, no positions ------------------------------------------
+
+def mla_attention(q, k, v, block: int = ATTN_BLOCK, segments: int = 4):
+    """Causal softmax(q k^T / sqrt(d)) v. ``q``, ``k``: [B, T, H, d];
+    ``v``: [B, T, H, dv]. The sequence is cut into ``segments``; a segment's
+    queries see the keys up to the segment's end, a block of ``block``
+    queries at a time, one after the other (``lax.map``), each block
+    recomputed in the backward pass: one block's scores exist at a time."""
+    B, T, H, d = q.shape
+    scale = 1.0 / np.sqrt(d)
+
+    @jax.checkpoint
+    def one(qb, kb, vb, first):
+        s = _mm(qb, kb, "bthd,bshd->bhts") * scale
+        t_pos = first + jnp.arange(qb.shape[1])[:, None]
+        s = jnp.where(t_pos >= jnp.arange(kb.shape[1])[None, :], s, -1e30)
+        return _mm(jax.nn.softmax(s, axis=-1), vb, "bhts,bshd->bthd", BF16)
+
+    if T % (segments * block):
+        segments, block = 1, T      # a size no segment divides: one block
+    seg = T // segments
+    outs = []
+    for i in range(segments):
+        end = (i + 1) * seg
+        kb, vb = k[:, :end], v[:, :end]
+        qs = q[:, i * seg: end].reshape(B, seg // block, block, H, d)
+        firsts = i * seg + jnp.arange(seg // block) * block
+        o = jax.lax.map(lambda xs: one(xs[0], kb, vb, xs[1]),
+                        (jnp.moveaxis(qs, 1, 0), firsts))
+        outs.append(jnp.moveaxis(o, 0, 1).reshape(B, seg, H, v.shape[-1]))
+    return jnp.concatenate(outs, axis=1)
+
+
+# -- sparse experts ------------------------------------------------------------
+
+def route(x, w_router, bias, top_k: int, scaling: float):
+    """Sigmoid scores over every expert; the top ``top_k`` of score +
+    bias are selected, and weighted by their scores renormalised over
+    the selected set, times ``scaling``. Returns (ids [N, k], weights
+    [N, k]) in float32."""
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(F32), w_router.astype(F32),
+                                  precision=jax.lax.Precision.HIGHEST))
+    _vals, ids = jax.lax.top_k(s + bias, top_k)
+    picked = jnp.take_along_axis(s, ids, axis=-1)
+    return ids, scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+
+def expert_layer(x, ids, weights, held: Sequence[int], w_gate, w_up, w_down):
+    """The held experts' part of the routed result. ``x`` [N, D]; ``ids``
+    and ``weights`` [N, k] over all experts; ``held`` the expert ids whose
+    weights ``w_gate``/``w_up`` [E, D, F] and ``w_down`` [E, F, D] are here,
+    in that order. One choice of every token at a time (k passes, each
+    recomputed in the backward pass): the tokens are sorted by the local
+    expert their choice names, those that name an absent one last and in
+    no group, and the three products run ragged over the groups. A pass
+    has room for every token, so there is no capacity and no dropped
+    token, and a ragged product costs what its groups hold. Returns
+    (y [N, D], rows a held expert took [E])."""
+    N, D = x.shape
+    E = len(held)
+    top = int(max(held)) + 1
+    local = np.full((top + 1,), E, np.int32)               # E: absent
+    local[list(held)] = np.arange(E)
+    local = jnp.asarray(local)
+    xb = x.astype(BF16)
+    wg, wu, wd = (w.astype(BF16) for w in (w_gate, w_up, w_down))
+
+    @jax.checkpoint
+    def one_choice(carry, choice):
+        y, load = carry
+        ids_j, w_j = choice
+        expert = jnp.take(local, jnp.minimum(ids_j, top))
+        order = jnp.argsort(expert)
+        sizes = jnp.sum(expert[:, None] == jnp.arange(E)[None, :], axis=0,
+                        dtype=jnp.int32)
+        rd = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                               preferred_element_type=F32)
+        # A ragged product leaves the rows past its groups as they were in
+        # memory (zero on the CPU, anything on the TPU), in its result and in
+        # the gradient it hands back for its left operand alike. Every such
+        # operand and result is therefore selected by ``live`` (a select, not
+        # a product: it stops a NaN), so that neither a value nor a gradient
+        # of a token whose choice is absent comes from there.
+        live = (jnp.arange(N) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(live, jnp.take(xb, order, axis=0), 0)
+        h = jnp.where(live, jax.nn.silu(rd(xs, wg)) * rd(xs, wu), 0.0).astype(BF16)
+        out = jnp.where(live, rd(h, wd), 0.0)
+        # back in token order, then the router's weight (absent: no row, 0)
+        out = jnp.take(out, jnp.argsort(order), axis=0) * w_j[:, None]
+        return (y + out, load + sizes), None
+
+    (y, load), _ = jax.lax.scan(
+        one_choice, (jnp.zeros((N, D), F32), jnp.zeros((E,), jnp.int32)),
+        (ids.T, weights.T))
+    return y, load
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    gate = _mm(x, w_gate, "...d,df->...f", BF16).astype(F32)
+    h = jax.nn.silu(gate) * _mm(x, w_up, "...d,df->...f", BF16).astype(F32)
+    return _mm(h, w_down, "...f,fd->...d")
+
+
+# -- the module ----------------------------------------------------------------
+
+def _dense_init(scale: float = 0.02):
+    return nn.initializers.normal(scale)
+
+
+class _Kda(nn.Module):
+    """The KDA mixer. Each wide branch (q, k, v, the decay, the gate, the
+    output) is a function of its own that is recomputed in the backward
+    pass, so that what lives through the layer's backward is the core's
+    five inputs and not every [tokens, heads x d] intermediate."""
+
+    heads: int
+    head_dim: int
+    conv: int
+    chunk: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        D, H, d = x.shape[-1], self.heads, self.head_dim
+        B, T, _ = x.shape
+        eps = self.eps
+        p = lambda name, shape, init=_dense_init(): self.param(name, init, shape)
+
+        @functools.partial(jax.checkpoint, static_argnums=(3, 4))
+        def branch(x, w, conv_w, normed: bool, scale: float):
+            y = causal_conv(_mm(x, w, "btd,de->bte", BF16).astype(F32), conv_w)
+            y = jax.nn.silu(y).reshape(B, T, H, d)
+            return ((l2norm(y) * scale) if normed else y).astype(BF16)
+
+        @jax.checkpoint
+        def decay(x, w1, w2, a_log, dt_bias):
+            f = _mm(_mm(x, w1, "btd,de->bte", BF16), w2, "bte,ef->btf")
+            return (-jnp.exp(a_log)[:, None]
+                    * jax.nn.softplus(f + dt_bias).reshape(B, T, H, d))
+
+        @jax.checkpoint
+        def output(o, x, w1, w2, o_norm, w_o):
+            gate = jax.nn.sigmoid(_mm(_mm(x, w1, "btd,de->bte", BF16), w2, "bte,ef->btf"))
+            o = rms_norm(o, o_norm, eps).reshape(B, T, H * d) * gate
+            return _mm(o, w_o, "bte,ed->btd", BF16)
+
+        with jax.named_scope(SCOPE_KDA):
+            conv_init = nn.initializers.normal(1.0 / np.sqrt(self.conv))
+            q, k, v = (branch(x, p(f"w_{n}", (D, H * d)),
+                              p(f"conv_{n}", (self.conv, H * d), conv_init), normed, scale)
+                       for n, normed, scale in (("q", True, d ** -0.5), ("k", True, 1.0),
+                                                ("v", False, 1.0)))
+            w_f1, w_f2 = p("w_f1", (D, d)), p("w_f2", (d, H * d))
+            # A in [1, 16], dt in [1e-3, 1e-1] (the Mamba-2 ranges FLA uses)
+            a_log = p("A_log", (H,), lambda key, s: jnp.log(
+                jax.random.uniform(key, s, F32, 1.0, 16.0)))
+
+            def dt_init(key, s):
+                dt = jnp.exp(jax.random.uniform(key, s, F32, np.log(1e-3), np.log(1e-1)))
+                return dt + jnp.log(-jnp.expm1(-dt))      # softplus^-1(dt)
+
+            a = decay(x, w_f1, w_f2, a_log, p("dt_bias", (H * d,), dt_init))
+            beta = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x.astype(F32),
+                                             p("w_beta", (D, H))))
+            o = kda_chunked(q, k, v, a, beta, self.chunk)
+            return output(o.astype(BF16), x, p("w_g1", (D, d)), p("w_g2", (d, H * d)),
+                          p("o_norm", (d,), nn.initializers.ones), p("w_o", (H * d, D)))
+
+
+class _Mla(nn.Module):
+    heads: int
+    nope: int
+    rope: int
+    v_dim: int
+    kv_rank: int
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        D, H = x.shape[-1], self.heads
+        B, T, _ = x.shape
+        p = lambda name, shape, init=_dense_init(): self.param(name, init, shape)
+        with jax.named_scope(SCOPE_MLA):
+            q = _mm(x, p("w_q", (D, H * (self.nope + self.rope))),
+                    "btd,de->bte", BF16).reshape(B, T, H, self.nope + self.rope)
+            kva = _mm(x, p("w_kva", (D, self.kv_rank + self.rope)), "btd,de->bte")
+            c = rms_norm(kva[..., : self.kv_rank],
+                         p("kv_norm", (self.kv_rank,), nn.initializers.ones), self.eps)
+            k_r = kva[..., self.kv_rank:].astype(BF16)     # shared by the heads, unrotated
+            kvb = _mm(c, p("w_kvb", (self.kv_rank, H * (self.nope + self.v_dim))),
+                      "btr,re->bte", BF16).reshape(B, T, H, self.nope + self.v_dim)
+            k = jnp.concatenate(
+                [kvb[..., : self.nope],
+                 jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, self.rope))], axis=-1)
+            o = mla_attention(q, k, kvb[..., self.nope:])
+            return _mm(o.reshape(B, T, H * self.v_dim),
+                       p("w_o", (H * self.v_dim, D)), "bte,ed->btd", BF16)
+
+
+class _Dense(nn.Module):
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        D = x.shape[-1]
+        p = lambda name, shape: self.param(name, _dense_init(), shape)
+        return swiglu(x, p("w_gate", (D, self.width)), p("w_up", (D, self.width)),
+                      p("w_down", (self.width, D)))
+
+
+class _Moe(nn.Module):
+    experts: int            # the router's outputs (published)
+    top_k: int
+    held: tuple             # expert ids whose weights live here
+    width: int
+    scaling: float
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, D = x.shape
+        E = len(self.held)
+        p = lambda name, shape: self.param(name, _dense_init(), shape)
+        flat = x.reshape(B * T, D)
+        with jax.named_scope(SCOPE_ROUTE):
+            # ``e_score_correction_bias``: held at zero, no balancing update
+            bias = self.param("router_bias", nn.initializers.zeros, (self.experts,))
+            ids, weights = route(flat, p("w_router", (D, self.experts)),
+                                 jax.lax.stop_gradient(bias), self.top_k, self.scaling)
+        with jax.named_scope(SCOPE_EXPERTS):
+            y, sizes = expert_layer(
+                flat, ids, weights, self.held, p("w_gate", (E, D, self.width)),
+                p("w_up", (E, D, self.width)), p("w_down", (E, self.width, D)))
+        with jax.named_scope(SCOPE_SHARED):
+            y = y + swiglu(flat, p("shared_gate", (D, self.width)),
+                           p("shared_up", (D, self.width)),
+                           p("shared_down", (self.width, D)))
+        return y.reshape(B, T, D), sizes
+
+
+class _Layer(nn.Module):
+    cfg: Any            # a hashable tuple of (key, value) pairs
+    mixer: str          # "kda" | "mla"
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, h):
+        c = dict(self.cfg)
+        eps = c["rms_norm_eps"]
+        norm = lambda name: self.param(name, nn.initializers.ones, (h.shape[-1],))
+        x = rms_norm(h, norm("norm_mixer"), eps)
+        if self.mixer == "kda":
+            m = _Kda(c["num_heads"], c["kda_head_dim"], c["short_conv_kernel_size"],
+                     c["kda_chunk"], eps, name="kda")(x)
+        else:
+            m = _Mla(c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                     c["v_head_dim"], c["kv_lora_rank"], eps, name="mla")(x)
+        h = h + m.astype(h.dtype)
+        x = rms_norm(h, norm("norm_ffn"), eps)
+        if self.sparse:
+            y, load = _Moe(c["num_experts"], c["num_experts_per_token"],
+                             tuple(c["experts_held"]), c["moe_intermediate_size"],
+                             c["routed_scaling_factor"], name="moe")(x)
+        else:
+            y = _Dense(c["intermediate_size"], name="ffn")(x)
+            load = jnp.zeros((len(c["experts_held"]),), jnp.int32)
+        return h + y.astype(h.dtype), load
+
+
+class _KimiLinear(nn.Module):
+    """x [B, T] token ids -> the next token's logits after the last one
+    given [B, V]; with ``hidden``, (hidden states after the final norm
+    [B, T, D] in bfloat16, the untied head [D, V], rows each held expert
+    took in each layer [layers, E])."""
+
+    cfg: Any
+    vocab: int
+
+    def layer_kinds(self):
+        c = dict(self.cfg)
+        return [("mla" if i in c["full_attn_layers"] else "kda",
+                 i > c["first_k_dense_replace"])
+                for i in range(1, c["num_hidden_layers"] + 1)]
+
+    @nn.compact
+    def __call__(self, x, train: bool = False, hidden: bool = False):
+        c = dict(self.cfg)
+        D = c["hidden_size"]
+        embed = self.param("embed", _dense_init(), (self.vocab, D))
+        head = self.param("head", _dense_init(), (D, self.vocab))
+        h = jnp.take(embed, x, axis=0).astype(BF16)
+        loads = []
+        layer = nn.remat(_Layer) if train else _Layer
+        for i, (mixer, sparse) in enumerate(self.layer_kinds()):
+            h, load = layer(self.cfg, mixer, sparse, name=f"layer_{i + 1}")(h)
+            loads.append(load)
+        h = rms_norm(h, self.param("norm_out", nn.initializers.ones, (D,)),
+                     c["rms_norm_eps"]).astype(BF16)
+        if hidden:
+            return h, head, jnp.stack(loads)
+        # Serving: the next token's distribution after the last one given.
+        return _mm(h[:, -1], head, "bd,dv->bv")
+
+
+def blocked_logit_stats(h, head, y, smoothing, block: int = LOSS_BLOCK):
+    """Over all positions, a block of the sequence at a time (each block
+    recomputed in the backward pass): summed cross entropy against ``y``
+    with label smoothing (a traced scalar), hits of the argmax, and the
+    count of labelled positions (``y`` >= 0)."""
+    T = h.shape[1]
+
+    @jax.checkpoint
+    def one(hb, yb):
+        logits = _mm(hb, head, "btd,dv->btv")
+        mask = yb >= 0
+        safe = jnp.where(mask, yb, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        smooth = -jnp.mean(logp, axis=-1)
+        ce = (1.0 - smoothing) * nll + smoothing * smooth
+        hit = (jnp.argmax(logits, axis=-1) == safe) & mask
+        return (jnp.where(mask, ce, 0.0).sum(), hit.sum().astype(jnp.int32),
+                mask.sum().astype(jnp.int32))
+
+    ce = jnp.zeros((), F32)
+    hits = n = jnp.zeros((), jnp.int32)
+    for start in range(0, T, block):
+        c1, h1, n1 = one(h[:, start: start + block], y[:, start: start + block])
+        ce, hits, n = ce + c1, hits + h1, n + n1
+    return ce, hits, n
+
+
+class KimiLinear(JaxModel):
+    """The template. Shape knobs default to a size a CPU trains in
+    seconds; a tenant's model file pins them (the benchmark's
+    configuration pins the published widths). ``expert_shard`` of
+    ``expert_shards`` says which experts this chip holds: ids
+    ``expert_shard * (num_experts // expert_shards)`` onward."""
+
+    @staticmethod
+    def get_knob_config():
+        fixed = lambda v: FixedKnob(v, affects_shape=True)
+        return {
+            "hidden_size": fixed(64), "num_heads": fixed(4), "kda_head_dim": fixed(16),
+            "short_conv_kernel_size": fixed(4), "kda_chunk": fixed(16),
+            "qk_nope_head_dim": fixed(16), "qk_rope_head_dim": fixed(8),
+            "v_head_dim": fixed(16), "kv_lora_rank": fixed(32),
+            "intermediate_size": fixed(128), "moe_intermediate_size": fixed(32),
+            "num_experts": fixed(16), "num_experts_per_token": fixed(4),
+            "expert_shards": fixed(4), "expert_shard": fixed(0),
+            "routed_scaling_factor": fixed(2.446), "first_k_dense_replace": fixed(1),
+            "num_hidden_layers": fixed(5), "full_attn_every": fixed(4),
+            "rms_norm_eps": fixed(1e-5),
+            "learning_rate": FloatKnob(3e-5, 1e-3, is_exp=True),
+            "label_smoothing": FloatKnob(0.0, 0.1),
+            "batch_size": fixed(2), "epochs": FixedKnob(1), "seed": FixedKnob(0),
+        }
+
+    @classmethod
+    def packable(cls) -> bool:
+        return False  # one trial fills the chip
+
+    @classmethod
+    def epoch_program(cls) -> bool:
+        return False  # a step of seconds, a compile of minutes: one step program
+
+    def _input_dtype(self):
+        return np.int32
+
+    def _dataset_arch(self, ds):
+        return int(ds.classes), tuple(ds.x.shape[1:])
+
+    def module_config(self) -> tuple:
+        kn = self.knobs
+        per = int(kn["num_experts"]) // int(kn["expert_shards"])
+        first = int(kn["expert_shard"]) * per
+        c = {k: kn[k] for k in (
+            "hidden_size", "num_heads", "kda_head_dim", "short_conv_kernel_size",
+            "kda_chunk", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+            "kv_lora_rank", "intermediate_size", "moe_intermediate_size",
+            "num_experts", "num_experts_per_token", "routed_scaling_factor",
+            "first_k_dense_replace", "num_hidden_layers", "rms_norm_eps")}
+        c["experts_held"] = tuple(range(first, first + per))
+        every = int(kn["full_attn_every"])
+        c["full_attn_layers"] = tuple(range(every, int(kn["num_hidden_layers"]) + 1, every))
+        return tuple(sorted(c.items()))
+
+    def build_module(self, num_classes, input_shape):
+        return _KimiLinear(cfg=self.module_config(), vocab=int(num_classes))
+
+    def _dynamic_hyper(self, takes_dropout: bool) -> Dict[str, float]:
+        hyper = super()._dynamic_hyper(takes_dropout)
+        hyper["label_smoothing"] = float(self.knobs.get("label_smoothing", 0.0))
+        return hyper
+
+    def _loop_fns(self, num_classes, input_shape):
+        """The shared closures, with this template's loss and counts in
+        place of the ones over whole logits."""
+        fns = super()._loop_fns(num_classes, input_shape)
+        module = fns["module"]
+        slots = float(self.knobs["num_experts_per_token"]) * sum(
+            sparse for _m, sparse in module.layer_kinds())
+
+        sparse = np.array([sp for _m, sp in module.layer_kinds()])
+
+        def stats(params, batch, train, smoothing):
+            h, head, loads = module.apply({"params": params}, batch["x"],
+                                          train=train, hidden=True)
+            with jax.named_scope(SCOPE_LM_LOSS):
+                ce, hits, n = blocked_logit_stats(h, head, batch["y"], smoothing)
+            return ce, hits, n, loads
+
+        def loss_fn(params, batch, rng, hyper):
+            ce, hits, n, loads = stats(params, batch, True, hyper["label_smoothing"])
+            n = jnp.maximum(n, 1)
+            loads = loads[sparse].astype(F32)
+            skew = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads, axis=-1), 1.0)
+            return ce / n, {
+                "acc": hits / n,
+                "count.moe.slots_held": loads.sum(),
+                "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
+                "gauge.moe.held_load_max_over_mean": skew.mean()}
+
+        def eval_count(params, batch):
+            _ce, hits, n, _loads = stats(params, batch, False, 0.0)
+            return hits, n
+
+        fns.update(loss_fn=loss_fn, eval_count=eval_count)
+        return fns
+
+
+if __name__ == "__main__":
+    # Dev harness run (`python -m rafiki_tpu.models.kimi_linear`): an
+    # explicit CPU request is applied before the first backend use.
+    from rafiki_tpu.utils.backend import honor_env_platform
+
+    honor_env_platform()
+    from rafiki_tpu.model.dev import test_model_class
+
+    _fixed = {k: v.value for k, v in KimiLinear.get_knob_config().items()
+              if isinstance(v, FixedKnob)}
+    test_model_class(
+        KimiLinear, "LANGUAGE_MODELING",
+        "synthetic://tokens?vocab=256&n=16&len=96&seed=0",
+        "synthetic://tokens?vocab=256&n=4&len=96&seed=1",
+        queries=[[5, 9, 3] * 8, [17, 2] * 12],
+        knobs=dict(_fixed, learning_rate=1e-3, label_smoothing=0.05),
+    )

@@ -27,6 +27,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: Metric-dict key prefix for sentinel outputs. ``ops.train`` strips
 #: these from caller-visible epoch metrics (the JaxModel/logger contract
@@ -89,15 +90,19 @@ def reduce_epoch(series: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
       These are the bit-reproduction surface ``obs replay`` verifies.
     """
     nf = series["health_nonfinite"]
+    # A series already on the host (the step-by-step serial path fetches
+    # its steps' scalars once) is reduced there, by numpy: no device program
+    # whose shape is the number of steps.
+    xp = np if isinstance(nf, np.ndarray) else jnp
     bad = nf > 0
     any_bad = bad.any(axis=0)
-    at = jnp.argmax(bad, axis=0).astype(jnp.int32)  # 0 when clean
-    first_bad = jnp.where(any_bad, at, jnp.int32(-1))
+    at = xp.argmax(bad, axis=0).astype(xp.int32)  # 0 when clean
+    first_bad = xp.where(any_bad, at, xp.int32(-1))
 
-    def _at_bad(v: jax.Array) -> jax.Array:
+    def _at_bad(v):
         if v.ndim == 1:
             return v[at]
-        return jnp.take_along_axis(v, at[None, :], axis=0)[0]
+        return xp.take_along_axis(v, at[None, :], axis=0)[0]
 
     gn = series["health_grad_norm"]
     un = series["health_update_norm"]

@@ -177,11 +177,13 @@ def _sample_device_mem() -> None:
 
 
 def capture_cost(key: Any, jitted: Any, *args: Any,
-                 kind: str = "serial", k: int = 1) -> Optional[Dict[str, Any]]:
+                 kind: str = "serial", k: int = 1,
+                 compiled: Any = None) -> Optional[Dict[str, Any]]:
     """AOT-compile ``jitted(*args)`` and record its XLA cost analysis
-    under ``key``. Idempotent per key; never raises (a backend that
-    can't lower/compile the AOT path just leaves the cost model empty).
-    Returns the captured cost dict, or None."""
+    under ``key`` (``compiled``: the executable, where the caller has
+    built it ahead of time itself). Idempotent per key; never raises (a
+    backend that can't lower/compile the AOT path just leaves the cost
+    model empty). Returns the captured cost dict, or None."""
     if not cost_capture_enabled():
         return None
     with _lock:
@@ -194,7 +196,8 @@ def capture_cost(key: Any, jitted: Any, *args: Any,
         import time as _time
 
         t0 = _time.monotonic()
-        compiled = jitted.lower(*args).compile()
+        if compiled is None:
+            compiled = jitted.lower(*args).compile()
         cost["cost_capture_s"] = _time.monotonic() - t0
         ca = compiled.cost_analysis() or {}
         cost["flops"] = float(ca.get("flops", 0.0)) or None

@@ -60,7 +60,27 @@ LossFn = Callable[..., Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]]
 # (lr / warmup via the update scaling, dropout via apply), or never
 # reach the trace at all (epochs = python loop count, seed = init rng).
 # Model templates must not bake these into module attributes.
-DYNAMIC_KNOBS = frozenset({"learning_rate", "warmup_steps", "dropout", "epochs", "seed"})
+DYNAMIC_KNOBS = frozenset({"learning_rate", "warmup_steps", "dropout", "epochs", "seed",
+                           "label_smoothing"})
+
+# Device-side counts that ride a step's metric dict (as the health
+# sentinels do): a ``count.<name>`` metric is summed over the epoch's
+# steps and lands in the counter ``<name>``; a ``gauge.<name>`` metric's
+# last step sets the gauge ``<name>``. Neither reaches a trial's log.
+COUNT_PREFIX, GAUGE_PREFIX = "count.", "gauge."
+
+
+def publish_counts(out: Dict[str, float]) -> None:
+    """Pop the ``count.`` / ``gauge.`` keys of an epoch's host metrics
+    into the telemetry registry."""
+    for key in [k for k in out if k.startswith((COUNT_PREFIX, GAUGE_PREFIX))]:
+        value = out.pop(key)
+        if key.startswith(COUNT_PREFIX):
+            # lint: disable=RF008 — bounded: the names a template's loss puts in its metric dict (docs/telemetry.md lists them)
+            telemetry.inc(key[len(COUNT_PREFIX):], value)
+        else:
+            # lint: disable=RF008 — bounded: as above
+            telemetry.set_gauge(key[len(GAUGE_PREFIX):], value)
 
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray,
@@ -182,11 +202,16 @@ def _gather_batch(X, Y, ib) -> Dict[str, jnp.ndarray]:
 
 def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
                    optimizer: optax.GradientTransformation,
-                   dynamic_lr: bool):
+                   dynamic_lr: bool, eval_count=None):
     """The single-trial step closures shared by :class:`Program` and
     :class:`PackedProgram`: (train_step, eval_step, predict, init_all).
     Pure per-trial functions — the packed path vmaps them over a
-    leading trial axis instead of re-deriving the math."""
+    leading trial axis instead of re-deriving the math.
+
+    ``eval_count(params, batch) -> (correct, counted)``, where a template
+    gives one, is the evaluation's step in place of an argmax over
+    ``apply_fn``'s whole logits (a language model counts a block of the
+    sequence at a time: its logits never exist whole)."""
     loss4 = _as_hyper_loss(loss_fn)
 
     def train_step(state, batch):
@@ -226,6 +251,9 @@ def _make_step_fns(init_fn, apply_fn, loss_fn: LossFn,
         return (params, opt_state, step_i + 1, rng, hyper), metrics
 
     def eval_step(params, batch):
+        if eval_count is not None:
+            with jax.named_scope(SCOPE_EVAL_COUNT):
+                return eval_count(params, batch)
         # The barrier (an identity) keeps XLA from fusing the forward's
         # tail with the argmax below. On the v5e (libtpu 0.0.34) that
         # fusion is miscompiled when this step is vmapped over a pack
@@ -274,13 +302,14 @@ class Program:
 
     def __init__(self, init_fn, apply_fn, loss_fn: LossFn,
                  optimizer: optax.GradientTransformation,
-                 plan: _ShardingPlan, dynamic_lr: bool = True):
+                 plan: _ShardingPlan, dynamic_lr: bool = True,
+                 eval_count=None):
         self.plan = plan
         self.optimizer = optimizer
         self.dynamic_lr = dynamic_lr
         self.apply_fn = apply_fn
         train_step, eval_step, predict, init_all = _make_step_fns(
-            init_fn, apply_fn, loss_fn, optimizer, dynamic_lr)
+            init_fn, apply_fn, loss_fn, optimizer, dynamic_lr, eval_count)
 
         # Whole-epoch programs over a DEVICE-RESIDENT dataset (single-
         # device path): one lax.scan per epoch, per-step batches
@@ -306,7 +335,8 @@ class Program:
             # python-loop path); the health series reduces on-device to
             # its epoch-boundary summary (docs/health.md).
             rest, health = _sentinel.split(ms)
-            out = {k: v[-1] for k, v in rest.items()}
+            out = {k: v.sum() if k.startswith(COUNT_PREFIX) else v[-1]
+                   for k, v in rest.items()}
             with jax.named_scope(SCOPE_HEALTH):
                 out.update(_sentinel.reduce_epoch(health))
             return state, out
@@ -335,6 +365,20 @@ class Program:
         self.init = jax.jit(init_all, **ikw)
         self.train_epoch = jax.jit(train_epoch, donate_argnums=(0,))
         self.eval_epoch = jax.jit(eval_epoch)
+        self.compiled_steps: Dict[Hashable, Any] = {}
+        self._compiled_lock = threading.Lock()
+
+    def compiled_step(self, state, batch):
+        """``train_step`` compiled ahead of time for arguments of these
+        shapes: built once a Program (every trial that shares it calls the
+        same executable), and kept, so that its cost analysis and its text
+        can be read without building it again."""
+        leaves, tree = jax.tree.flatten((state, batch))
+        key = (tree, tuple((np.shape(a), str(a.dtype)) for a in leaves))
+        with self._compiled_lock:
+            if key not in self.compiled_steps:
+                self.compiled_steps[key] = self.train_step.lower(state, batch).compile()
+            return self.compiled_steps[key]
 
 
 # ---------------------------------------------------------------------------
@@ -519,6 +563,9 @@ class TrainLoop:
     program_key: optional hashable. When given, the compiled Program is
         fetched from / stored in the process-wide cache under
         (program_key, mesh) — the compile-amortization path.
+    epoch_program: whether an epoch over a data set that fits the device
+        runs as one program (a scan over its steps). False runs it step by
+        step: ONE compiled step whatever the train set's length.
     initial_state: optional full (params, opt_state, step, rng, hyper)
         tuple to adopt INSTEAD of running init — the detached-member
         path: a trial evicted from a pack mid-sweep continues (or just
@@ -530,14 +577,19 @@ class TrainLoop:
                  mesh: Optional[Mesh] = None, seed: int = 0,
                  hyper: Optional[Dict[str, float]] = None,
                  program_key: Optional[Hashable] = None,
-                 initial_state=None):
+                 initial_state=None, eval_count=None,
+                 epoch_program: bool = True):
         dynamic_lr = hyper is not None and "lr" in hyper
+        # False: epochs run step by step even over a device-resident data
+        # set (``JaxModel.epoch_program``: templates whose step takes seconds).
+        self.epoch_program = bool(epoch_program)
         if optimizer is None:
             optimizer = optax.scale_by_adam() if dynamic_lr else optax.adam(1e-3)
 
         def build() -> Program:
             return Program(init_fn, apply_fn, loss_fn, optimizer,
-                           _ShardingPlan.build(mesh), dynamic_lr=dynamic_lr)
+                           _ShardingPlan.build(mesh), dynamic_lr=dynamic_lr,
+                           eval_count=eval_count)
 
         if program_key is not None:
             self._perf_key = (program_key, mesh_cache_key(mesh), dynamic_lr)
@@ -580,6 +632,27 @@ class TrainLoop:
     def hyper(self) -> Dict[str, jax.Array]:
         return self.state[4]
 
+    #: a dispatched host copy of the parameters (``release_to_host``), or None
+    host_copy = None
+
+    def state_bytes(self) -> int:
+        """Bytes of the train state on the device."""
+        return sum(int(x.nbytes) for x in jax.tree.leaves(self.state))
+
+    def release_to_host(self, cast_f32_to_bf16: bool) -> None:
+        """For a trial that is trained and scored: dispatch ONE
+        device-to-host copy of the parameters, cast to what a dump
+        stores, and let the device state go. What stays on the device is
+        the cast copy until ``host_copy.fetch`` has it (a sixth of a
+        float32 Adam state), so the next trial's state fits beside a dump
+        in flight where two states would not. The loop can dump after
+        this (``JaxModel.dump_parameters`` reads ``host_copy``) and
+        nothing else."""
+        from rafiki_tpu.utils.serial import StackedHostCopy
+
+        self.host_copy = StackedHostCopy(self.state[0], cast_f32_to_bf16)
+        self.state = None
+
     def _fits_device_fast_path(self, dataset) -> bool:
         """Single-device x/y datasets small enough to live in HBM run
         as one lax.scan per epoch over a device-resident copy."""
@@ -609,7 +682,8 @@ class TrainLoop:
             _chaos.hook("collective.step",
                         key=f"p{jax.process_index()}:"
                             f"{_os.environ.get('RAFIKI_WORKER_ID', '')}")
-        fast = on_metrics is None and self._fits_device_fast_path(dataset)
+        fast = (on_metrics is None and self.epoch_program
+                and self._fits_device_fast_path(dataset))
         # Pre-epoch host snapshot for the replay capsule: the epoch
         # program donates its input buffers, so the "state before the
         # bad epoch" must be banked BEFORE dispatch — and before the
@@ -634,15 +708,21 @@ class TrainLoop:
             perm = np.random.default_rng(epoch_seed).permutation(dataset.size)
             idx = perm[: n_steps * batch_size].reshape(
                 n_steps, batch_size).astype(np.int32)
-            if not getattr(self, "_warm", False):
+            cold = not getattr(self, "_warm", False)
+            if cold:
                 from rafiki_tpu.obs.perf import profiler as _profiler
 
                 _profiler.capture_cost(self._perf_key,
                                        self.program.train_epoch,
                                        self.state, X, Y, idx, poison)
-            self.state, metrics = self.program.train_epoch(
-                self.state, X, Y, idx, poison)
-            out = {k: float(v) for k, v in metrics.items()}
+            # The epoch as the host waits for it, dispatch to metrics on
+            # the host: the serial lane's twin of ``train.packed_epoch``.
+            with telemetry.span("train.epoch", leaf=True, cold=cold,
+                                steps=n_steps):
+                self.state, metrics = self.program.train_epoch(
+                    self.state, X, Y, idx, poison)
+                out = {k: float(v) for k, v in jax.device_get(metrics).items()}
+            publish_counts(out)
             self._record_epoch(t_epoch, feed_s=0.0)
             self._health_check(out, t_epoch, epoch_seed, idx, poison, snap)
             return out
@@ -671,43 +751,72 @@ class TrainLoop:
             return dev
 
         dev_batch = put_next()
-        if dev_batch is not None and not getattr(self, "_warm", False):
-            from rafiki_tpu.obs.perf import profiler as _profiler
-
-            _profiler.capture_cost(self._perf_key, self._train_step,
-                                   self.state, dev_batch)
-        while dev_batch is not None:
-            if poison is not None and count < n_steps:
-                pz = jnp.float32(poison[count])
-                if self.plan.mesh is not None:
-                    # The dp batch sharding is a rank-≥1 prefix; ship the
-                    # step multiplier as a batch-length column it can
-                    # shard (train_step reads one element back out).
-                    pz = jnp.full((batch_size,), pz, jnp.float32)
-                dev_batch = dict(dev_batch, _health_poison=pz)
-            self.state, metrics = self._train_step(self.state, dev_batch)
-            # Device scalars appended as-is: the per-step health series
-            # syncs to the host ONCE, at the epoch-boundary reduction.
-            health_steps.append({k: v for k, v in metrics.items()
-                                 if k.startswith(_sentinel.PREFIX)})
-            dev_batch = put_next()  # overlaps the in-flight step
-            if on_metrics is not None and (count % 50 == 0):
-                on_metrics(count, {k: float(v) for k, v in metrics.items()
-                                   if not k.startswith(_sentinel.PREFIX)})
-            count += 1
-        # Final-step metrics are the epoch result (one host sync per epoch).
-        out = {k: float(v) for k, v in metrics.items()
+        cold = not getattr(self, "_warm", False)
+        step = self._train_step
+        steps = []      # each step's metric dict, device scalars
+        with telemetry.span("train.epoch", leaf=True, cold=cold, steps=n_steps):
+            while dev_batch is not None:
+                if poison is not None and count < n_steps:
+                    pz = jnp.float32(poison[count])
+                    if self.plan.mesh is not None:
+                        # The dp batch sharding is a rank-≥1 prefix; ship the
+                        # step multiplier as a batch-length column it can
+                        # shard (train_step reads one element back out).
+                        pz = jnp.full((batch_size,), pz, jnp.float32)
+                    dev_batch = dict(dev_batch, _health_poison=pz)
+                if count == 0:
+                    step = self._step_callable(dev_batch, cold)
+                self.state, metrics = step(self.state, dev_batch)
+                # Device scalars kept as they are: the per-step series comes
+                # to the host ONCE, at the epoch boundary.
+                steps.append(metrics)
+                dev_batch = put_next()  # overlaps the in-flight step
+                if on_metrics is not None and (count % 50 == 0):
+                    on_metrics(count, {k: float(v) for k, v in metrics.items()
+                                       if not k.startswith(_sentinel.PREFIX)})
+                count += 1
+            # One host sync an epoch: every step's scalars in one fetch, and
+            # the reductions below in numpy (no device program whose shape
+            # is the number of steps).
+            steps = jax.device_get(steps)
+        # Final-step metrics are the epoch result; counts are summed over
+        # the steps, as the epoch program sums them.
+        out = {k: (float(sum(st[k] for st in steps)) if k.startswith(COUNT_PREFIX)
+                   else float(v))
+               for k, v in steps[-1].items()
                if not k.startswith(_sentinel.PREFIX)} if count else {}
+        publish_counts(out)
         self._record_epoch(t_epoch, feed_s)
         if count:
-            series = {k: jnp.stack([h[k] for h in health_steps])
-                      for k in health_steps[0]}
+            series = {k: np.stack([st[k] for st in steps])
+                      for k in steps[0] if k.startswith(_sentinel.PREFIX)}
             out.update({k: float(v) for k, v
                         in _sentinel.reduce_epoch(series).items()})
             # No index matrix on this path -> detection and containment
             # only; the monitor skips the replay capsule.
             self._health_check(out, t_epoch, epoch_seed, None, poison, None)
         return out
+
+    def _step_callable(self, dev_batch, cold: bool):
+        """What the step-by-step path calls, and (``cold``) the profiler's
+        cost capture of it. A loop that runs step by step by choice
+        (``epoch_program`` false: a step of seconds, a compile of minutes)
+        calls ONE executable built ahead of time, which the cost capture
+        reads too: the jitted step and an ahead-of-time compile of it are
+        two compiles wherever the persistent cache cannot hold the
+        executable (measured on a v5e: 96 s, twice). By fallback (a mesh, a
+        mask, ``on_metrics``) it is the jitted step, as ever."""
+        from rafiki_tpu.obs.perf import profiler as _profiler
+
+        if self.epoch_program:
+            if cold:
+                _profiler.capture_cost(self._perf_key, self._train_step,
+                                       self.state, dev_batch)
+            return self._train_step
+        exe = self.program.compiled_step(self.state, dev_batch)
+        if cold:
+            _profiler.capture_cost(self._perf_key, self._train_step, compiled=exe)
+        return exe
 
     def _chaos_poison(self, n_steps: int) -> np.ndarray:
         """Chaos site ``train.nan``: when an active plane arms it for

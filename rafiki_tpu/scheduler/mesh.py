@@ -650,7 +650,31 @@ class MeshSweepScheduler:
                  devices: List[Any], k: int, budget: Dict[str, Any],
                  errors: List[str], stop_event: threading.Event,
                  elastic: Optional[ElasticHandle] = None) -> None:
-        """One sub-job's sweep: draft, claim, distribute, supervise."""
+        """One sub-job's sweep: rounds of draft, claim, distribute,
+        supervise. Without a ``TIME_HOURS`` budget the sweep is one round
+        (chips x k slots, capped by ``MODEL_TRIAL_COUNT``), as ever. With
+        one, rounds follow each other while the budget's clock runs (the
+        job row's age, as ``TrainWorker.budget_exhausted`` reads it) and a
+        round still claims a trial: the round in flight at the deadline
+        finishes, none starts after it."""
+        hours = budget.get(BudgetType.TIME_HOURS.value)
+        while True:
+            before = len(self.store.get_trials_of_sub_train_job(sub["id"]))
+            self._run_round(job, sub, model_cls, handle, devices, k, budget,
+                            errors, stop_event, elastic=elastic)
+            claimed = len(self.store.get_trials_of_sub_train_job(sub["id"])) - before
+            if hours is None or claimed == 0 or stop_event.is_set():
+                return
+            # lint: disable=RF009 — job age vs a persisted epoch timestamp, the basis the workers' own budget check uses
+            if time.time() - job["created_at"] >= float(hours) * 3600:
+                return
+
+    def _run_round(self, job: dict, sub: dict, model_cls: type, handle,
+                   devices: List[Any], k: int, budget: Dict[str, Any],
+                   errors: List[str], stop_event: threading.Event,
+                   elastic: Optional[ElasticHandle] = None) -> None:
+        """One round: one batched draft for every slot, claimed up front,
+        bucketed, trained a pack a chip, supervised until drained."""
         job_id = job["id"]
         n_chips = len(devices)
         assert n_chips >= 1, "mesh sweep needs at least one device"
@@ -662,7 +686,8 @@ class MeshSweepScheduler:
 
         # ONE batched draft for the whole mesh — the paper's per-GPU
         # propose loop collapses into a single call.
-        with telemetry.span("mesh.advisor_propose", job_id=job_id, n=n_slots):
+        with telemetry.span("mesh.advisor_propose", leaf=True, job_id=job_id,
+                            n=n_slots):
             batch = getattr(handle, "propose_batch", None)
             proposals = (batch(n_slots) if batch is not None
                          else [handle.propose() for _ in range(n_slots)])

@@ -38,7 +38,7 @@ concatenated little-endian buffers. Readable with numpy alone.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -135,8 +135,12 @@ class StackedHostCopy:
             self._host = jax.tree.map(np.asarray, self._device)
             self._device = None
 
-    def member(self, i: int) -> Any:
+    def member(self, i: Optional[int]) -> Any:
+        """Member ``i`` of a stacked copy; ``None`` for a copy that is one
+        trial's own tree (the serial lane's), which is handed out whole."""
         self.fetch()
+        if i is None:
+            return self._host
         # ``a[i, ...]``: a 0-d array, not a numpy scalar, for a (k,) leaf.
         return jax.tree.map(lambda a: a[i, ...], self._host)
 

@@ -280,8 +280,14 @@ class TrainWorker:
                     self._wire_checkpoints(model, tid, resume)
                 with telemetry.span("trial.train", trial_id=tid):
                     model.train(self.train_uri)
-                with telemetry.span("trial.evaluate", trial_id=tid):
+                with telemetry.span("trial.evaluate", leaf=True, trial_id=tid):
                     score = float(model.evaluate(self.val_uri))
+                # Scored: what only training needed can go before the dump
+                # is handed on (a model that fills the chip cannot wait
+                # for the saver beside the next trial's state).
+                release = getattr(model, "release_train_state", None)
+                if release is not None:
+                    release()
             # The advisor hears the score immediately (it steers the next
             # proposal); parameter persistence is NOT on the critical
             # path — the saver thread dumps/writes/marks-completed while

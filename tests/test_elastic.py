@@ -346,3 +346,40 @@ def test_mesh_degrades_to_single_chip(env, monkeypatch):
     # Everything ran on the single surviving chip's worker.
     assert {t["worker_id"] for t in result.trials} == \
         {f"{job['id'][:8]}-mesh-c0"}
+
+
+def test_mesh_sweep_honours_a_time_budget_by_rounds(env):
+    """With TIME_HOURS the sweep runs round after round while the budget's
+    clock runs: more trials than one round's chips x k slots, every one
+    completed, none started after the deadline's round; with a trial-count
+    budget beside it the count still bounds the sweep."""
+    from rafiki_tpu.scheduler import MeshSweepScheduler
+
+    store, params, _ = env
+    model = store.create_model("chaosff", "IMAGE_CLASSIFICATION", None,
+                               CHAOS_FF_SOURCE, "ChaosFF")
+    # One round without the clock first: it compiles the pack's programs,
+    # which on a loaded machine takes longer than the budget below.
+    warm = _job(store, model, {"MODEL_TRIAL_COUNT": 4})
+    result = MeshSweepScheduler(store, params).run_sweep(
+        warm["id"], chips=2, trials_per_chip=2, advisor_kind="random")
+    assert result.status == "COMPLETED" and len(result.trials) == 4
+    budget_s = 6.0
+    job = _job(store, model, {"TIME_HOURS": budget_s / 3600.0})
+    t0 = time.monotonic()
+    result = MeshSweepScheduler(store, params).run_sweep(
+        job["id"], chips=2, trials_per_chip=2, advisor_kind="random")
+    wall = time.monotonic() - t0
+    assert result.status == "COMPLETED", result.errors
+    assert len(result.trials) > 4 and len(result.trials) % 4 == 0
+    assert all(t["status"] == "COMPLETED" for t in result.trials)
+    assert budget_s <= wall < 60.0
+    # The last round was started because the one before it ended inside the
+    # budget (a trial's own ``started_at`` lags its round's start, so it is
+    # the earlier rounds' ends that are exact).
+    earlier = sorted(result.trials, key=lambda t: t["started_at"])[:-4]
+    assert max(t["stopped_at"] for t in earlier) - job["created_at"] < budget_s
+    both = _job(store, model, {"TIME_HOURS": 1.0, "MODEL_TRIAL_COUNT": 6})
+    result = MeshSweepScheduler(store, params).run_sweep(
+        both["id"], chips=2, trials_per_chip=2, advisor_kind="random")
+    assert result.status == "COMPLETED" and len(result.trials) == 6
