@@ -924,6 +924,9 @@ def _portable_meta(meta: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+MODEL_CODE_FILENAME = "<rafiki_model.py>"
+
+
 def load_model_class(model_file_bytes: bytes, model_class: str,
                      temp_mod_name: Optional[str] = None) -> type:
     """Load a model template class from uploaded ``.py`` source bytes.
@@ -937,7 +940,12 @@ def load_model_class(model_file_bytes: bytes, model_class: str,
     mod.__dict__["__file__"] = f"<{name}.py>"
     sys.modules[name] = mod
     try:
-        exec(compile(model_file_bytes, f"<{name}.py>", "exec"), mod.__dict__)
+        # The code's file name is one constant, not the module's name (which
+        # differs from process to process): jax writes the file names of the
+        # traceback into a Pallas kernel's serialized body, that body is part
+        # of the persistent compile cache's key, and a step program that
+        # holds a kernel would be compiled anew by every process.
+        exec(compile(model_file_bytes, MODEL_CODE_FILENAME, "exec"), mod.__dict__)
     except Exception:
         del sys.modules[name]
         raise

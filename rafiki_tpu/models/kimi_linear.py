@@ -18,8 +18,14 @@ What the template adds to the zoo, by mechanism:
   ``[d_k, d_v]`` state. (The recurrence token by token is the benchmark's
   reference, ``benchmark/references/kimi_linear.py``; tests compare the two.)
 * ``mla_attention``: causal softmax attention over latent-projected
-  keys and values, computed a block of queries at a time so that no
-  ``[T, T]`` score array outlives its block.
+  keys and values (queries and keys 192 wide, values 128). Lowered for a
+  TPU, at a length ``KERNEL_BLOCK`` divides: one fused kernel a pass (the
+  library's Pallas splash attention: online softmax, the score tile in
+  VMEM, nothing above the diagonal visited, the backward pass from the
+  saved log-sum-exp). Everywhere else (the CPU, any other length): plain
+  ``jax.numpy``, a block of queries at a time so that no ``[T, T]`` score
+  array outlives its block. ``count.mla.fused`` of ``count.mla.layers``
+  says which ran.
 * ``expert_layer``: the router keeps every output and its experts per
   token; the layer is *told which expert ids it holds* and computes
   their part of the result for the tokens routed to them, without a
@@ -31,7 +37,14 @@ What the template adds to the zoo, by mechanism:
 
 TPU notes: matrix products take bfloat16 operands and accumulate in
 float32; parameters, the recurrent state, decays, normalisations, the
-router and the softmaxes stay float32. Sequences are fixed length, one
+router and the softmaxes stay float32 (in the attention kernel: the
+running maximum, sum and accumulator). Which attention runs is decided
+when a program is lowered, by the platform it is lowered for
+(``lax.platform_dependent``), so a compile on a CPU host for a described
+chip holds the kernel; no environment variable or knob enters. The
+blocked code on a TPU writes each block's float32 scores to HBM three
+times a pass (2.2 s of a 3.9 s step at 2 x 8,192 tokens against 0.07 s
+in the kernel's four calls: PERF.md, PR 28). Sequences are fixed length, one
 document a sequence (`synthetic://tokens`). A trial of this template
 fills a chip by itself, so it is not packable; it runs in the serial
 lane.
@@ -46,6 +59,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash, splash_attention_mask as splash_mask)
 
 from rafiki_tpu.model.base import JaxModel
 from rafiki_tpu.model.knobs import FixedKnob, FloatKnob
@@ -54,7 +69,8 @@ F32 = jnp.float32
 L2_EPS = 1e-6        # l2norm's epsilon (assumed; FLA's kernels use 1e-6)
 EXP_CLIP = 80.0      # |log-decay| a chunk half may span before float32 overflows
 LOSS_BLOCK = 1024    # tokens of a sequence whose logits exist at one time
-ATTN_BLOCK = 256     # queries whose scores exist at one time
+ATTN_BLOCK = 256     # queries whose scores exist at one time (the blocked path)
+KERNEL_BLOCK = 1024  # queries and keys of one tile of the fused attention kernel
 KDA_GROUP = 16       # chunks whose insides exist at one time
 BF16 = jnp.bfloat16
 
@@ -188,12 +204,12 @@ def causal_conv(x, w):
 
 # -- MLA: latent attention, no positions ------------------------------------------
 
-def mla_attention(q, k, v, block: int = ATTN_BLOCK, segments: int = 4):
-    """Causal softmax(q k^T / sqrt(d)) v. ``q``, ``k``: [B, T, H, d];
-    ``v``: [B, T, H, dv]. The sequence is cut into ``segments``; a segment's
-    queries see the keys up to the segment's end, a block of ``block``
-    queries at a time, one after the other (``lax.map``), each block
-    recomputed in the backward pass: one block's scores exist at a time."""
+def _blocked_attention(q, k, v, block: int = ATTN_BLOCK, segments: int = 4):
+    """``mla_attention`` in plain ``jax.numpy``. The sequence is cut into
+    ``segments``; a segment's queries see the keys up to the segment's end,
+    a block of ``block`` queries at a time, one after the other
+    (``lax.map``), each block recomputed in the backward pass: one block's
+    scores exist at a time."""
     B, T, H, d = q.shape
     scale = 1.0 / np.sqrt(d)
 
@@ -217,6 +233,48 @@ def mla_attention(q, k, v, block: int = ATTN_BLOCK, segments: int = 4):
                         (jnp.moveaxis(qs, 1, 0), firsts))
         outs.append(jnp.moveaxis(o, 0, 1).reshape(B, seg, H, v.shape[-1]))
     return jnp.concatenate(outs, axis=1)
+
+
+def _fused_attention(q, k, v, interpret: bool = False):
+    """``mla_attention`` as one kernel a pass (the library's Pallas splash
+    attention): online softmax over ``KERNEL_BLOCK`` keys at a time, the
+    score tile in VMEM, key blocks above the diagonal never visited,
+    float32 maximum, sum and accumulator, bfloat16 operands; the backward
+    pass from the saved output and log-sum-exp (its own ``custom_vjp``).
+    The kernel scales nothing, so q is scaled here, in float32, before it
+    is rounded; it takes one sequence heads-major, so the batch is mapped.
+    ``interpret``: the tests' way to run it on the CPU."""
+    _B, T, H, d = q.shape
+    b = KERNEL_BLOCK
+    kernel = splash.make_splash_mha(
+        splash_mask.MultiHeadMask([splash_mask.CausalMask((T, T))] * H),
+        block_sizes=splash.BlockSizes(
+            block_q=b, block_kv=b, block_kv_compute=b,
+            block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+            block_q_dq=b, block_kv_dq=b),
+        head_shards=1, q_seq_shards=1, interpret=interpret)
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)
+    q = (q.astype(F32) * (1.0 / np.sqrt(d))).astype(BF16)
+    o = jax.vmap(kernel)(heads_major(q), heads_major(k.astype(BF16)),
+                         heads_major(v.astype(BF16)))
+    return heads_major(o)
+
+
+def mla_attention(q, k, v):
+    """Causal softmax(q k^T / sqrt(d)) v: bfloat16 operands, float32
+    products and softmax, every query sees every key at or before it.
+    ``q``, ``k``: [B, T, H, d]; ``v``: [B, T, H, dv]. Returns (the result
+    [B, T, H, dv] in bfloat16, 1.0 where the fused kernel computed it and
+    0.0 where the blocked code did). The kernel runs where the program is
+    lowered for a TPU and ``KERNEL_BLOCK`` divides the length; which of the
+    two is decided when the program is lowered, from the platform it is
+    lowered for (so a compile here for a described chip takes the chip's
+    path), and from nothing else."""
+    blocked = lambda q, k, v: (_blocked_attention(q, k, v), jnp.float32(0.0))
+    if q.shape[1] % KERNEL_BLOCK:
+        return blocked(q, k, v)
+    fused = lambda q, k, v: (_fused_attention(q, k, v), jnp.float32(1.0))
+    return jax.lax.platform_dependent(q, k, v, tpu=fused, default=blocked)
 
 
 # -- sparse experts ------------------------------------------------------------
@@ -380,9 +438,9 @@ class _Mla(nn.Module):
             k = jnp.concatenate(
                 [kvb[..., : self.nope],
                  jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, self.rope))], axis=-1)
-            o = mla_attention(q, k, kvb[..., self.nope:])
+            o, fused = mla_attention(q, k, kvb[..., self.nope:])
             return _mm(o.reshape(B, T, H * self.v_dim),
-                       p("w_o", (H * self.v_dim, D)), "bte,ed->btd", BF16)
+                       p("w_o", (H * self.v_dim, D)), "bte,ed->btd", BF16), fused
 
 
 class _Dense(nn.Module):
@@ -436,12 +494,13 @@ class _Layer(nn.Module):
         eps = c["rms_norm_eps"]
         norm = lambda name: self.param(name, nn.initializers.ones, (h.shape[-1],))
         x = rms_norm(h, norm("norm_mixer"), eps)
+        fused = jnp.float32(0.0)
         if self.mixer == "kda":
             m = _Kda(c["num_heads"], c["kda_head_dim"], c["short_conv_kernel_size"],
                      c["kda_chunk"], eps, name="kda")(x)
         else:
-            m = _Mla(c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
-                     c["v_head_dim"], c["kv_lora_rank"], eps, name="mla")(x)
+            m, fused = _Mla(c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                            c["v_head_dim"], c["kv_lora_rank"], eps, name="mla")(x)
         h = h + m.astype(h.dtype)
         x = rms_norm(h, norm("norm_ffn"), eps)
         if self.sparse:
@@ -451,14 +510,15 @@ class _Layer(nn.Module):
         else:
             y = _Dense(c["intermediate_size"], name="ffn")(x)
             load = jnp.zeros((len(c["experts_held"]),), jnp.int32)
-        return h + y.astype(h.dtype), load
+        return h + y.astype(h.dtype), load, fused
 
 
 class _KimiLinear(nn.Module):
     """x [B, T] token ids -> the next token's logits after the last one
     given [B, V]; with ``hidden``, (hidden states after the final norm
     [B, T, D] in bfloat16, the untied head [D, V], rows each held expert
-    took in each layer [layers, E])."""
+    took in each layer [layers, E], the MLA layers whose attention the
+    fused kernel computed)."""
 
     cfg: Any
     vocab: int
@@ -476,15 +536,16 @@ class _KimiLinear(nn.Module):
         embed = self.param("embed", _dense_init(), (self.vocab, D))
         head = self.param("head", _dense_init(), (D, self.vocab))
         h = jnp.take(embed, x, axis=0).astype(BF16)
-        loads = []
+        loads, fused = [], jnp.float32(0.0)
         layer = nn.remat(_Layer) if train else _Layer
         for i, (mixer, sparse) in enumerate(self.layer_kinds()):
-            h, load = layer(self.cfg, mixer, sparse, name=f"layer_{i + 1}")(h)
+            h, load, kernel = layer(self.cfg, mixer, sparse, name=f"layer_{i + 1}")(h)
             loads.append(load)
+            fused = fused + kernel
         h = rms_norm(h, self.param("norm_out", nn.initializers.ones, (D,)),
                      c["rms_norm_eps"]).astype(BF16)
         if hidden:
-            return h, head, jnp.stack(loads)
+            return h, head, jnp.stack(loads), fused
         # Serving: the next token's distribution after the last one given.
         return _mm(h[:, -1], head, "bd,dv->bv")
 
@@ -589,16 +650,17 @@ class KimiLinear(JaxModel):
             sparse for _m, sparse in module.layer_kinds())
 
         sparse = np.array([sp for _m, sp in module.layer_kinds()])
+        mla_layers = sum(mixer == "mla" for mixer, _sp in module.layer_kinds())
 
         def stats(params, batch, train, smoothing):
-            h, head, loads = module.apply({"params": params}, batch["x"],
-                                          train=train, hidden=True)
+            h, head, loads, fused = module.apply({"params": params}, batch["x"],
+                                                 train=train, hidden=True)
             with jax.named_scope(SCOPE_LM_LOSS):
                 ce, hits, n = blocked_logit_stats(h, head, batch["y"], smoothing)
-            return ce, hits, n, loads
+            return ce, hits, n, loads, fused
 
         def loss_fn(params, batch, rng, hyper):
-            ce, hits, n, loads = stats(params, batch, True, hyper["label_smoothing"])
+            ce, hits, n, loads, fused = stats(params, batch, True, hyper["label_smoothing"])
             n = jnp.maximum(n, 1)
             loads = loads[sparse].astype(F32)
             skew = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads, axis=-1), 1.0)
@@ -606,10 +668,12 @@ class KimiLinear(JaxModel):
                 "acc": hits / n,
                 "count.moe.slots_held": loads.sum(),
                 "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
+                "count.mla.fused": fused,
+                "count.mla.layers": jnp.float32(mla_layers),
                 "gauge.moe.held_load_max_over_mean": skew.mean()}
 
         def eval_count(params, batch):
-            _ce, hits, n, _loads = stats(params, batch, False, 0.0)
+            _ce, hits, n, _loads, _fused = stats(params, batch, False, 0.0)
             return hits, n
 
         fns.update(loss_fn=loss_fn, eval_count=eval_count)

@@ -225,3 +225,112 @@ def test_stacked_top2_vgg16_serving_forward_compiles_for_v5e(one_chip):
     compiled = fwd.lower(
         stacked, {"x": _spec((64, 32, 32, 3), jnp.float32, one_chip)}).compile()
     assert _peak_bytes(compiled) < HBM_BYTES
+
+
+# -- the language model's latent attention (ISSUE 28) ---------------------------
+
+SCORES = "f32[2,32,256,8192]"  # a block of 256 queries' scores over 8,192 keys, in HBM
+
+
+def _kimi_linear_cell():
+    """(the template pinned to the benchmark cell's published widths,
+    vocabulary, sequence length, batch)."""
+    import json
+    from pathlib import Path
+
+    from rafiki_tpu.model.knobs import FixedKnob
+    from rafiki_tpu.models import kimi_linear as K
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+                      / "kimi_linear_48b_a3b_ep32.json").read_text())
+    pinned = {k: v["fixed"] for k, v in cfg["knobs"].items() if "fixed" in v}
+    pinned["seed"] = 0
+
+    class Cell(K.KimiLinear):
+        @staticmethod
+        def get_knob_config():
+            base = K.KimiLinear.get_knob_config()
+            return {k: (FixedKnob(pinned[k], affects_shape=True)
+                        if k in pinned and isinstance(base[k], FixedKnob) else base[k])
+                    for k in base}
+
+    model = Cell(**pinned, learning_rate=1e-3, label_smoothing=0.05)
+    model._planned_steps = 8
+    return model, int(cfg["vocab_size"]), int(cfg["seq_len"]), int(pinned["batch_size"])
+
+
+def _attention_kernels(text):
+    """(instruction name, op_name) of the fused attention's kernel calls in
+    a compiled text. (The printed call spans three lines: its kernel
+    metadata holds a line break.)"""
+    import re
+
+    return [(m.group(1), text[m.end(): text.find("\n  %", m.end())].split('op_name="')[1]
+             .split('"')[0])
+            for m in re.finditer(r"^\s*%(splash_mha[\w.]*) = ", text, re.M)]
+
+
+def test_the_latent_attention_layer_is_three_kernel_calls_on_v5e(one_chip):
+    """``_Mla``'s value and gradients at the cell's shapes (2 x 8,192
+    tokens, 32 heads of 192 / 128): lowered for the described chip,
+    ``mla_attention`` takes the fused kernel. One forward, two backward
+    calls, each under the ``mla`` scope (``mla_device_share.lm`` reads
+    that), and no block of float32 scores in HBM."""
+    from rafiki_tpu.models import kimi_linear as K
+
+    model, _vocab, T, B = _kimi_linear_cell()
+    c = dict(model.module_config())
+    mod = K._Mla(c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
+                 c["v_head_dim"], c["kv_lora_rank"], c["rms_norm_eps"])
+    x = jax.ShapeDtypeStruct((B, T, c["hidden_size"]), jnp.float32)
+    params = jax.eval_shape(mod.init, jax.random.PRNGKey(0), x)["params"]
+
+    def loss(params, x):
+        out, fused = mod.apply({"params": params}, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2), fused
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        _on(one_chip, params), _on(one_chip, x)).compile()
+    text = compiled.as_text()
+    kernels = _attention_kernels(text)
+    assert sorted(name.split(".")[0] for name, _op in kernels) == [
+        "splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+        "splash_mha_fwd_residuals"]
+    assert all("/mla/" in op for _name, op in kernels), kernels
+    assert sum("transpose(jvp(" in op for _name, op in kernels) == 2
+    assert text.count("tpu_custom_call") == 3 and SCORES not in text
+    # the blocked code's layer, compiled the same way at the parent commit: 3.47 GB (1.42 here)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.47e9
+
+
+def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel(one_chip):
+    """The benchmark cell's whole step program (602 M parameters, Adam,
+    every layer recomputed) and its evaluation step: the TPU compiler has
+    refused in the whole step what it took in every part (PR 27's two
+    scatter-adds). The forward kernel twice (the forward pass and the
+    layer's ``nn.remat``), the two backward kernels once, nothing else of
+    the attention; the evaluation takes the kernel that saves nothing."""
+    from rafiki_tpu.ops.train import Program, _ShardingPlan
+
+    model, vocab, T, B = _kimi_linear_cell()
+    fns = model._loop_fns(vocab, (T,))
+    prog = Program(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                   fns["optimizer"], _ShardingPlan.build(None),
+                   eval_count=fns["eval_count"])
+    state = _serial_state(fns, prog.init, one_chip)
+    batch = {k: _spec((B, T), jnp.int32, one_chip) for k in ("x", "y")}
+    step = prog.train_step.lower(state, batch).compile()
+    text = step.as_text()
+    kernels = _attention_kernels(text)
+    assert sorted(name.split(".")[0] for name, _op in kernels) == [
+        "splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+        "splash_mha_fwd_residuals", "splash_mha_fwd_residuals"]
+    assert all("/mla/" in op for _name, op in kernels), kernels
+    assert SCORES not in text and "[2,32,256," not in text
+    # the parent's step, compiled the same way: 5.83 GB of temporaries (4.94 here)
+    assert step.memory_analysis().temp_size_in_bytes < 5.83e9
+    assert _peak_bytes(step) < HBM_BYTES
+    evaluate = prog.eval_step.lower(state[0], batch).compile()
+    assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
+        "splash_mha_fwd_no_residuals"]
+    assert _peak_bytes(evaluate) < HBM_BYTES

@@ -142,7 +142,121 @@ def test_each_mixer_matches_the_reference(cfg, mixer, f32):
         want = R.mla(ref, f"layer_{layer}", x, cfg)
         assert close(R.mla(ref, f"layer_{layer}", x, cfg, q_block=32), want, 1e-6)
     got = mod.apply({"params": params[f"layer_{layer}"][mixer]}, x)
+    if mixer == "mla":
+        got, fused = got
+        assert float(fused) == 0.0         # 96 tokens: no kernel block divides them
     assert close(got, want, 2e-5)
+
+
+def mla_operands(T, dtype=jnp.float32, B=1, H=2):
+    """What is particular to MLA: queries and keys 192 wide, values 128."""
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    q, k = (jax.random.normal(ks[i], (B, T, H, 192)).astype(dtype) for i in (0, 1))
+    v = jax.random.normal(ks[2], (B, T, H, 128)).astype(dtype)
+    return q, k, v, jax.random.normal(ks[3], (B, T, H, 128))
+
+
+def value_and_grads(fn, q, k, v, ct):
+    """fn's result and its three gradients under the cotangent ``ct``."""
+    out, vjp = jax.vjp(lambda q, k, v: fn(q, k, v).astype(jnp.float32), q, k, v)
+    return (out,) + vjp(ct)
+
+
+@pytest.fixture
+def interpreted():
+    """Pallas' interpreter ran in this test. With what it leaves in jax's
+    caches, a later test of this file (an eager ``lax.scan`` under the
+    ``f32`` fixture) died of a segmentation fault in this jax (0.9.0), every
+    time; with the caches cleared it does not."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("against", ["whole_row_softmax", "blocked_path"])
+def test_the_fused_kernel_matches(against, f32, monkeypatch, interpreted):
+    """The kernel path (Pallas in interpret mode on the CPU) at a length of
+    four of its blocks, float32 operands: value and all three gradients
+    against the reference's whole-row softmax and against the blocked path."""
+    monkeypatch.setattr(K, "KERNEL_BLOCK", 128)
+    q, k, v, ct = mla_operands(4 * 128)
+    other = R.attention if against == "whole_row_softmax" else K._blocked_attention
+    got = value_and_grads(lambda *a: K._fused_attention(*a, interpret=True), q, k, v, ct)
+    for name, a, b in zip(("value", "dq", "dk", "dv"), got,
+                          value_and_grads(other, q, k, v, ct)):
+        assert a.shape == b.shape and close(a, b, 2e-5), name
+
+
+def test_the_fused_kernel_in_bfloat16_is_as_near_the_reference_as_the_blocked_path(monkeypatch, interpreted):
+    """The contract both paths share: bfloat16 operands and result, float32
+    products and softmax. What the kernel changes (q rounded after its
+    scaling, an online softmax) must cost no more than bfloat16 itself."""
+    monkeypatch.setattr(K, "KERNEL_BLOCK", 128)
+    q, k, v, ct = mla_operands(4 * 128, jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        want = value_and_grads(R.attention, *(x.astype(jnp.float32) for x in (q, k, v)), ct)
+    fused = value_and_grads(lambda *a: K._fused_attention(*a, interpret=True), q, k, v, ct)
+    blocked = value_and_grads(K._blocked_attention, q, k, v, ct)
+    assert K._fused_attention(q, k, v, interpret=True).dtype == jnp.bfloat16
+
+    def gap(x, w):
+        return float(jnp.max(jnp.abs(x.astype(jnp.float32) - w)) / jnp.max(jnp.abs(w)))
+
+    for name, a, b, w in zip(("value", "dq", "dk", "dv"), fused, blocked, want):
+        assert a.dtype == b.dtype
+        assert gap(b, w) < 0.02 and gap(a, w) < max(1.5 * gap(b, w), 0.01), (name, gap(a, w), gap(b, w))
+
+
+@pytest.mark.parametrize("T", [4 * K.KERNEL_BLOCK, K.KERNEL_BLOCK + 96],
+                         ids=["a_length_of_four_blocks", "a_length_no_block_divides"])
+def test_which_attention_runs_is_read_from_the_length_and_the_lowering(T):
+    """A length the kernel's block divides: both paths are staged, and the
+    platform the program is lowered for takes its own (here the CPU: the
+    blocked code, flag 0; ``tests/test_chip_compile.py`` lowers the same
+    call for a described TPU). Any other length: the blocked code alone."""
+    q, k, v, _ct = mla_operands(T, jnp.bfloat16)
+    staged = str(jax.make_jaxpr(K.mla_attention)(q, k, v))
+    assert ("platform_index" in staged) == ("pallas_call" in staged) == (T % K.KERNEL_BLOCK == 0)
+    lowered = jax.jit(K.mla_attention).lower(q, k, v).as_text()
+    assert "tpu_custom_call" not in lowered
+    got, fused = jax.jit(K.mla_attention)(q, k, v)
+    assert float(fused) == 0.0 and got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(K._blocked_attention(q, k, v), np.float32))
+
+
+def test_the_kernel_is_the_same_text_whatever_model_file_holds_it():
+    """A tenant's model file is loaded as a module whose name differs from
+    process to process, and the benchmark's differs from seed to seed. jax
+    writes the file names of the traceback into a Pallas kernel's serialized
+    body, which the persistent compile cache hashes: were the code's file
+    name the module's, every process would build the step program anew
+    (110 s on the chip). Lowered for a TPU from two such files, the
+    attention is one text."""
+    from drivers import sweep as sweep_driver
+    from rafiki_tpu.model.base import load_model_class
+
+    q, k, v, _ct = mla_operands(K.KERNEL_BLOCK, jnp.bfloat16)
+    texts, modules = [], []
+    for seed in (1, 2):
+        cls = load_model_class(sweep_driver.model_source(REPO, load_lm_cfg(), seed), "BenchModel")
+        modules.append(cls.__module__)
+        attention = sys.modules[cls.__module__].mla_attention
+        texts.append(jax.jit(attention).trace(q, k, v).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True))
+    assert modules[0] != modules[1]
+    assert "tpu_custom_call" in texts[0] and "rafiki_model.py" in texts[0]
+    assert texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("seq_len", [96, K.KERNEL_BLOCK])
+def test_a_step_counts_its_mla_layers_and_none_of_them_fused_on_the_cpu(seq_len):
+    cfg = tiny_lm(load_lm_cfg(), seq_len=seq_len)
+    _model, fns, params, _ref = program_of(cfg)
+    x, y = tokens(cfg)
+    _loss, metrics = jax.jit(fns["loss_fn"])(params, {"x": x, "y": y}, None,
+                                             {"label_smoothing": jnp.float32(0.0)})
+    assert float(metrics["count.mla.layers"]) == 1.0    # layers: dense, KDA, KDA, MLA, KDA
+    assert float(metrics["count.mla.fused"]) == 0.0
 
 
 def test_expert_layer_matches_the_reference_and_counts_its_rows(cfg, f32):
@@ -186,7 +300,7 @@ def test_logits_loss_counts_and_every_gradient_leaf(cfg, f32):
     model, fns, params, ref = program_of(cfg, label_smoothing=0.07)
     x, y = tokens(cfg)
     module = fns["module"]
-    h, head, _loads = module.apply({"params": params}, x, hidden=True)
+    h, head, _loads, _fused = module.apply({"params": params}, x, hidden=True)
     logits = R.forward(ref, x, cfg)
     assert close(jnp.einsum("btd,dv->btv", h, head, precision="highest"), logits, 5e-5)
     assert close(module.apply({"params": params}, x), logits[:, -1], 5e-5)
@@ -285,6 +399,7 @@ def test_a_trial_trains_scores_counts_and_reloads(cfg):
     assert counters["moe.slots_total"] == 4 * 2 * 96 * 4 * 4
     assert 0 < counters["moe.slots_held"] < counters["moe.slots_total"]
     assert telemetry.get_gauge("moe.held_load_max_over_mean") >= 1.0
+    assert (counters["mla.layers"], counters["mla.fused"]) == (4, 0)   # a step each; the CPU
     blob = model.dump_parameters()
     assert counters.get("persist.blob_bytes", 0) == 0 < telemetry.get_counter("persist.blob_bytes")
     model.release_train_state()            # the CPU reports no limit: nothing staged
