@@ -60,7 +60,7 @@ REPO = Path(__file__).resolve().parent
 EXIT_PHASE_FAILED = 1
 EXIT_NO_ACCELERATOR = 4
 
-#: The size the driver runs: bench.py's canonical trial and BASELINE.md's
+#: The size the driver runs: the canonical trial (BASELINE.md) and its
 #: acceptance configs. Widths are the templates' own; nothing is cut.
 FULL: Dict[str, Any] = {
     "vgg": {"depth": 16, "width_mult": 1.0, "batch_size": 256},
@@ -109,7 +109,7 @@ STACKED_VS_DIRECT_PROB_TOL = 0.01   # |p_gateway - p_direct|; found 1.65e-3
 PACKED_VS_SERIAL_SCORE_TOL = 0.01   # |accuracy| per trial; found 0
 MESH_VS_ONE_CHIP_SCORE_TOL = 0.01   # packs of 2 vs a pack of 8; found 9e-4
 SHARDED_WIDTH_SCORE_TOL = 0.01      # width 4 vs 1; found 0, state bit-identical
-NOISE, FLIP = 0.35, 0.2             # bench.py's non-saturating task
+NOISE, FLIP = 0.35, 0.2             # the non-saturating task (benchmark/datagen.py)
 
 _VGG_SUBCLASS = '''
 

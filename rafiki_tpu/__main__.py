@@ -5,7 +5,6 @@ row) started the reference's services as containers; here the whole
 control plane is one process, so the CLI is the deployment surface:
 
   python -m rafiki_tpu serve [--host H] [--port P]   admin + web UI
-  python -m rafiki_tpu bench                          one-chip benchmark
   python -m rafiki_tpu version
 """
 
@@ -23,7 +22,6 @@ def main(argv=None) -> int:
     serve_p.add_argument("--host", default=None)
     serve_p.add_argument("--port", type=int, default=None)
 
-    sub.add_parser("bench", help="run the one-chip AutoML benchmark")
     sub.add_parser("version", help="print version")
 
     args = parser.parse_args(argv)
@@ -39,15 +37,6 @@ def main(argv=None) -> int:
 
         enable_compilation_cache()
         serve(host=args.host, port=args.port)
-        return 0
-    if args.command == "bench":
-        # bench.py owns its whole jax set-up (platform check, compile
-        # cache): nothing is initialised here on its behalf.
-        import runpy
-        from pathlib import Path
-
-        bench = Path(__file__).resolve().parent.parent / "bench.py"
-        runpy.run_path(str(bench), run_name="__main__")
         return 0
     if args.command == "version":
         import rafiki_tpu

@@ -328,7 +328,7 @@ class ServicesManager:
         # forward (k models, one XLA program); otherwise the
         # reference-shaped fallback of one worker per trial.
         # RAFIKI_STACKED_SERVING=0 forces the replicated route (ops
-        # escape hatch + the A/B knob bench_serving drives).
+        # escape hatch, and the switch for an A/B of the two routes).
         from rafiki_tpu.parallel.serving import build_stacked
 
         stacked, route_reason = None, "disabled-by-env"

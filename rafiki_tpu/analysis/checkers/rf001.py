@@ -10,7 +10,7 @@ Rule: a *process entrypoint* (module-level ``main``/``serve``,
 ``run_*_process`` spawn targets, or an ``if __name__ == "__main__"``
 block) in a module whose import closure reaches jax must call
 ``honor_env_platform()`` or ``force_cpu_backend()`` — directly, or via
-another function in the same module (``bench.main`` pins through
+another function in the same module (a ``main`` that pins through its own
 ``_init_backend``) — and the pin must lexically precede the first
 direct ``jax.*`` use in that scope. A bare ``import jax`` before the
 pin is fine: the platform is chosen at backend *init*, which
@@ -40,7 +40,7 @@ def _calls_in(nodes: Iterable[ast.AST]) -> List[ast.Call]:
 
 def _pinning_functions(tree: ast.Module) -> Set[str]:
     """Module functions that (transitively, within this module) call a
-    pin — covers bench.py's main -> _init_backend -> honor chain."""
+    pin — covers a main -> _init_backend -> honor chain."""
     fns = {f.name: f for f in module_functions(tree)}
     pinning: Set[str] = set()
     changed = True

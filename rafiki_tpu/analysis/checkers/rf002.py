@@ -1,7 +1,7 @@
 """RF002 platform-literal-gate.
 
-Historical bug (round 5, bench.py:607): the bench's MFU fields were
-gated on ``platform == "tpu"`` while the backend of the day registered
+Historical bug (round 5, the one-chip bench script, since retired): its
+MFU fields were gated on ``platform == "tpu"`` while the backend of the day registered
 the chip under another platform name — every run silently reported
 ``mfu: null``.
 

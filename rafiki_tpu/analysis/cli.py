@@ -24,7 +24,7 @@ from typing import List, Optional, Sequence
 from rafiki_tpu.analysis.core import (
     REGISTRY, AnalysisResult, analyze_paths, load_builtin_checkers)
 
-DEFAULT_PATHS = ["rafiki_tpu", "bench.py", "scripts"]
+DEFAULT_PATHS = ["rafiki_tpu", "scripts"]
 
 
 def _format_text(result: AnalysisResult, show_suppressed: bool) -> List[str]:

@@ -25,7 +25,7 @@ KNOB_DOCS = {
     "RAFIKI_AUTOSCALE": "autoscale controller spec; empty disables the "
         "elasticity loop (docs/autoscale.md)",
     "RAFIKI_AUTOSCALE_DAMPING": "flap damping; off exists ONLY so "
-        "tests/smoke can demonstrate the flapping it prevents",
+        "tests can demonstrate the flapping it prevents",
     "RAFIKI_AUTOSCALE_DOWN_COOLDOWN_S": "cooldown after a scale-down "
         "actuation",
     "RAFIKI_AUTOSCALE_DOWN_THRESHOLD": "hysteresis band lower edge "
@@ -55,18 +55,6 @@ KNOB_DOCS = {
         "(pressure above it scales up)",
     "RAFIKI_BACKEND_INIT_TIMEOUT_S": "worker gives up on jax backend "
         "init after this many seconds",
-    "RAFIKI_BENCH_DEADLINE_S": "bench.py wall-clock budget before the "
-        "run is declared hung",
-    "RAFIKI_BENCH_PLATFORM": "cpu = run the bench at the CPU smoke "
-        "scale; otherwise it needs a tpu device",
-    "RAFIKI_BENCH_SELFTEST_FAIL": "bench self-test hook: fail "
-        "deliberately (CI polarity check)",
-    "RAFIKI_BENCH_SELFTEST_SLEEP_S": "bench self-test hook: sleep to "
-        "trip the deadline gate",
-    "RAFIKI_BENCH_TOP1_TARGET": "override the per-scale top-1 accuracy "
-        "gate (calibrated default per platform)",
-    "RAFIKI_BENCH_TRIALS": "override trial count for both bench scales "
-        "(unset: 3 on cpu smoke, 30 on tpu)",
     "RAFIKI_BUS_REAP_FACTOR": "multiplier on queue TTL before an "
         "abandoned entry is reaped",
     "RAFIKI_CAS_CHUNK_KB": "content-addressed params store chunk size",
@@ -147,7 +135,7 @@ KNOB_DOCS = {
         "planner fits a group member under (docs/sharding.md)",
     "RAFIKI_SHARD_MAX_WIDTH": "cap on the solved group width even "
         "when the HBM estimate wants more chips",
-    "RAFIKI_SHARD_WIDTH": "pin the group width (tests/smokes); 0 "
+    "RAFIKI_SHARD_WIDTH": "pin the group width (tests); 0 "
         "solves it from the HBM estimate",
     "RAFIKI_SLO": "SLO spec overrides as JSON; empty keeps the "
         "defaults (docs/slo.md)",
@@ -173,7 +161,7 @@ KNOB_DOCS = {
     "RAFIKI_TENANT_TIERS": "tenant→tier map, e.g. "
         "\"alice=gold,bob=batch\" (docs/multitenancy.md)",
     "RAFIKI_TENANT_UNWEIGHTED": "polarity knob: disable weighted "
-        "admission and quotas (tenancy smoke's doctored run)",
+        "admission and quotas (the tenancy tests' doctored run)",
     "RAFIKI_TPU_DATA_DIR": "root for all durable state (stores, "
         "journals, caches)",
     "RAFIKI_TRACE_ID": "trace id stamped on every journal record of "

@@ -229,7 +229,7 @@ def _collect_py_files(paths: Sequence[str]) -> List[str]:
 def module_name_for(path: str) -> str:
     """Dotted module name: walk up while __init__.py exists, so
     rafiki_tpu/bus/queues.py -> rafiki_tpu.bus.queues; a top-level
-    script (bench.py) is just its stem."""
+    script (chip_smoke.py) is just its stem."""
     path = os.path.abspath(path)
     parts = [os.path.splitext(os.path.basename(path))[0]]
     d = os.path.dirname(path)

@@ -27,7 +27,7 @@ Stability machinery, all per lane:
     actuation count instead of thrashing (the
     ``autoscale-flap-damping`` chaos scenario proves it). Damping can
     be disabled (``damping=False`` / RAFIKI_AUTOSCALE_DAMPING=0) only
-    so tests and the smoke's vacuous-pass polarity can demonstrate the
+    so the tests' vacuous-pass polarity can demonstrate the
     flapping it prevents.
 
 Every decision — including holds — journals ``autoscale/decision``
@@ -282,7 +282,7 @@ class AutoscaleController:
 
     def actuation_count(self, lane_name: str) -> int:
         """Total actuations recorded for a lane (bounded-actuation
-        assertions in the flap scenario/smoke)."""
+        assertions in the flap scenario)."""
         return len(self._history[lane_name])
 
     def _recent_flips(self, lane_name: str, now: float) -> int:

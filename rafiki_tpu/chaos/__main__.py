@@ -1,8 +1,9 @@
 """CLI: ``python -m rafiki_tpu.chaos run <scenario>|all`` / ``list``.
 
 Runs recovery scenarios against an in-proc cluster and exits nonzero
-on any failed invariant — the entrypoint scripts/chaos_smoke.py and
-operators use to replay a fault schedule deterministically.
+on any failed invariant — the entrypoint operators use to replay a
+fault schedule deterministically (tests/test_chaos.py runs the
+scenarios through the runner's Python API).
 """
 
 from __future__ import annotations

@@ -1177,9 +1177,8 @@ def load_spike_scale_up(tmp, check: CheckFn) -> None:
           "burn never cleared after scale-up")
     if breach_at is not None and recovered_at is not None:
         recovery_s = recovered_at - breach_at
-        # The smoke reads this gauge right after run_scenario (the
-        # runner resets telemetry BEFORE the body, not after) and
-        # trends it through SCALE_r*.json.
+        # A caller reads this gauge right after run_scenario (the
+        # runner resets telemetry BEFORE the body, not after).
         telemetry.set_gauge("autoscale.recovery_s", round(recovery_s, 3))
         check("recovery_within_budget", recovery_s < 8.0,
               f"recovery took {recovery_s:.2f}s")
@@ -1820,7 +1819,7 @@ def noisy_neighbor_shed(tmp, check: CheckFn) -> None:
         # quota_frac 0.5 each tenant may hold ONE. Weighted mode caps
         # the aggressor at that one slot — the victim is always the
         # next eligible tenant and waits at most one in-flight forward.
-        # Unweighted (the doctored smoke polarity) ignores the quota
+        # Unweighted (the doctored polarity) ignores the quota
         # and degrades to global FIFO, so the victim queues behind the
         # whole flood — which is exactly what blows the victim-p99
         # gate below. (max_inflight=1 would NOT separate the modes:

@@ -87,7 +87,7 @@ class Transformer(JaxModel):
         """Solve this configuration's group width from the param tree's
         shapes alone (eval_shape — nothing is materialized). Width 1
         (the usual answer for this small grid) keeps the trial in the
-        serial/packed lanes; tests and smokes pin wider groups via
+        serial/packed lanes; tests pin wider groups via
         ``RAFIKI_SHARD_WIDTH``."""
         import jax
 

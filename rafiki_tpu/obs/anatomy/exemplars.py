@@ -77,7 +77,7 @@ class ExemplarRing:
             self._journal_items(rolled)
 
     def flush(self) -> int:
-        """Force the current window closed (bench/smoke teardown —
+        """Force the current window closed (a short run's teardown —
         otherwise a run shorter than ``window_s`` journals nothing).
         Returns how many exemplars were journaled."""
         with self._lock:

@@ -408,7 +408,7 @@ def cmd_curves(log_dir: str, trial: Optional[str], as_json: bool,
 def cmd_replay(path: str, as_json: bool) -> int:
     """Re-execute a divergence capsule and report the bit-comparison.
     Exit 0 only when every compared sentinel value reproduced exactly —
-    the determinism contract scripts/health_smoke.py enforces."""
+    the determinism contract tests/test_health.py enforces."""
     from rafiki_tpu.obs.health import capsule
 
     try:
@@ -758,7 +758,7 @@ def cmd_autoscale(log_dir: str, n: int, as_json: bool, check: bool,
                   window_s: float, max_flips: int) -> int:
     """Replay the controller's decision stream; with ``--check``, gate
     on flap: actuated direction flips per lane inside ``window_s``
-    must stay under ``max_flips`` (the smoke's vacuous-pass polarity
+    must stay under ``max_flips`` (the tests' vacuous-pass polarity
     runs an undamped controller through here and MUST fail)."""
     records = [r for r in journal_mod.read_dir(log_dir)
                if r.get("kind") == "autoscale"]

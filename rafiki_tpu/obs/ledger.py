@@ -18,8 +18,7 @@ section) buckets of
 
 rolled up to ``goodput = productive_step_s / wall_s`` per entity and
 fleet-wide. The roll-up is exposed as the ``goodput`` telemetry
-collector, so it rides along in every ``GET /metrics`` snapshot and in
-``bench.py`` detail.
+collector, so it rides along in every ``GET /metrics`` snapshot.
 
 Charging is ambient: ``with ledger.entity("trial:t1"): ...`` binds the
 entity to the thread (nestable — inner entities win), and the training

@@ -318,7 +318,7 @@ engine = SloEngine(specs=_specs_from_env())
 
 def configure(specs: Optional[Sequence[SloSpec]] = None,
               tick_s: Optional[float] = None) -> SloEngine:
-    """(Re)configure the global engine — smoke scripts and tests."""
+    """(Re)configure the global engine — tests."""
     engine.configure(specs=specs, tick_s=tick_s)
     return engine
 

@@ -23,7 +23,7 @@ package closes that:
   journaled pack/evict/backfill/resume/repack events into explicit
   trial genealogy, with fleet-wide orphan reconciliation;
 * :mod:`~rafiki_tpu.obs.search.stats` — the seeded bootstrap-CI
-  helper shared by the reconstruction and ``bench.py``.
+  helper the reconstruction uses.
 
 Read through ``python -m rafiki_tpu.obs sweep`` / ``... lineage``
 (docs/search_anatomy.md).

@@ -215,7 +215,7 @@ def record_correct(advisor: Any, knobs: Dict[str, Any],
 
 def record_false_kill(knobs: Dict[str, Any], killed_predicted: float,
                       sibling_score: float, best_so_far: float) -> None:
-    """Hindsight verdict from a ground-truth checker (sweep smoke
+    """Hindsight verdict from a ground-truth checker (the A/B test
     re-runs each killed trial's knobs to completion): the sibling
     finished above best-so-far, so the kill cost the search a
     contender."""

@@ -11,8 +11,8 @@ Mounted by :mod:`rafiki_tpu.obs.cli` the same way the twin verbs are:
                     bootstrap CI. Exit 1 when audit reconciliation
                     fails (a feedback or batch member with no propose
                     record) or no advisor records exist. ``--out``
-                    writes the trendable SWEEP_r*.json artifact for
-                    ``bench_report --sweep``.
+                    writes the sweep's headline keys as one JSON
+                    artifact.
     lineage [trial] walk one trial across incarnations, chips and
                     packs; omit the trial for the fleet-wide table.
                     ``--check`` exits 1 on orphaned incarnations —
@@ -42,7 +42,7 @@ def attach(sub) -> None:
     sp.add_argument("job", nargs="?", default=None,
                     help="job-id substring or advisor-id prefix filter")
     sp.add_argument("--out", default=None,
-                    help="write the SWEEP artifact (bench_report --sweep)")
+                    help="write the sweep's headline keys as a JSON artifact")
     sp.add_argument("--boot-seed", type=int, default=0,
                     help="bootstrap-CI seed (default 0, deterministic)")
     sp = sub.add_parser(

@@ -8,7 +8,7 @@ backfilled). The roll-up is the ROADMAP's learning-curve success
 metric — ``search.effective_trials_per_hour`` at equal final best —
 plus ``search.regret`` and ``search.best_score``, exposed as the
 ``search`` telemetry collector so it rides every ``GET /metrics``
-snapshot and ``bench.py`` detail.
+snapshot.
 
 Charging is keyed by the audit plane's knobs-hash: ``note_propose``
 opens the meter for a hash, the worker's error paths call
@@ -47,7 +47,7 @@ class SearchLedger:
             self.best_score: Optional[float] = None
             # Curve-advisor outcomes (docs/early_kill.md). Kills are a
             # subset of doomed; false kills are hindsight verdicts a
-            # ground-truth checker (sweep smoke's sibling re-runs)
+            # ground-truth checker (the A/B test's sibling re-runs)
             # establishes after the fact.
             self.n_killed = 0
             self.n_false_kills = 0
@@ -84,7 +84,7 @@ class SearchLedger:
 
     def note_false_kill(self) -> None:
         """Hindsight verdict: a killed trial's sibling re-run finished
-        above best-so-far (sweep smoke's false-kill gate)."""
+        above best-so-far (tests/test_curve_kill.py's false-kill gate)."""
         with self._lock:
             self.n_false_kills += 1
             n = self.n_false_kills

@@ -6,14 +6,14 @@ the reader that turns a journal directory back into the sweep:
 ordered proposals with their acquisition breakdowns, the score each
 one earned, the best-so-far/regret curve, lineage roll-ups, and —
 when a random-engine baseline ran beside the main advisor — the
-advisor lift with a seeded bootstrap CI (the same
-:func:`~rafiki_tpu.obs.search.stats.bootstrap_ci` bench.py uses).
+advisor lift with a seeded bootstrap CI
+(:func:`~rafiki_tpu.obs.search.stats.bootstrap_ci`).
 
 Reconciliation is always on and loud: a ``feedback`` whose knobs-hash
 never appeared in a ``propose`` record, or a ``propose_batch`` member
 with no matching ``propose``, means an advisor decision escaped the
-audit trail — the CLI exits nonzero naming the hash, and the sweep
-smoke proves that path by doctoring a journal.
+audit trail — the CLI exits nonzero naming the hash, and
+tests/test_search_obs.py proves that path by doctoring a journal.
 
 Joins (all by the canonical knobs-hash):
 
@@ -333,9 +333,9 @@ def reconstruct(records: List[Dict[str, Any]], job: Optional[str] = None,
 
 
 def artifact(doc: Dict[str, Any]) -> Dict[str, Any]:
-    """The trendable SWEEP_r*.json slice of a sweep document — headline
-    keys at top level for ``bench_report --sweep`` (polarities live in
-    its SWEEP_METRICS table)."""
+    """The headline slice of a sweep document (``obs sweep --out``):
+    the keys a reader compares from one sweep to the next, at top
+    level."""
     keys = ("sweep_schema_version", "job", "engine", "seed",
             "n_proposals", "n_scored", "n_doomed", "span_s",
             "best_score", "regret", "effective_trials_per_hour",

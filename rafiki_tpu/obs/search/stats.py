@@ -1,10 +1,8 @@
-"""Seeded bootstrap statistics shared by the sweep reconstruction and
-``bench.py``'s ``detail.search`` block.
+"""Seeded bootstrap statistics for the sweep reconstruction.
 
-One implementation so the CI printed by ``obs sweep`` and the CI
-gated by ``bench_report --sweep`` cannot drift apart. Deterministic
-under a fixed seed — tests and the sweep smoke assert byte-equality
-across runs.
+One implementation, so the CI ``obs sweep`` prints and the CI its
+``--out`` artifact carries cannot drift apart. Deterministic under a
+fixed seed — the tests assert byte-equality across runs.
 """
 
 from __future__ import annotations

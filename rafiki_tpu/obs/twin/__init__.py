@@ -20,7 +20,7 @@ Layers:
 * :mod:`~rafiki_tpu.obs.twin.whatif` — knob sweeps, the
   ``RAFIKI_SLO``-aware smallest-fleet search;
 * :mod:`~rafiki_tpu.obs.twin.validate` — predicted-vs-measured gating
-  against a real ``bench_serving`` run;
+  against a captured serving run;
 * :mod:`~rafiki_tpu.obs.twin.pregate` — the chaos runner's offline
   fault forecast.
 

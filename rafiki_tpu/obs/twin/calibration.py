@@ -81,8 +81,7 @@ class CalibrationError(ValueError):
         super().__init__(
             "cannot calibrate twin from %r: missing journal record "
             "kind(s): %s — run the workload with RAFIKI_LOG_DIR set "
-            "(e.g. bench_serving --smoke) so the serving plane journals "
-            "them" % (source or "<records>", ", ".join(self.missing)))
+            "so the serving plane journals them" % (source or "<records>", ", ".join(self.missing)))
 
 
 def _cap(samples: List[float]) -> List[float]:
@@ -242,7 +241,7 @@ class Calibration:
 
     def scaled(self, scales: Dict[str, float]) -> "Calibration":
         """A copy with named segments multiplied — the deliberate
-        mis-calibration knob the validation smoke uses to prove the
+        mis-calibration knob the validation tests use to prove the
         gate fails when the model is wrong."""
         unknown = set(scales) - set(SAMPLED_SEGMENTS)
         if unknown:

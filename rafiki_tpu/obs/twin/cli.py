@@ -71,14 +71,13 @@ def attach(sub: argparse._SubParsersAction) -> None:
                          "(implies --fleet)")
 
     sp = tsub.add_parser("validate",
-                         help="replay a captured bench_serving run; "
+                         help="replay a captured serving run; "
                               "gate predicted-vs-measured p50/p99 error")
     common(sp)
     sp.add_argument("--tolerance", type=float, default=None,
                     help="relative-error gate (default 0.40)")
     sp.add_argument("--out", default=None,
-                    help="write the TWIN artifact JSON here (the "
-                         "bench_report --twin ledger format)")
+                    help="write the TWIN artifact JSON here")
 
     train_cli.attach(tsub)
 

@@ -760,5 +760,5 @@ def _ms(v: Optional[float]) -> Optional[float]:
 
 def result_fingerprint(result: Dict[str, Any]) -> str:
     """A stable digest of everything deterministic in a result — the
-    bit-identical-replay assertion surface (tests, twin_smoke)."""
+    bit-identical-replay assertion surface (tests)."""
     return sha1(json.dumps(result, sort_keys=True).encode()).hexdigest()

@@ -17,7 +17,7 @@ Layers:
 * :mod:`~rafiki_tpu.obs.twin.train.whatif` — best pack width per key,
   the chips-vs-pack split search, proposed-member forecasts;
 * :mod:`~rafiki_tpu.obs.twin.train.validate` — predicted-vs-measured
-  gating against a captured mesh sweep (TRAINTWIN_r*.json);
+  gating against a captured mesh sweep;
 * :mod:`~rafiki_tpu.obs.twin.train.placement` — the advisory
   sweep-admission consultation behind ``RAFIKI_TWIN_PLACEMENT``;
 * :mod:`~rafiki_tpu.obs.twin.train.pregate` — SweepChipLane autoscale

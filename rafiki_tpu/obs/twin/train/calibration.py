@@ -52,7 +52,7 @@ TRAIN_CALIBRATION_VERSION = 1
 REQUIRED_KINDS = ("perf/step", "mesh/pack_formed")
 
 #: Segments :meth:`TrainCalibration.scaled` may doctor — the
-#: deliberate mis-calibration knob the validation smoke uses.
+#: deliberate mis-calibration knob the validation tests use.
 SCALABLE_SEGMENTS = ("step", "compile")
 
 #: Multiplier spread for :meth:`TrainCalibration.nominal` warm epochs —
@@ -71,7 +71,7 @@ class TrainCalibrationError(CalibrationError):
     """A journal dir missing required TRAIN record kinds. ``missing``
     lists every absent kind so the operator fixes the capture once.
     Subclasses the serving :class:`CalibrationError` so existing
-    ``except CalibrationError`` handlers (CLI, smokes) catch both."""
+    ``except CalibrationError`` handlers (the CLI's) catch both."""
 
     def __init__(self, missing: List[str], source: str = ""):
         self.missing = list(missing)
@@ -80,8 +80,7 @@ class TrainCalibrationError(CalibrationError):
             self,
             "cannot calibrate the train twin from %r: missing journal "
             "record kind(s): %s — run a mesh sweep with RAFIKI_LOG_DIR "
-            "set (e.g. scripts/train_twin_smoke.py --capture DIR) so "
-            "the sweep plane journals them"
+            "set so the sweep plane journals them"
             % (source or "<records>", ", ".join(self.missing)))
 
 
@@ -308,7 +307,7 @@ class TrainCalibration:
 
     def scaled(self, scales: Dict[str, float]) -> "TrainCalibration":
         """A copy with named segments multiplied — the deliberate
-        mis-calibration knob the validation smoke uses to prove the
+        mis-calibration knob the validation tests use to prove the
         gate fails when the model is wrong."""
         unknown = set(scales) - set(SCALABLE_SEGMENTS)
         if unknown:

@@ -83,8 +83,7 @@ def attach(tsub: argparse._SubParsersAction) -> None:
     sp.add_argument("--tolerance", type=float, default=None,
                     help="relative-error gate (default 0.25)")
     sp.add_argument("--out", default=None,
-                    help="write the TRAINTWIN artifact JSON here (the "
-                         "bench_report --train-twin ledger format)")
+                    help="write the TRAINTWIN artifact JSON here")
 
 
 def _load_calibration(args, log_dir):

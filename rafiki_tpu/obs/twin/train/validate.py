@@ -18,7 +18,7 @@ comparison honest:
 Prediction error is relative for BOTH trials/hour and total wall:
 ``|predicted - measured| / measured``; the gate passes only if both
 are within tolerance. ``scales`` deliberately mis-calibrates (e.g.
-``step=2.0``) — the negative polarity in scripts/train_twin_smoke.py
+``step=2.0``) — the negative polarity in tests/test_train_twin.py
 proves the gate actually fails when the model is wrong.
 """
 
@@ -75,8 +75,8 @@ def validate(log_dir, seed: int = 0,
              tolerance: float = DEFAULT_TOLERANCE,
              scales: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
     """Score the train twin against one captured sweep. Returns the
-    gate artifact (the TRAINTWIN_r*.json / ``bench_report
-    --train-twin`` ledger format); ``ok`` is the verdict. Raises
+    gate artifact (what ``obs twin train validate --out`` writes);
+    ``ok`` is the verdict. Raises
     :class:`TrainCalibrationError` if the journals can't calibrate and
     ``ValueError`` when too few trials were measured."""
     records = journal_mod.read_dir(log_dir)
@@ -92,7 +92,7 @@ def validate(log_dir, seed: int = 0,
             f"only {n_meas} measured trial(s) over "
             f"{wall_meas if wall_meas else 0:.3f}s in {log_dir}; need "
             f">= {MIN_TRIALS} trials with packed perf/step records "
-            f"(run scripts/train_twin_smoke.py --capture DIR)")
+            f"(run a mesh sweep with RAFIKI_LOG_DIR set)")
     packs = packs_from_calibration(cal)
     cfg = TrainTwinConfig.from_calibration(cal)
     res = simulate(cal, cfg, packs=packs, seed=seed)
@@ -122,7 +122,7 @@ def validate(log_dir, seed: int = 0,
         "ok": ok,
         "event_log_sha1": res["event_log_sha1"],
         "config": res["config"],
-        # Wall stamp for the TRAINTWIN_r*.json trend ledger — metadata
+        # Wall stamp of the artifact — metadata
         # only, never an input to the simulation itself.
         "created_ts": round(time.time(), 3),  # lint: disable=RF010 — artifact timestamp, not simulation state; determinism covers everything above
     }

@@ -16,7 +16,7 @@ same journal directory:
 Prediction error is relative: ``|predicted - measured| / measured``
 for p50 and p99. The gate passes only if BOTH are within tolerance.
 ``scales`` deliberately mis-calibrates named segments (e.g. forward
-halved) — the negative polarity scripts/twin_smoke.py proves the gate
+halved) — the negative polarity tests/test_twin.py proves the gate
 actually fails when the model is wrong.
 """
 
@@ -79,7 +79,7 @@ def validate(log_dir, seed: int = 0,
         raise ValueError(
             f"only {len(latencies)} serving/request record(s) in "
             f"{log_dir}; need >= {MIN_REQUESTS} for a meaningful "
-            f"percentile comparison (run bench_serving --smoke with "
+            f"percentile comparison (drive the gateway with "
             f"RAFIKI_LOG_DIR set)")
     cfg = TwinConfig.from_calibration(cal)
     res = simulate(cal, cfg, arrivals, seed=seed)
@@ -107,7 +107,7 @@ def validate(log_dir, seed: int = 0,
         "ok": ok,
         "event_log_sha1": res["event_log_sha1"],
         "config": res["config"],
-        # Wall stamp for the TWIN_r*.json trend ledger — metadata only,
+        # Wall stamp of the artifact — metadata only,
         # never an input to the simulation itself.
         "created_ts": round(time.time(), 3),  # lint: disable=RF010 — artifact timestamp, not simulation state; determinism covers everything above
     }
@@ -161,7 +161,7 @@ def validate_tenants(log_dir, seed: int = 0,
                      scales: Optional[Dict[str, float]] = None
                      ) -> Dict[str, Any]:
     """Score the twin's weighted-admission model against a captured
-    ``bench_serving --tenants`` run: replay the per-tenant arrival
+    multi-tenant serving run: replay the per-tenant arrival
     trains through the simulator with the capture's own tier weights
     and gate each tenant's predicted p99 against its measured p99.
     This is the model-fidelity check behind the new-job pre-gate
@@ -179,7 +179,7 @@ def validate_tenants(log_dir, seed: int = 0,
     if total < MIN_REQUESTS:
         raise ValueError(
             f"only {total} serving/request record(s) in {log_dir}; need "
-            f">= {MIN_REQUESTS} (run bench_serving --smoke --tenants "
+            f">= {MIN_REQUESTS} (drive the gateway as several tenants "
             f"with RAFIKI_LOG_DIR set)")
     tiers = TIERS()
     classes = {t: {"weight": tiers.get(tier_names.get(t, ""),

@@ -156,7 +156,7 @@ def suggest_slo(fleet: Dict[str, Any]) -> List[Dict[str, Any]]:
     where latency is highest among compliant picks — budgets derived
     there hold for any larger fleet. Output round-trips through
     ``SloSpec.from_dict`` / ``RAFIKI_SLO=<json>`` byte-identically for
-    the same fleet doc (scripts/twin_smoke.py asserts this), so an
+    the same fleet doc (tests/test_twin.py asserts this), so an
     operator can paste it straight into the live burn-rate engine.
 
     When no scanned fleet met the default targets, anchor on the best

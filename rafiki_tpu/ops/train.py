@@ -604,10 +604,6 @@ class TrainLoop:
         # sentinel scalars at each epoch boundary; serial loops fail
         # fast (DivergenceError) on divergence.
         self.health = HealthMonitor(str(self._perf_key))
-        # Back-compat aliases (bench/tests poke the private names).
-        self._train_step = self.program.train_step
-        self._eval_step = self.program.eval_step
-        self._predict = self.program.predict
 
         if initial_state is not None:
             self.state = self.plan.put_state(initial_state)
@@ -697,7 +693,7 @@ class TrainLoop:
         # Chaos site INSIDE the timed region (unlike collective.step
         # above): an injected delay here inflates the measured epoch
         # wall, which is exactly what the perf sentinel's anomaly
-        # detector watches — perf_smoke.py drives it through this site.
+        # detector watches — tests/test_perf.py drives it through this site.
         from rafiki_tpu import chaos as _chaos
 
         _chaos.hook("train.epoch", key=str(self._perf_key))
@@ -752,7 +748,7 @@ class TrainLoop:
 
         dev_batch = put_next()
         cold = not getattr(self, "_warm", False)
-        step = self._train_step
+        step = self.program.train_step
         steps = []      # each step's metric dict, device scalars
         with telemetry.span("train.epoch", leaf=True, cold=cold, steps=n_steps):
             while dev_batch is not None:
@@ -810,12 +806,12 @@ class TrainLoop:
 
         if self.epoch_program:
             if cold:
-                _profiler.capture_cost(self._perf_key, self._train_step,
+                _profiler.capture_cost(self._perf_key, self.program.train_step,
                                        self.state, dev_batch)
-            return self._train_step
+            return self.program.train_step
         exe = self.program.compiled_step(self.state, dev_batch)
         if cold:
-            _profiler.capture_cost(self._perf_key, self._train_step, compiled=exe)
+            _profiler.capture_cost(self._perf_key, self.program.train_step, compiled=exe)
         return exe
 
     def _chaos_poison(self, n_steps: int) -> np.ndarray:
@@ -900,7 +896,7 @@ class TrainLoop:
         for batch in dataset.batches(batch_size, shuffle=False, drop_remainder=False,
                                      start=start):
             dev_batch = self.plan.put_batch(batch)
-            c, n = self._eval_step(self.state[0], dev_batch)
+            c, n = self.program.eval_step(self.state[0], dev_batch)
             total_correct = total_correct + c
             total = total + n
         return int(total_correct) / max(int(total), 1)
@@ -917,7 +913,7 @@ class TrainLoop:
             batch = {"x": chunk}
             if extra:
                 batch.update(extra)
-            probs = np.asarray(self._predict(self.state[0], self.plan.put_batch(batch)))
+            probs = np.asarray(self.program.predict(self.state[0], self.plan.put_batch(batch)))
             outs.append(probs[: batch_size - pad] if pad else probs)
         return np.concatenate(outs) if outs else np.zeros((0,))
 

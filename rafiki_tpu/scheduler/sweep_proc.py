@@ -7,7 +7,7 @@ testing needs it in a process OF ITS OWN, so a chaos fault (the
 supervisor without taking the test harness down with it — and so
 ``resume_sweep`` can then prove a genuinely fresh process (no shared
 memory, only the MetaStore + sweep WAL + journals) adopts the job.
-The chaos scenarios (chaos/scenarios.py) and scripts/resume_smoke.py
+The chaos scenarios (chaos/scenarios.py) and tests/test_recovery.py
 drive sweeps through this module; it is equally usable as a manual
 supervisor launcher.
 

@@ -2,10 +2,10 @@
 tracer behind a module-level functional API.
 
 Every subsystem writes through these functions; every reader (the
-``GET /metrics`` endpoints on the admin and predictor apps, bench.py's
-embedded snapshot, tests) reads the SAME
-state via :func:`snapshot`, so "what the bench reports" and "what the
-serving endpoint shows" can never drift apart.
+``GET /metrics`` endpoints on the admin and predictor apps, the
+benchmark's drivers, tests) reads the SAME
+state via :func:`snapshot`, so "what the benchmark reports" and "what
+the serving endpoint shows" can never drift apart.
 
 Write API (cheap, thread-safe, never raises into callers):
     inc("bus.reaped_workers")            counters (floats allowed)

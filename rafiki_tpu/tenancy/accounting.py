@@ -35,7 +35,7 @@ from rafiki_tpu.obs.journal import journal as _journal
 from rafiki_tpu.tenancy.qos import TenantDirectory
 
 #: Rolling latency window per tenant — enough for a stable p99 at
-#: smoke scale without unbounded growth.
+#: test scale without unbounded growth.
 LATENCY_WINDOW = 512
 
 

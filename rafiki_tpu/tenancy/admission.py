@@ -17,7 +17,7 @@ two primitives tenant isolation needs:
   tenant's share under contention — proportional share, not absolute
   priority: batch still progresses.
 
-With ``RAFIKI_TENANT_UNWEIGHTED=1`` (the tenancy smoke's doctored
+With ``RAFIKI_TENANT_UNWEIGHTED=1`` (the tenancy tests' doctored
 polarity) quotas widen to the whole gateway and granting degrades to
 global FIFO — exactly the pre-tenancy behaviour, which demonstrably
 fails the victim-p99 gate.

@@ -87,8 +87,8 @@ class JobAdmissionGate:
                      require_valid: bool = True,
                      tolerance: Optional[float] = None
                      ) -> "JobAdmissionGate":
-        """Build the gate straight from a ``bench_serving --tenants``
-        capture: calibration, gateway knobs, AND the existing
+        """Build the gate straight from a captured multi-tenant serving
+        run: calibration, gateway knobs, AND the existing
         per-tenant load (tier + observed qps) all come from the same
         journal directory. With ``require_valid`` (the default) the
         twin's weighted-admission model must first pass

@@ -30,7 +30,7 @@ Knobs (defaults in :data:`TIERS`, one-liners in docs/knobs.md):
                                  gateway's inflight/queue capacity
     RAFIKI_TENANT_MAX_TENANTS    bound on tracked per-tenant state
     RAFIKI_TENANT_UNWEIGHTED     polarity knob: disable weighting and
-                                 quotas (tenancy smoke's doctored run)
+                                 quotas (the tenancy tests' doctored run)
 """
 
 from __future__ import annotations
@@ -80,7 +80,7 @@ def _env_int(name: str, default: int) -> int:
 
 def unweighted() -> bool:
     """Whether weighted-fair admission is DISABLED (quotas off, all
-    weights equal) — exists only so the tenancy smoke can run the
+    weights equal) — exists only so the tenancy tests can run the
     doctored polarity and watch the victim-p99 gate fail."""
     return os.environ.get(ENV_PREFIX + "UNWEIGHTED", "").lower() in (
         "1", "true", "yes", "on")
@@ -99,7 +99,7 @@ class QosClass:
 
 def TIERS() -> Dict[str, QosClass]:
     """The three tiers with env-overridable weights. A function, not a
-    module constant, so tests and the smoke's doctored polarity can
+    module constant, so the tests' doctored polarity can
     flip knobs per-process without import-order traps."""
     if unweighted():
         gold = std = batch = 1.0

@@ -103,8 +103,8 @@ def enable_compilation_cache() -> str:
 
 
 #: Peak dense bf16 FLOP/s per chip, keyed by ``device.device_kind`` —
-#: the one MFU denominator (bench.py and obs/perf/profiler.py both read
-#: it). Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16.
+#: the one MFU denominator (obs/perf/profiler.py and the benchmark's
+#: readers divide by it). Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16.
 PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
 
 

@@ -109,6 +109,7 @@ class InferenceWorker:
                           for tr in traces]
                 for qid, tr in zip(qids, traces):
                     if tr:
+                        # lint: disable=RF014 — read by trace id, not by kind: `obs trace <id>` stitches it into the trace (tests/test_obs.py)
                         _journal.record(
                             "bus", "pop_query", query_id=qid,
                             worker_id=self.worker_id,

@@ -1,9 +1,8 @@
 #!/usr/bin/env bash
 # Static-analysis gate: zero unsuppressed findings over the canonical
 # path set (see docs/static_analysis.md). Same checkers, same paths as
-# tests/test_lint_clean.py — this is the shell-visible form CI and
-# check_tier1.sh use. JSON output so a failing run leaves a
-# machine-readable artifact on stdout.
+# tests/test_lint_clean.py — this is the shell-visible form. JSON
+# output so a failing run leaves a machine-readable artifact on stdout.
 #
 # The contracts pass (docs/static_analysis.md, "Contracts") then diffs
 # the freshly extracted contracts manifest and the generated knob docs
@@ -16,7 +15,7 @@
 set -o pipefail
 cd "$(dirname "$0")/.."
 
-PATHS="rafiki_tpu bench.py scripts"
+PATHS="rafiki_tpu scripts"
 GOLDEN=tests/data/contracts_manifest.json
 KNOBS=docs/knobs.md
 

@@ -21,12 +21,10 @@ ride chunk-level dedup, not rewrite the tree.
 
 Usage::
 
-    python scripts/measure_store_throughput.py [n_workers] [trials] \
-        [--out STORE_rNN.json]
+    python scripts/measure_store_throughput.py [n_workers] [trials]
 
-Prints one machine-readable JSON line (headline keys at top level —
-``bench_report --store`` trends STORE_r*.json artifacts of it); exits
-non-zero when the dedup gate fails.
+Prints one machine-readable JSON line (headline keys at top level);
+exits non-zero when the dedup gate fails.
 """
 
 from __future__ import annotations
@@ -178,8 +176,6 @@ def main(argv=None) -> int:
         description="meta-store ceiling + CAS params dedup, one JSON line")
     p.add_argument("n_workers", nargs="?", type=int, default=8)
     p.add_argument("trials", nargs="?", type=int, default=400)
-    p.add_argument("--out", help="also write the artifact here "
-                                 "(STORE_rNN.json round file)")
     args = p.parse_args(argv)
 
     doc = {"store_schema_version": 1}
@@ -188,11 +184,7 @@ def main(argv=None) -> int:
     # The ISSUE 14 acceptance gate: a near-identical second checkpoint
     # streams deltas, not the tree.
     doc["dedup_gate"] = doc["second_write_frac"] < 0.20
-    line = json.dumps(doc)
-    print(line)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(line + "\n")
+    print(json.dumps(doc))
     return 0 if doc["dedup_gate"] else 1
 
 

@@ -14,12 +14,12 @@ With ``--train`` the TRAIN twin's bundle is extracted instead:
 per-(packing_key, k) epoch samples (``perf/step``), the captured pack
 placement (``mesh/pack_formed``) and sweep shape, fitted epoch
 overhead, and cost rows (docs/twin.md). The usual fix for a missing-
-kinds failure there is ``scripts/train_twin_smoke.py --capture DIR``.
+kinds failure there is a mesh sweep run with ``RAFIKI_LOG_DIR`` set.
 
 Fails LOUDLY (exit 2) listing every missing record kind rather than
 defaulting anything: a twin calibrated on air predicts air. The usual
-fix is re-running the workload (e.g. ``scripts/bench_serving.py
---smoke``) with ``RAFIKI_LOG_DIR`` pointed at a fresh directory.
+fix is re-running the workload with ``RAFIKI_LOG_DIR`` pointed at a
+fresh directory.
 
 Exit codes: 0 bundle written, 2 calibration impossible (missing
 kinds / unreadable dir), plus a summary line on stdout either way.

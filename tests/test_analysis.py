@@ -87,7 +87,7 @@ def test_rf001_fires_when_jax_touched_before_pin(tmp_path):
 
 
 def test_rf001_pin_through_local_helper_chain(tmp_path):
-    # bench.py's shape: main -> _init_backend -> honor_env_platform
+    # a root script's shape: main -> _init_backend -> honor_env_platform
     r = _analyze_snippet(tmp_path, """
         import jax
 
@@ -166,13 +166,34 @@ def test_rf002_quiet_on_cpu_gate_and_membership(tmp_path):
     assert "RF002" not in _ids(r)
 
 
+#: The MFU gate of the retired one-chip bench script as it stood when
+#: the round-5 bug was fixed (kept here as history: the script is gone,
+#: the failure class is not).
+HISTORICAL_MFU_GATE = '''
+def microbench(sc, loop, dev_b, step_s):
+    mfu = mfu_model = None
+    if sc["platform"] != "cpu":
+        from rafiki_tpu.utils.backend import peak_bf16_flops
+
+        peak = peak_bf16_flops(jax.devices()[0].device_kind)
+        # whole-program flops from XLA's own cost model
+        compiled = loop._train_step.lower(loop.state, dev_b).compile()
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
+        if flops > 0:
+            mfu = flops / step_s / peak
+    return mfu, mfu_model
+'''
+
+
 def test_rf002_real_prefix_bench_mfu_gate(tmp_path):
-    """The round-5 bug verbatim: bench.py's MFU gate reverted to the
+    """The round-5 bug verbatim: the bench's MFU gate reverted to the
     == "tpu" form that nulled MFU when the backend registered the chip
     under another platform name."""
-    live = open(os.path.join(REPO, "bench.py")).read()
-    assert 'sc["platform"] != "cpu"' in live  # the fix is present today
-    prefix = live.replace('sc["platform"] != "cpu"', 'sc["platform"] == "tpu"')
+    assert 'sc["platform"] != "cpu"' in HISTORICAL_MFU_GATE  # the fixed form
+    fixed = tmp_path / "bench_fixed.py"
+    fixed.write_text(HISTORICAL_MFU_GATE)
+    assert analyze_paths([str(fixed)], select=["RF002"]).unsuppressed == []
+    prefix = HISTORICAL_MFU_GATE.replace('sc["platform"] != "cpu"', 'sc["platform"] == "tpu"')
     bad = tmp_path / "bench_prefix.py"
     bad.write_text(prefix)
     r = analyze_paths([str(bad)], select=["RF002"])
@@ -180,7 +201,7 @@ def test_rf002_real_prefix_bench_mfu_gate(tmp_path):
 
 
 def test_rf002_current_bench_is_clean():
-    r = analyze_paths([os.path.join(REPO, "bench.py")], select=["RF002"])
+    r = analyze_paths([os.path.join(REPO, "chip_smoke.py")], select=["RF002"])
     assert r.unsuppressed == []
 
 
@@ -503,8 +524,7 @@ def test_rf006_live_tree_is_clean():
     """The violations RF006 found in this repo are fixed or carry a
     justified suppression — and stay that way."""
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "scripts"),
-                       os.path.join(REPO, "bench.py")],
+                       os.path.join(REPO, "scripts")],
                       select=["RF006"])
     assert r.unsuppressed == []
 
@@ -606,7 +626,6 @@ def test_rf008_exempts_the_registry_itself(tmp_path):
 
 def test_rf008_current_tree_is_clean():
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "bench.py"),
                        os.path.join(REPO, "scripts")], select=["RF008"])
     mine = [f for f in r.unsuppressed if f.checker_id == "RF008"]
     assert mine == [], [f"{f.path}:{f.line}" for f in mine]
@@ -664,7 +683,6 @@ def test_rf009_justified_suppression_honored(tmp_path):
 
 def test_rf009_current_tree_is_clean():
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "bench.py"),
                        os.path.join(REPO, "scripts")], select=["RF009"])
     mine = [f for f in r.unsuppressed if f.checker_id == "RF009"]
     assert mine == [], [f"{f.path}:{f.line}" for f in mine]
@@ -765,7 +783,6 @@ def test_rf010_justified_suppression_honored(tmp_path):
 
 def test_rf010_current_tree_is_clean():
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "bench.py"),
                        os.path.join(REPO, "scripts")], select=["RF010"])
     mine = [f for f in r.unsuppressed if f.checker_id == "RF010"]
     assert mine == [], [f"{f.path}:{f.line}" for f in mine]
@@ -791,7 +808,7 @@ def test_select_runs_only_requested_checkers(tmp_path):
 def test_module_name_for_package_files():
     assert module_name_for(
         os.path.join(REPO, "rafiki_tpu/bus/queues.py")) == "rafiki_tpu.bus.queues"
-    assert module_name_for(os.path.join(REPO, "bench.py")) == "bench"
+    assert module_name_for(os.path.join(REPO, "chip_smoke.py")) == "chip_smoke"
 
 
 def test_cli_json_and_exit_codes(tmp_path, capsys):
@@ -1126,7 +1143,7 @@ def test_rf013_current_scheduler_is_clean():
 
 # ---------------------------------------------------------------------------
 # RF014/RF016 — regression fixtures for the live violations this
-# analysis surfaced when first enabled (fixed in bench.py,
+# analysis surfaced when first enabled (fixed in the bench script,
 # scripts/smoke_trial_pack.py, scripts/perf_smoke.py, and closed by the
 # `obs decisions` reader). Each fixture freezes the *fixed* shape as
 # quiet and the pre-fix shape as firing, so the fixes can't regress.
@@ -1145,7 +1162,7 @@ def _tree(tmp_path, files):
 
 
 def test_rf016_bench_trials_regression(tmp_path):
-    # pre-fix bench.py: two reads of RAFIKI_BENCH_TRIALS with mode-
+    # the pre-fix bench script: two reads of RAFIKI_BENCH_TRIALS with mode-
     # specific defaults "3"/"30" → divergent
     r = analyze_paths(_tree(tmp_path, {"bench_old.py": """
         import os
@@ -1303,7 +1320,6 @@ def test_rf017_justified_suppression_honored(tmp_path):
 
 def test_rf017_current_tree_is_clean():
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "bench.py"),
                        os.path.join(REPO, "scripts")], select=["RF017"])
     mine = [f for f in r.unsuppressed if f.checker_id == "RF017"]
     assert mine == [], [f"{f.path}:{f.line}" for f in mine]
@@ -1489,7 +1505,6 @@ def test_rf019_justified_suppression_honored(tmp_path):
 
 def test_rf019_current_tree_is_clean():
     r = analyze_paths([os.path.join(REPO, "rafiki_tpu"),
-                       os.path.join(REPO, "bench.py"),
                        os.path.join(REPO, "scripts")], select=["RF019"])
     mine = [f for f in r.unsuppressed if f.checker_id == "RF019"]
     assert mine == [], [f"{f.path}:{f.line}" for f in mine]

@@ -1,9 +1,16 @@
 """Request-anatomy plane (docs/serving_anatomy.md): hop-mark envelope
 back-compat, segment math and hop-sum reconciliation, the exemplar
 ring's bounds, the serving rollup's determinism, and waterfall
-stitching across processes via the real CLI readers."""
+stitching across processes via the real CLI readers — first over
+hand-written journals, then (the file's last tests) over what REAL
+spawned stub workers on the multiprocess bus wrote under closed-loop
+load: clean, through the stacked route's microbatch, and with an
+injected forward delay that must be both localised and alarmed."""
 
+import contextlib
 import json
+import threading
+import time
 
 import pytest
 
@@ -295,3 +302,236 @@ def test_hop_histograms_flatten_into_prom_exposition(journaled):
     assert 'rafiki_serving_hop_forward_s{quantile="0.99"}' in text
     assert "rafiki_serving_hop_forward_s_count 1" in text
     assert "rafiki_serving_hop_admission_wait_s_count 1" in text
+
+
+# -- live: spawned workers on the mp bus -------------------------------------
+
+
+class _StubModel:
+    """Fixed service time, fixed output — no jax, no compile. Module
+    level so a spawned process can unpickle it."""
+
+    def __init__(self, service_ms):
+        self.service_ms = service_ms
+
+    def predict(self, queries):
+        time.sleep(self.service_ms / 1000.0)
+        return [[0.6, 0.4] for _ in queries]
+
+
+def _stub_worker_process(bus, worker_id, service_ms):
+    """Spawn target: one stub inference worker as its OWN process, the
+    dance run_inference_worker_process does (platform pin first, then
+    the obs plane) minus the model store."""
+    from rafiki_tpu.utils.backend import honor_env_platform
+
+    honor_env_platform()
+    from rafiki_tpu import obs
+
+    obs.configure_from_env(role="infer")
+    from rafiki_tpu.worker.inference import InferenceWorker
+
+    InferenceWorker(bus, "anat", worker_id, _StubModel(service_ms)).run()
+
+
+@contextlib.contextmanager
+def _mp_serving_stack(log_dir, monkeypatch, n_workers, min_replies,
+                      max_batch=1):
+    """The real Gateway + PredictorApp over ``n_workers`` spawned stub
+    workers; every process journals under ``log_dir`` (the spawn env is
+    the propagation channel). Yields ``post(payload, trace_id=None)``."""
+    import multiprocessing as mp
+
+    from werkzeug.test import Client
+
+    from rafiki_tpu.bus import make_mp_bus
+    from rafiki_tpu.gateway import Gateway, GatewayConfig
+    from rafiki_tpu.obs.anatomy import exemplars
+    from rafiki_tpu.predictor import Predictor
+    from rafiki_tpu.predictor.app import PredictorApp
+
+    monkeypatch.setenv("RAFIKI_LOG_DIR", str(log_dir))
+    journal.configure(log_dir, role="gateway")
+    ctx = mp.get_context("spawn")
+    manager = ctx.Manager()
+    bus = make_mp_bus(manager)
+    procs = [ctx.Process(target=_stub_worker_process,
+                         args=(bus, f"aw{i}", 1.0), daemon=True)
+             for i in range(n_workers)]
+    try:
+        for p in procs:
+            p.start()
+        deadline = time.monotonic() + 60
+        while len(bus.get_workers("anat")) < n_workers:
+            assert all(p.is_alive() for p in procs), "a stub worker died"
+            assert time.monotonic() < deadline, "workers never registered"
+            time.sleep(0.01)
+        gateway = Gateway(Predictor(bus, "anat", timeout_s=2.0),
+                          GatewayConfig(max_inflight=4, max_queue=8,
+                                        min_replies=min_replies,
+                                        hedge_grace_s=0.02,
+                                        max_batch=max_batch,
+                                        max_batch_wait_ms=5.0))
+        wsgi = Client(PredictorApp(gateway))
+
+        def post(payload, trace_id=None):
+            headers = {"X-Rafiki-Trace-Id": trace_id} if trace_id else None
+            return wsgi.post("/predict", json=payload,
+                             headers=headers).status_code
+
+        yield post
+        # A short run would otherwise journal nothing: close the
+        # time-series bucket and the exemplar window.
+        gateway.rollup.flush()
+        exemplars.ring.flush()
+    finally:
+        for p in procs:
+            p.terminate()
+        for p in procs:
+            p.join(timeout=5)
+        manager.shutdown()
+        journal.close()
+
+
+def _closed_loop(post, clients, per_client, queries_per_request):
+    """Each client fires its next request only after the last answered."""
+    payload = {"queries": [[1.0]] * queries_per_request, "deadline_s": 2.0}
+    statuses = []
+
+    def client():
+        for _ in range(per_client):
+            statuses.append(post(payload))
+
+    threads = [threading.Thread(target=client, daemon=True)
+               for _ in range(clients)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    return payload, statuses
+
+
+def _pin(post, payload, trace_id):
+    """One traced request AFTER the load: a known id with a full
+    waterfall (retried — the pinned trace is the evidence, not a
+    sample)."""
+    for _ in range(20):
+        if post(payload, trace_id) == 200:
+            return
+        time.sleep(0.05)
+    raise AssertionError("the pinned request never answered 200")
+
+
+def _waterfall(log_dir, trace_id, capsys):
+    from rafiki_tpu.obs import cli
+
+    capsys.readouterr()
+    assert cli.cmd_waterfall(str(log_dir), trace_id, as_json=True) == 0
+    queries = json.loads(capsys.readouterr().out)["queries"]
+    assert queries
+    return {"min_hops": min(q["n_hops"] for q in queries),
+            "pids": {p for q in queries for p in q["pids"]},
+            "max_reconcile_err": max(q["max_reconcile_err"]
+                                     for q in queries),
+            "segments": {s["segment"] for q in queries
+                         for v in q.get("chains", {}).values()
+                         for s in v.get("segments", [])}}
+
+
+def _forward_breaches(log_dir):
+    return [r for r in journal_mod.read_dir(log_dir)
+            if r.get("kind") == "slo" and r.get("name") == "breach"
+            and r.get("slo") == "serving_forward_p99"]
+
+
+def test_live_waterfall_crosses_three_processes_and_reconciles(
+        tmp_path, monkeypatch, capsys):
+    """Replicated route, two spawned workers, quorum 2: the pinned trace
+    reconstructs with >= 4 hops over >= 3 pids, every chain's hop sums
+    reconciling with its span within 10% (``tails --check`` holds the
+    same fleet-wide), the time series journaled rows, and the default
+    1 s forward budget did NOT breach on millisecond forwards — the
+    no-false-positive control of the injected test below."""
+    from rafiki_tpu.obs import cli
+
+    pin = "cafe0bet4p5"
+    with _mp_serving_stack(tmp_path, monkeypatch, n_workers=2,
+                           min_replies=2) as post:
+        payload, statuses = _closed_loop(post, clients=4, per_client=12,
+                                         queries_per_request=4)
+        _pin(post, payload, pin)
+    assert 200 in statuses and not [s for s in statuses
+                                    if s not in (200, 429)]
+    assert "serving.hop.forward_s" in telemetry.snapshot()["histograms"]
+    w = _waterfall(tmp_path, pin, capsys)
+    assert w["min_hops"] >= 4 and len(w["pids"]) >= 3
+    assert w["max_reconcile_err"] <= 0.10
+    assert cli.cmd_tails(str(tmp_path), as_json=True, check=True,
+                         tolerance=0.10) == 0
+    assert cli.cmd_serving(str(tmp_path), 20, as_json=True) == 0
+    assert capsys.readouterr().out.strip()
+    assert _forward_breaches(tmp_path) == []
+
+
+def test_live_waterfall_stitches_across_the_microbatch(
+        tmp_path, monkeypatch, capsys):
+    """Stacked route: ONE spawned worker stands in for the whole top-k
+    ensemble and the gateway microbatches into it. The pinned trace
+    stitches ACROSS the microbatch (member prefix + shared batch leg +
+    worker leg + decide): >= 5 hops, >= 2 pids, a named
+    ``gateway_batch_wait`` segment, reconciling within 10%; and the
+    collapsed route's fan-out cost stays under 15 ms, a fraction of what
+    the replicated mp fan-out pays in wire tax alone."""
+    from rafiki_tpu.obs.anatomy import hops as hops_mod
+
+    pin = "cafe0bet4p5st"
+    with _mp_serving_stack(tmp_path, monkeypatch, n_workers=1,
+                           min_replies=1, max_batch=8) as post:
+        payload, statuses = _closed_loop(post, clients=4, per_client=12,
+                                         queries_per_request=4)
+        _pin(post, payload, pin)
+    assert 200 in statuses and not [s for s in statuses
+                                    if s not in (200, 429)]
+    hists = telemetry.snapshot()["histograms"]
+    assert "serving.hop.gateway_batch_wait_s" in hists
+    assert hists[hops_mod.FANOUT_METRIC]["p50"] < 0.015
+    w = _waterfall(tmp_path, pin, capsys)
+    assert w["min_hops"] >= 5 and len(w["pids"]) >= 2
+    assert "gateway_batch_wait" in w["segments"]
+    assert w["max_reconcile_err"] <= 0.10
+
+
+def test_injected_forward_delay_is_localised_and_alarmed(
+        tmp_path, monkeypatch, capsys):
+    """The chaos plane delays ``inference.forward`` by 250 ms on ~20% of
+    batches in both spawned workers, under a forward-p99 budget tightened
+    to 150 ms ticking every 100 ms: ``obs tails`` must attribute the tail
+    to the ``forward`` hop, and the journals must carry the
+    ``slo/breach`` record. The load is shaped so attribution is crisp:
+    one closed-loop client with one query a request makes every
+    micro-batch a single query, so both replicas' chaos RNG streams
+    (seeded, advanced once per hit) stay aligned and a delayed request
+    delays BOTH replicas — the partner chain never mirrors the delay
+    into its gather_decide wait, and p=0.2 keeps it out of the p50."""
+    from rafiki_tpu.obs import cli
+    from rafiki_tpu.obs.perf import slo
+
+    monkeypatch.setenv("RAFIKI_CHAOS",
+                       "seed=7;inference.forward:delay:delay=0.25:p=0.2")
+    slo.configure([slo.SloSpec(
+        name="serving_forward_p99", source="hist_p99:serving.hop.forward_s",
+        threshold=0.15, windows=(0.4, 1.0))], tick_s=0.1)
+    try:
+        with _mp_serving_stack(tmp_path, monkeypatch, n_workers=2,
+                               min_replies=2) as post:
+            _payload, statuses = _closed_loop(post, clients=1, per_client=80,
+                                              queries_per_request=1)
+    finally:
+        slo.configure_from_env()
+    assert statuses.count(200) >= 60
+    capsys.readouterr()
+    assert cli.cmd_tails(str(tmp_path), as_json=True, check=False,
+                         tolerance=0.10) == 0
+    assert json.loads(capsys.readouterr().out)["dominant"].startswith(
+        "forward")
+    assert _forward_breaches(tmp_path)

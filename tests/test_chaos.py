@@ -357,6 +357,37 @@ def test_predictor_outage_scenario_passes():
         f"{c.name}: {c.detail}" for c in report.checks if not c.ok)
 
 
+@pytest.mark.parametrize("name,fired", [
+    # a subprocess worker SIGKILLs itself at epoch 1; the respawned
+    # worker adopts and resumes from the epoch-1 checkpoint
+    ("kill-mid-trial-resume", None),
+    # gateway drain with injected frontend latency holding inflight
+    # slots: flushes, then sheds as ``draining``
+    ("drain-under-load", "gateway.predict"),
+    # a 2-chip mesh sweep loses chip 1 mid-pack: re-pack onto the
+    # survivor, loss and re-pack journaled, params bit-match serial
+    ("mesh-chip-loss-repack", "scheduler.preempt"),
+    # a width-2 sharded group loses a member mid-epoch: re-forms at
+    # width 1, restore reshards 2 -> 1, params bit-match serial
+    ("chip-loss-mid-sharded-trial", "scheduler.preempt"),
+    # one slow replica breaches the serving-p99 SLO, the controller
+    # scales the inference lane up, the breach clears
+    ("load-spike-scale-up", "inference.forward"),
+    # damped bounded vs undamped thrashing on a fake clock
+    ("autoscale-flap-damping", "autoscale.sensor"),
+])
+def test_recovery_scenario_passes_and_its_fault_fired(name, fired):
+    """The catalog's recovery scenarios, end to end. A scenario that
+    injects through the plane must show the fault in its schedule: a
+    vacuous pass (nothing injected, nothing recovered) fails."""
+    report = run_scenario(name)
+    assert report.passed, "\n".join(
+        f"{c.name}: {c.detail}" for c in report.checks if not c.ok) \
+        + (f"\n{report.error}" if report.error else "")
+    if fired is not None:
+        assert any(s[0] == fired for s in report.schedule), report.schedule
+
+
 def test_kill_mid_pack_resume_acceptance():
     """ISSUE 5 acceptance: k=4 packed run SIGKILLed mid-trial resumes
     every member from its per-epoch slice checkpoint; no lost or

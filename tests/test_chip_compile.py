@@ -95,7 +95,7 @@ def _vgg16():
 
 
 def test_serial_vgg16_epoch_and_eval_compile_for_v5e(one_chip):
-    """The canonical trial (bench.py): VGG16 depth 16 / width 1.0,
+    """The canonical trial (chip_smoke.py): VGG16 depth 16 / width 1.0,
     32x32x3, 50k train / 10k eval device-resident, batch 256."""
     from rafiki_tpu.ops.train import Program, _ShardingPlan
 

@@ -328,8 +328,7 @@ def test_join_prom_golden_classification():
 
 
 def test_manifest_byte_deterministic_across_runs():
-    paths = [os.path.join(REPO, "rafiki_tpu"),
-             os.path.join(REPO, "bench.py"), os.path.join(REPO, "scripts")]
+    paths = [os.path.join(REPO, "rafiki_tpu"), os.path.join(REPO, "scripts")]
     a = dump_manifest(manifest_for_paths(paths, root=REPO))
     b = dump_manifest(manifest_for_paths(paths, root=REPO))
     assert a == b

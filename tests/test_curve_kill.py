@@ -42,8 +42,8 @@ def journaled(tmp_path):
         search_ledger.reset()
 
 
-def _knob_config():
-    return {"lr": FloatKnob(1e-4, 1e-1, is_exp=True),
+def _knob_config(lr_max=1e-1):
+    return {"lr": FloatKnob(1e-4, lr_max, is_exp=True),
             "units": IntegerKnob(4, 64),
             "b": FixedKnob(8)}
 
@@ -229,7 +229,7 @@ def test_serial_worker_kills_doomed_trial(journaled, monkeypatch):
     assert kills[0]["best_so_far"] == 0.9
     assert any(r["name"] == "predict" for r in recs)
     # The scripted handle bypasses record_feedback, so the doomed
-    # bucket isn't charged here (the sweep smoke's A/B pins that);
+    # bucket isn't charged here (the A/B test below pins that);
     # the kill counter rides record_kill and must land regardless.
     assert search_ledger.snapshot()["n_killed"] == 1
 
@@ -378,3 +378,139 @@ def test_rehydration_replays_speculation_byte_identically(journaled):
         journal_records=[r for r in recs if r.get("name") != "speculate"],
         seed=0, engine_kwargs={"n_initial": 2})
     assert _batch(unspeculated) != hydrated[0]
+
+
+# -- the A/B: what killing buys, and an over-aggressive killer caught --------
+#
+# One RandomAdvisor proposal stream trained twice over a synthetic
+# epoch-curve objective with real per-epoch sleeps. Half the knob box is
+# doomed: the curve saturates low and the trial diverges on its final
+# epoch — consolation feedback, doomed bucket — in BOTH polarities, so the
+# scored set (and therefore the final best) is identical by construction
+# and the only difference the ledger can see is wall: kill-off sinks
+# AB_EPOCHS sleeps into every doomed trial, kill-on only min_obs.
+
+AB_TRIALS, AB_EPOCHS, AB_EPOCH_S, AB_SEED = 8, 10, 0.03, 10
+KILL_CFG = {"warmup_epochs": 2, "margin": 0.35, "min_obs": 3}
+DOCTORED_KILL_CFG = {"warmup_epochs": 0, "margin": 0.0, "min_obs": 2}
+
+
+def _curve_profile(knobs):
+    """A trial's destiny from the knob assignment itself — the 'sibling
+    re-run' ground truth is this function again. Finals are bimodal
+    (doomed plateau 0.10-0.18, healthy 0.70-0.90) so a sane margin
+    separates the bands."""
+    from rafiki_tpu.obs.search import audit as search_audit
+
+    h = int(search_audit.knobs_hash(knobs), 16)
+    doomed = (h >> 8) % 2 == 1
+    final = (0.10 + (h % 97) / 97.0 * 0.08) if doomed \
+        else (0.70 + (h % 89) / 89.0 * 0.20)
+    return round(final, 6), doomed, h
+
+
+def _epoch_score(h_int, final, e):
+    """Saturating curve with a deterministic per-trial wiggle: enough
+    noise that a 2-observation fit can be badly wrong (the doctored
+    killer's trap) while a min_obs=3 fit still lands inside the band."""
+    wiggle = 1.0 + 0.06 * math.sin((h_int % 7) + 1.7 * e)
+    return round(final * (1.0 - math.exp(-(e + 1) / 2.0)) * wiggle, 6)
+
+
+def _curved_sweep(log_dir, kill_cfg):
+    """Run the seeded stream once, journaled under ``log_dir``;
+    ``kill_cfg=None`` is the kill-off polarity (no coordinator at all).
+    Returns the run's counters and its reconstructed artifact."""
+    import time
+
+    from rafiki_tpu.advisor.random_advisor import RandomAdvisor
+    from rafiki_tpu.obs.search import audit as search_audit
+    from rafiki_tpu.obs.search import reconstruct
+
+    search_ledger.reset()
+    journal.configure(log_dir, role="sweep")
+    counts = {"killed": 0, "diverged": 0, "scored": 0, "false_kills": 0}
+    killed = []  # (knobs, predicted_final, best_at_kill)
+    try:
+        adv = RandomAdvisor(_knob_config(lr_max=3e-2), seed=AB_SEED)
+        coord = (CurveCoordinator(KillConfig(enabled=True, **kill_cfg))
+                 if kill_cfg else None)
+        for t in range(AB_TRIALS):
+            knobs = adv.propose()
+            final, doomed, h_int = _curve_profile(knobs)
+            was_killed, score = False, 0.0
+            for e in range(AB_EPOCHS):
+                time.sleep(AB_EPOCH_S)
+                score = _epoch_score(h_int, final, e)
+                if coord is None:
+                    continue
+                coord.observe(knobs, e, score, trial_id=f"t{t:02d}",
+                              horizon=AB_EPOCHS)
+                fit = coord.kill_verdict(knobs, e, trial_id=f"t{t:02d}")
+                if fit is not None:
+                    killed.append((knobs, fit.predicted_final,
+                                   coord.best_so_far))
+                    search_audit.note_doomed(knobs)
+                    adv.feedback(0.0, knobs)
+                    was_killed = True
+                    break
+            if was_killed:
+                counts["killed"] += 1
+            elif doomed:
+                # The trial diverges at the end — the consolation path
+                # the workers take, identical in both polarities.
+                search_audit.note_doomed(knobs)
+                adv.feedback(0.0, knobs)
+                if coord is not None:
+                    coord.note_done(knobs)
+                counts["diverged"] += 1
+            else:
+                adv.feedback(score, knobs)
+                if coord is not None:
+                    coord.note_scored(knobs, score)
+                counts["scored"] += 1
+        # Hindsight pass: every killed trial's knobs re-run to completion
+        # (the analytic profile IS the sibling); a false-kill verdict is
+        # journaled when the sibling beats best-so-far.
+        for knobs, predicted, best_at in killed:
+            sibling, _, h_int = _curve_profile(knobs)
+            sibling_score = _epoch_score(h_int, sibling, AB_EPOCHS - 1)
+            if best_at is not None and sibling_score > best_at:
+                search_audit.record_false_kill(
+                    knobs, killed_predicted=predicted,
+                    sibling_score=sibling_score, best_so_far=best_at)
+                counts["false_kills"] += 1
+    finally:
+        journal.close()
+        search_ledger.reset()
+    return counts, reconstruct.artifact(
+        reconstruct.reconstruct(read_dir(log_dir)))
+
+
+def test_kill_on_buys_effective_trials_per_hour_at_an_equal_best(tmp_path):
+    """Kill-on condemns the doomed trials off the curve fit after
+    ``min_obs`` epochs: >= 1.3x kill-off's effective trials/hour at a
+    byte-equal final best, with zero false kills — each killed trial's
+    sibling, re-run to completion, stays below best-so-far."""
+    c_off, art_off = _curved_sweep(tmp_path / "off", None)
+    c_on, art_on = _curved_sweep(tmp_path / "on", KILL_CFG)
+    assert c_on["false_kills"] == 0 and c_on["killed"] >= 2
+    assert c_on["scored"] == c_off["scored"] >= 3
+    assert art_on["best_score"] is not None
+    assert art_on["best_score"] == art_off["best_score"]
+    assert (art_on["effective_trials_per_hour"]
+            >= 1.3 * art_off["effective_trials_per_hour"])
+    assert art_on["n_kills"] == c_on["killed"]
+    assert art_on["n_false_kills"] == 0 and art_on["kill_precision"] == 1.0
+    assert (art_off.get("n_kills") or 0) == 0
+
+
+def test_an_over_aggressive_killer_is_caught_by_hindsight(tmp_path):
+    """The same stream under margin=0, warmup=0, min_obs=2: at least one
+    hindsight false kill journaled and kill_precision < 1 in the
+    reconstruction. A killer the false-kill pass cannot catch would let
+    a 'faster' sweep quietly discard its best trials."""
+    counts, art = _curved_sweep(tmp_path / "doctored", DOCTORED_KILL_CFG)
+    assert counts["false_kills"] >= 1
+    assert art["n_false_kills"] == counts["false_kills"]
+    assert art["kill_precision"] < 1.0

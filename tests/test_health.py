@@ -17,6 +17,7 @@ The contract under test:
     in-proc API and the real ``obs replay`` CLI alike.
 """
 
+import json
 import math
 import time
 
@@ -410,6 +411,31 @@ def test_worker_serial_contains_divergence(tmp_path, journaled):
     # The worker loop SURVIVED the divergence: trial 2 completed.
     good = next(t for t in trials if t["status"] == "COMPLETED")
     assert good["score"] is not None
+
+
+def test_quiet_worker_round_gets_a_clean_bill(tmp_path, journaled, capsys):
+    """The uninjected polarity, end to end: the sentinels are ON (they
+    always are) but stay silent through a 2-trial serial worker round —
+    no divergence, no capsule — ``obs health`` renders a clean bill and
+    ``obs curves`` surfaces both trials' learning curves from the same
+    journals."""
+    from rafiki_tpu.obs import cli
+
+    store, worker, adv, sub = _mk_worker(tmp_path, n_trials=2)
+    assert worker.run() == 2
+    journal.close()
+    trials = store.get_trials_of_sub_train_job(sub["id"])
+    assert [t["status"] for t in trials] == ["COMPLETED", "COMPLETED"]
+    stats = health.stats()
+    assert stats["divergences"] == 0 and stats["capsules"] == 0
+    assert not list(journaled.glob("capsule-*.rcap"))
+
+    assert cli.main(["--dir", str(journaled), "--json", "health"]) == 0
+    report = json.loads(capsys.readouterr().out)
+    assert not report["divergences"] and not report["capsule_errors"]
+    assert cli.main(["--dir", str(journaled), "--json", "curves"]) == 0
+    curves = json.loads(capsys.readouterr().out)["trials"]
+    assert len(curves) == 2 and all(len(v) >= 2 for v in curves.values())
 
 
 def test_worker_packed_contains_divergence(tmp_path, journaled):

@@ -10,7 +10,6 @@ from rafiki_tpu.analysis import analyze_paths, load_builtin_checkers
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LINT_PATHS = [os.path.join(REPO, "rafiki_tpu"),
-              os.path.join(REPO, "bench.py"),
               os.path.join(REPO, "scripts")]
 
 load_builtin_checkers()

@@ -3,13 +3,15 @@ trace propagation through bus envelopes, the bounded on-disk journal
 ring, the goodput ledger, the flight recorder, and the Prometheus
 exposition (golden-file pinned).
 
-Cross-PROCESS stitching is exercised by scripts/obs_smoke.py (real
-spawned workers) and the chaos runner's journal-reconstruction checks;
-these tests pin the in-process mechanics those builds sit on.
+Most tests pin the in-process mechanics; the last one stitches one
+pinned trace across three real processes (a gateway and two spawned
+inference workers) and line-parses the live ``/metrics?format=prom``.
 """
 
 import json
 import os
+import re
+import time
 from pathlib import Path
 
 import pytest
@@ -368,3 +370,125 @@ def test_prometheus_exposition_is_deterministic_and_parseable():
         assert "rafiki_obs_test_counter 2" in text
     finally:
         telemetry.reset()
+
+
+# -- one trace across three processes ---------------------------------------
+
+# Prometheus text exposition: comments, or `name[{labels}] value`.
+_PROM_COMMENT = re.compile(r"^# (TYPE|HELP) ")
+_PROM_SAMPLE = re.compile(
+    r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? -?[0-9.e+-]+(\s+[0-9]+)?$')
+
+
+def _wait_until(cond, timeout_s, what, every_s=0.05):
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(every_s)
+
+
+def test_pinned_trace_stitches_across_three_processes(tmp_path, monkeypatch,
+                                                      capsys):
+    """Train one tiny trial, serve it from TWO spawned inference worker
+    processes over the mp bus (one journal file a process under a
+    shared RAFIKI_LOG_DIR), send one query with a pinned
+    ``X-Rafiki-Trace-Id`` through the gateway's WSGI app: the reader
+    (``obs trace <id>``) must stitch records of >= 3 distinct processes
+    with a bus hop among them, and ``/metrics?format=prom`` must
+    line-parse."""
+    import multiprocessing as mp
+    import uuid
+
+    import numpy as np
+    from werkzeug.test import Client
+
+    from rafiki_tpu.bus import make_mp_bus
+    from rafiki_tpu.chaos.scenarios import VAL, _make_job, _train_env
+    from rafiki_tpu.gateway import Gateway, GatewayConfig
+    from rafiki_tpu.model.dataset import dataset_utils
+    from rafiki_tpu.obs import cli
+    from rafiki_tpu.predictor import Predictor
+    from rafiki_tpu.predictor.app import PredictorApp
+    from rafiki_tpu.scheduler import LocalScheduler
+    from rafiki_tpu.worker.inference import run_inference_worker_process
+
+    log_dir = tmp_path / "obs"
+    # The spawn env is the propagation channel: children inherit
+    # RAFIKI_LOG_DIR and open their own journal files under it.
+    monkeypatch.setenv("RAFIKI_LOG_DIR", str(log_dir))
+    journal.configure(log_dir, role="gateway")
+    store, params, model = _train_env(tmp_path)
+    job = _make_job(store, model, {"MODEL_TRIAL_COUNT": 1})
+    best = LocalScheduler(store, params).run_train_job(
+        job["id"], n_workers=1, advisor_kind="random").best_trials[0]
+    meta, blobs = str(tmp_path / "meta.sqlite3"), str(tmp_path / "params")
+
+    ctx = mp.get_context("spawn")
+    manager = ctx.Manager()
+    bus = make_mp_bus(manager)
+    procs = [ctx.Process(target=run_inference_worker_process,
+                         args=(bus, meta, blobs, best["id"], "obs-job",
+                               f"ow-{i}"), daemon=True) for i in range(2)]
+    try:
+        for p in procs:
+            p.start()
+
+        def registered():
+            assert all(p.is_alive() for p in procs), "a worker died"
+            return len(bus.get_workers("obs-job")) == len(procs)
+
+        _wait_until(registered, 120, "both workers to register")
+        predictor = Predictor(bus, "obs-job", timeout_s=10.0, worker_ttl_s=3.0)
+        wsgi = Client(PredictorApp(Gateway(predictor,
+                                           GatewayConfig(min_replies=2))))
+        payload = {"queries": np.asarray(
+            dataset_utils.load(VAL).x[:1], np.float32).tolist()}
+
+        def warm():
+            r = wsgi.post("/predict", json=payload)
+            preds = (r.get_json() or {}).get("predictions") or []
+            return r.status_code == 200 and preds and not any(
+                isinstance(p, dict) and "error" in p for p in preds)
+
+        _wait_until(warm, 120, "both workers to answer", every_s=0.5)
+
+        # THE traced query: pin the id, like a caller would.
+        tid = uuid.uuid4().hex
+        r = wsgi.post("/predict", json=payload,
+                      headers={"X-Rafiki-Trace-Id": tid})
+        assert r.status_code == 200
+        assert r.get_json()["trace_id"] == tid
+        capsys.readouterr()
+
+        records = []
+
+        def stitched():
+            # Worker journal writes are line-buffered, but the
+            # pop -> journal hop may trail the reply by a beat.
+            assert cli.main(["--dir", str(log_dir), "--json", "trace",
+                             tid]) == 0
+            records[:] = [json.loads(line) for line in
+                          capsys.readouterr().out.splitlines() if line]
+            return len({(rec.get("role"), rec.get("pid"))
+                        for rec in records}) >= 3
+
+        _wait_until(stitched, 20, "three processes in the trace",
+                    every_s=0.25)
+        assert {rec["trace_id"] for rec in records} == {tid}
+        assert any(rec.get("kind") == "bus" for rec in records)
+
+        pr = wsgi.get("/metrics?format=prom")
+        assert pr.status_code == 200
+        lines = pr.get_data(as_text=True).splitlines()
+        bad = [ln for ln in lines if ln and not _PROM_COMMENT.match(ln)
+               and not _PROM_SAMPLE.match(ln)]
+        assert bad == []
+        assert any(ln.startswith("rafiki_predictor_queries") for ln in lines)
+    finally:
+        for p in procs:
+            if p.is_alive():
+                p.kill()
+        for p in procs:
+            p.join(timeout=10)
+        manager.shutdown()
+        journal.close()

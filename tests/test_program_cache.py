@@ -172,8 +172,8 @@ def test_dynamic_lr_matches_baked_adam():
     baked = TrainLoop(init_fn, apply_fn, loss_fn, optax.adam(lr), seed=0)
     dev = dyn.plan.put_batch(batch)
     for _ in range(5):
-        dyn.state, _ = dyn._train_step(dyn.state, dev)
-        baked.state, _ = baked._train_step(baked.state, dev)
+        dyn.state, _ = dyn.program.train_step(dyn.state, dev)
+        baked.state, _ = baked.program.train_step(baked.state, dev)
     np.testing.assert_allclose(np.asarray(dyn.params["w"]),
                                np.asarray(baked.params["w"]), rtol=1e-5, atol=1e-6)
 

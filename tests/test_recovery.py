@@ -512,3 +512,58 @@ def test_host_loss_mid_sweep_acceptance():
         + (f"\n{report.error}" if report.error else "")
     names = {c.name for c in report.checks}
     assert {"survivors_repacked", "wal_reconciles_clean"} <= names
+
+
+def test_sigkilled_sweep_resumes_in_a_fresh_process(tmp_path, capsys):
+    """The crash-recovery chain across REAL process boundaries: a 4-trial
+    random sweep run through ``scheduler/sweep_proc.py`` with a
+    ``supervisor.tick:kill`` fault installed dies by SIGKILL after its
+    warm-up claims; a second ``sweep_proc resume`` process adopts the
+    job from the WAL, reconciles it with zero duplicate claims, drives
+    it to COMPLETED with exactly budget-many trial rows, and ``obs
+    resume`` reconstructs the timeline from the journals alone. (The
+    slow acceptance scenario above also runs the unfaulted twin and
+    compares best scores; this is its tier-1 core.)"""
+    from rafiki_tpu.chaos.scenarios import (_make_job, _sweep_proc,
+                                            _sweep_proc_env, _train_env)
+    from rafiki_tpu.obs import cli
+    from rafiki_tpu.scheduler.wal import read_wal, reconcile, wal_path
+
+    budget, chips, per_chip = 4, 2, 2
+    log_dir = tmp_path / "obs"
+
+    def child_env(chaos):
+        env = _sweep_proc_env(chaos=False)  # never inherit a caller's spec
+        env.update(RAFIKI_LOG_DIR=str(log_dir),
+                   RAFIKI_SUPERVISOR_HEARTBEAT_S="0.2",
+                   RAFIKI_CHECKPOINT_EVERY="1")
+        if chaos:
+            env["RAFIKI_CHAOS"] = \
+                "seed=23;supervisor.tick:kill:after=30:times=1:match=g0"
+        return env
+
+    store, params, model = _train_env(tmp_path)
+    job = _make_job(store, model, {"MODEL_TRIAL_COUNT": budget})
+    killed, _ = _sweep_proc("run", store, params, job["id"], chips=chips,
+                            trials_per_chip=per_chip, advisor="random",
+                            env=child_env(chaos=True))
+    assert killed.returncode == -9, killed.stderr[-300:]
+    resumed, summary = _sweep_proc("resume", store, params, job["id"],
+                                   chips=chips, trials_per_chip=per_chip,
+                                   stale_after_s=0.4,
+                                   env=child_env(chaos=False))
+    assert resumed.returncode == 0, resumed.stderr[-300:]
+    assert summary["mode"] == "wal" and summary["adopted"] > 0
+    assert summary["status"] == "COMPLETED"
+
+    trials = store.get_trials_of_train_job(job["id"])
+    assert len(trials) == budget
+    assert all(t["status"] == "COMPLETED" for t in trials)
+    rec = reconcile(read_wal(wal_path(store.path, job["id"])), trials)
+    assert rec.ok, rec.errors
+    assert [e for r in summary.get("reconcile", [])
+            for e in r.get("errors", [])
+            if e["type"] == "duplicate_claim"] == []
+    capsys.readouterr()
+    assert cli.main(["--dir", str(log_dir), "resume", job["id"]]) == 0
+    assert "resumed:" in capsys.readouterr().out

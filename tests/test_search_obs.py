@@ -3,11 +3,7 @@ decision leaves an audit record, sweeps reconstruct from journals
 alone, trial lineage survives evict/backfill/repack/resume, and the
 SWEEP_r* trend gates both ways."""
 
-import json
 import math
-import os
-import subprocess
-import sys
 
 import pytest
 
@@ -16,7 +12,6 @@ from rafiki_tpu.obs.journal import journal, read_dir
 from rafiki_tpu.obs.search import audit, lineage, reconstruct, stats
 from rafiki_tpu.obs.search.ledger import search_ledger
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 KC = {"lr": FloatKnob(1e-4, 3e-2, is_exp=True),
       "units": IntegerKnob(4, 64),
@@ -391,48 +386,3 @@ def test_lineage_reconcile_flags_orphans():
     orphans = lineage.reconcile(trials)
     assert [o["trial_id"] for o in orphans] == ["t2"]
     assert trials["t2"]["status"] == "orphaned"
-
-
-# ---------------------------------------------------------------------------
-# bench_report --sweep end to end (subprocess, both polarities)
-# ---------------------------------------------------------------------------
-
-
-def _report(args, cwd):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "bench_report.py"),
-         "--sweep", *args],
-        capture_output=True, text=True, cwd=cwd, timeout=60)
-
-
-def test_bench_report_sweep_gates_both_ways(journaled, tmp_path):
-    recs = _two_engine_records(journaled)
-    art = reconstruct.artifact(reconstruct.reconstruct(recs))
-
-    def _round(n, doc):
-        p = tmp_path / f"SWEEP_r{n:02d}.json"
-        p.write_text(json.dumps(doc))
-        return str(p)
-
-    ok_rounds = [
-        _round(1, dict(art, effective_trials_per_hour=400.0, regret=0.08)),
-        _round(2, {"sweep_schema_version": 1,
-                   "error": "sweep reconciliation failed"}),
-        _round(3, dict(art, effective_trials_per_hour=420.0, regret=0.06)),
-    ]
-    ok = _report(ok_rounds, tmp_path)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    doc = json.loads(ok.stdout)
-    assert doc["mode"] == "sweep" and doc["verdict"] == "ok"
-    r02 = [r for r in doc["rounds"] if str(r["round"]).endswith("r02.json")]
-    assert not r02[0]["has_data"], "an error round must be no-data"
-    # negative advisor_lift is a measurement, not a dead backend
-    assert doc["metrics"]["advisor_lift"]["n_measured"] == 2
-
-    bad = _report(ok_rounds + [
-        _round(4, dict(art, effective_trials_per_hour=150.0, regret=0.4))],
-        tmp_path)
-    assert bad.returncode == 1
-    regressed = json.loads(bad.stdout)["regressed"]
-    assert "effective_trials_per_hour" in regressed
-    assert "regret" in regressed

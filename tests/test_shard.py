@@ -177,6 +177,44 @@ def test_reshard_roundtrip_bitmatches(lane, from_w, to_w):
     assert _bitmatch(restored, src.state)
 
 
+def test_obs_shard_reads_a_freshly_journaled_lane_run(tmp_path, capsys):
+    """The forensic reader against records the lane itself just wrote: a
+    journaled plan / train / save / reshard-on-restore sequence, then
+    ``obs shard`` over the directory (exit 0, the plan and the reshard
+    among its rows); an empty directory is exit 1, not a silent pass."""
+    from rafiki_tpu.obs.cli import cmd_shard
+    from rafiki_tpu.obs.journal import journal
+
+    init_fn, apply_fn, loss_fn = _loop_fns()
+    ds, devs = _DS(), jax.devices()
+    log_dir = tmp_path / "obs"
+    journal.configure(log_dir, role="test")
+    try:
+        loops = {}
+        for w in (2, 4):
+            plan = ShardPlan(width=w, family="mlp")
+            plan.note()
+            loops[w] = ShardedTrainLoop(
+                init_fn, apply_fn, loss_fn, devices=devs[:w], seed=SEED,
+                plan=plan, program_key=("test_shard", "mlp"))
+            loops[w].run_epoch(ds, BATCH, epoch_seed=SEED)
+        store = ParamsStore(str(tmp_path / "params"))
+        save_sharded(store, "a", 0, loops[2].state, 2)
+        _epoch, blob = store.latest_checkpoint("a")
+        restored = restore_sharded(store, blob, loops[4].state,
+                                   loops[4].mesh, loops[4].plan)
+        assert _bitmatch(restored, loops[2].state)
+    finally:
+        journal.close()
+    assert cmd_shard(str(log_dir), as_json=True) == 0
+    rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.strip()]
+    assert {"plan", "reshard"} <= {r.get("name") for r in rows}
+    empty = tmp_path / "empty"
+    empty.mkdir()
+    assert cmd_shard(str(empty), as_json=True) == 1
+
+
 def test_missing_chunk_fails_naming_the_chunk(lane):
     src, _ = lane["loops"][2]
     with tempfile.TemporaryDirectory() as d:

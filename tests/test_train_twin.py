@@ -372,3 +372,63 @@ def test_mesh_sweep_consults_twin_at_admission(tmp_path, monkeypatch):
                and r.get("name") == "step" and r.get("packing_key")
                and r.get("program_kind") == "packed"]
     assert stamped
+
+
+def test_train_twin_validates_against_a_captured_mesh_sweep(tmp_path,
+                                                            monkeypatch,
+                                                            capsys):
+    """Capture -> calibrate -> validate (both polarities) -> what-if, on
+    the journals of a REAL mini mesh sweep (2 virtual chips x k=2 packed
+    trials, one ``propose_batch(4)`` draft) rather than synthetic ones:
+    replaying the captured packs lands predicted-vs-measured trials/hour
+    and wall inside tolerance (exit 0); with both epoch segments doubled
+    the same gate FAILS (exit 1) — the mini sweep's epochs are
+    compile-dominated at this size, so the doctored polarity scales
+    both; the pure 2x step-time polarity is pinned above on synthetic
+    journals where the step cost dominates. The what-if sweep over the
+    captured calibration is byte-identical under one seed and every row
+    carries its event-log fingerprint."""
+    from rafiki_tpu.chaos.scenarios import FF_SOURCE, TRAIN, VAL
+    from rafiki_tpu.obs import cli
+    from rafiki_tpu.scheduler import MeshSweepScheduler
+    from rafiki_tpu.store import MetaStore, ParamsStore
+
+    log_dir = tmp_path / "obs"
+    monkeypatch.setenv("RAFIKI_LOG_DIR", str(log_dir))
+    journal.configure(log_dir, role="sweep")
+    try:
+        store = MetaStore(tmp_path / "meta.sqlite3")
+        params = ParamsStore(tmp_path / "params")
+        model = store.create_model("twinff", "IMAGE_CLASSIFICATION", None,
+                                   FF_SOURCE, "ChaosFF")
+        job = store.create_train_job("traintwin", "IMAGE_CLASSIFICATION",
+                                     None, TRAIN, VAL,
+                                     {"MODEL_TRIAL_COUNT": 4})
+        store.create_sub_train_job(job["id"], model["id"])
+        result = MeshSweepScheduler(store, params).run_sweep(
+            job["id"], chips=2, trials_per_chip=2, advisor_kind="random")
+    finally:
+        journal.close()
+    assert result.status == "COMPLETED", result.errors
+
+    cal = TrainCalibration.from_journal_dir(log_dir)
+    assert len(cal.to_dict()["packs"]) >= 2
+
+    twin = ["--dir", str(log_dir), "--json", "twin", "train"]
+    capsys.readouterr()
+    assert cli.main(twin + ["validate", "--seed", "7"]) == 0
+    assert json.loads(capsys.readouterr().out)["ok"] is True
+    assert cli.main(twin + ["validate", "--seed", "7", "--scale", "step=2.0",
+                            "--scale", "compile=2.0"]) == 1
+    assert json.loads(capsys.readouterr().out)["ok"] is False
+
+    sweep = twin + ["sweep", "--seed", "7", "--grid", "chips=1,2",
+                    "--grid", "pack=1,2", "--best-k", "--split"]
+    assert cli.main(sweep) == 0
+    first = capsys.readouterr().out
+    assert cli.main(sweep) == 0
+    assert capsys.readouterr().out == first
+    doc = json.loads(first)
+    assert len(doc["rows"]) == 4
+    assert all(r["event_log_sha1"] for r in doc["rows"])
+    assert len(doc["best_k"]) >= 1 and doc["split"]["best"] is not None

@@ -259,6 +259,7 @@ def test_worker_packed_run_keeps_per_trial_contract(tmp_path):
     assert 0.0 <= m.evaluate(VAL) <= 1.0
     assert _counter("worker.packed_rounds") >= rounds0 + 2
     assert _counter("worker.packed_trials") >= 8
+    assert "trial_pack.size" in telemetry.snapshot()["histograms"]
 
 
 def test_worker_pack1_default_is_serial(tmp_path):

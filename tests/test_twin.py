@@ -24,6 +24,8 @@ from __future__ import annotations
 import dataclasses
 import json
 import random
+import threading
+import time
 
 import pytest
 
@@ -373,3 +375,124 @@ def test_queries_per_request_rides_arrival_tuples():
     cfg = TwinConfig.from_calibration(cal)
     res = simulate(cal, cfg, [(0.0, 3), (0.1, 1)], seed=0)
     assert res["requests"] == 2 and res["ok"] == 2
+
+
+# -- a live capture, end to end ---------------------------------------------
+
+
+class _SlowStub:
+    """A 20 ms forward: it dominates the ~ms wiring overheads, so the
+    mis-calibrated polarity below is a ~50% latency error, not noise."""
+
+    def predict(self, queries):
+        time.sleep(0.020)
+        return [[0.6, 0.4] for _ in queries]
+
+
+def _capture_live_run(log_dir, workers=2, clients=4, per_client=25):
+    """Closed-loop load (a client fires its next request only after the
+    last one answered) against the real Gateway + PredictorApp stack over
+    stub workers on the in-proc bus, journaled under ``log_dir``."""
+    from werkzeug.test import Client
+
+    from rafiki_tpu.bus import InProcBus
+    from rafiki_tpu.gateway import Gateway, GatewayConfig
+    from rafiki_tpu.obs.anatomy import exemplars
+    from rafiki_tpu.obs.journal import journal
+    from rafiki_tpu.predictor import Predictor
+    from rafiki_tpu.predictor.app import PredictorApp
+    from rafiki_tpu.worker.inference import InferenceWorker
+
+    journal.configure(log_dir, role="gateway")
+    bus, stop = InProcBus(), threading.Event()
+    threads = [threading.Thread(
+        target=InferenceWorker(bus, "twin", f"tw{i}", _SlowStub(),
+                               stop_event=stop).run, daemon=True)
+               for i in range(workers)]
+    for th in threads:
+        th.start()
+    try:
+        deadline = time.monotonic() + 10
+        while len(bus.get_workers("twin")) < workers:
+            assert time.monotonic() < deadline, "workers never registered"
+            time.sleep(0.005)
+        gateway = Gateway(Predictor(bus, "twin", timeout_s=2.0),
+                          GatewayConfig(max_inflight=4, max_queue=8,
+                                        hedge_grace_s=0.02))
+        wsgi = Client(PredictorApp(gateway))
+        payload = {"queries": [[1.0]] * 4, "deadline_s": 2.0}
+        statuses = []
+
+        def client():
+            for _ in range(per_client):
+                statuses.append(wsgi.post("/predict", json=payload).status_code)
+
+        loops = [threading.Thread(target=client, daemon=True)
+                 for _ in range(clients)]
+        for th in loops:
+            th.start()
+        for th in loops:
+            th.join()
+        # A short run would otherwise journal nothing: close the
+        # time-series bucket and the exemplar window.
+        gateway.rollup.flush()
+        exemplars.ring.flush()
+        return statuses
+    finally:
+        stop.set()
+        for th in threads:
+            th.join(timeout=2)
+        journal.close()
+
+
+def test_twin_calibrates_and_validates_against_a_live_capture(
+        tmp_path, monkeypatch, capsys):
+    """Capture -> calibrate -> validate (both polarities) -> sweep, on
+    journals a live run wrote rather than synthetic ones: the bundle
+    comes from the capture; replaying the captured arrivals lands
+    predicted-vs-measured p50/p99 inside tolerance (exit 0) and FAILS
+    with the forward time halved (exit 1) — a twin that cannot detect a
+    halved forward validates nothing; the what-if sweep over the live
+    calibration is byte-identical under one seed, names each row's first
+    saturating resource, and its ``--suggest-slo`` set round-trips
+    through the live burn-rate engine's own parser."""
+    from rafiki_tpu import telemetry
+    from rafiki_tpu.obs import cli
+    from rafiki_tpu.obs.perf.slo import _specs_from_env
+
+    monkeypatch.delenv("RAFIKI_SLO", raising=False)
+    log_dir = str(tmp_path / "obs")
+    telemetry.reset()
+    try:
+        statuses = _capture_live_run(log_dir)
+    finally:
+        telemetry.reset()
+    assert statuses.count(200) >= 50 and 500 not in statuses
+
+    cal = Calibration.from_journal_dir(log_dir)
+    assert Calibration.from_dict(json.loads(json.dumps(cal.to_dict()))) \
+        .segments.keys() == cal.segments.keys()
+
+    twin = ["--dir", log_dir, "--json", "twin"]
+    assert cli.main(twin + ["validate", "--seed", "7"]) == 0
+    good = json.loads(capsys.readouterr().out)
+    assert good["ok"] is True and good["p50_err"] <= good["tolerance"]
+    assert cli.main(twin + ["validate", "--seed", "7",
+                            "--scale", "forward=0.5"]) == 1
+    assert json.loads(capsys.readouterr().out)["ok"] is False
+
+    sweep = twin + ["sweep", "--seed", "7", "--qps", "60", "--duration", "4",
+                    "--grid", "workers=1,2,4", "--fleet", "--suggest-slo"]
+    assert cli.main(sweep) == 0
+    first = capsys.readouterr().out
+    assert cli.main(sweep) == 0
+    assert capsys.readouterr().out == first
+    doc = json.loads(first)
+    assert len(doc["rows"]) == 3
+    assert all(r["first_saturating"] for r in doc["rows"])
+    assert doc["fleet"]["workers"] is not None
+    specs = doc["suggested_slo"]
+    assert len(specs) == 2
+    monkeypatch.setenv("RAFIKI_SLO", json.dumps(specs))
+    assert [(sp.name, sp.threshold) for sp in _specs_from_env()] == [
+        (d["name"], d["threshold"]) for d in specs]
