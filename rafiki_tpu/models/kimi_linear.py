@@ -14,9 +14,19 @@ What the template adds to the zoo, by mechanism:
 * ``kda_chunked``: the delta rule in its chunked (WY / UT-transform)
   form. Within a chunk the rank-one updates are folded into one unit
   lower-triangular system, inverted by block substitution in log depth
-  (matrix products only); across chunks a ``lax.scan`` carries the
-  ``[d_k, d_v]`` state. (The recurrence token by token is the benchmark's
-  reference, ``benchmark/references/kimi_linear.py``; tests compare the two.)
+  (matrix products only); across chunks the ``[d_k, d_v]`` state is
+  carried. Lowered for a TPU, at chunk 64, heads 128 wide and a length
+  two chunks divide: one Pallas kernel a pass (``kda_chunk_fwd``, and
+  ``kda_chunk_bwd`` for the five gradients), a head's chunks one after
+  the other, two a grid step, with the state in VMEM, so that only q, k,
+  v, a, beta, the result and the state each chunk started from touch HBM
+  (two chunks a step because the float32 solve is bound by MXU passes,
+  and two chunks' [64, 64] tiles side by side fill one). Everywhere
+  else (the CPU, chunk 16, other widths, a ragged length): plain
+  ``jax.numpy``, a ``lax.scan`` over groups of chunks. ``count.kda.fused``
+  of ``count.kda.layers`` says which ran. (The recurrence token by token
+  is the benchmark's reference, ``benchmark/references/kimi_linear.py``;
+  tests compare all three.)
 * ``mla_attention``: causal softmax attention over latent-projected
   keys and values (queries and keys 192 wide, values 128). Lowered for a
   TPU, at a length ``KERNEL_BLOCK`` divides: one fused kernel a pass (the
@@ -38,13 +48,17 @@ What the template adds to the zoo, by mechanism:
 TPU notes: matrix products take bfloat16 operands and accumulate in
 float32; parameters, the recurrent state, decays, normalisations, the
 router and the softmaxes stay float32 (in the attention kernel: the
-running maximum, sum and accumulator). Which attention runs is decided
-when a program is lowered, by the platform it is lowered for
+running maximum, sum and accumulator; in the chunk kernels: the running
+sum of the log-decay, its factors, the triangular solve at full precision
+and the state). Which attention and which chunk rule run is decided when a
+program is lowered, by the platform it is lowered for
 (``lax.platform_dependent``), so a compile on a CPU host for a described
-chip holds the kernel; no environment variable or knob enters. The
-blocked code on a TPU writes each block's float32 scores to HBM three
-times a pass (2.2 s of a 3.9 s step at 2 x 8,192 tokens against 0.07 s
-in the kernel's four calls: PERF.md, PR 28). Sequences are fixed length, one
+chip holds the kernels; no environment variable or knob enters. The scan
+on a TPU writes fifteen times its inputs to HBM as intermediates, in some
+sixty small programs a group (0.93 s of a 1.9 s step at 2 x 8,192 tokens:
+PERF.md, PR 30). The blocked attention on a TPU writes each block's float32
+scores to HBM three times a pass (2.2 s of a 3.9 s step at 2 x 8,192 tokens
+against 0.07 s in the kernel's four calls: PERF.md, PR 28). Sequences are fixed length, one
 document a sequence (`synthetic://tokens`). A trial of this template
 fills a chip by itself, so it is not packable; it runs in the serial
 lane.
@@ -59,6 +73,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as splash, splash_attention_mask as splash_mask)
 
@@ -72,6 +88,8 @@ LOSS_BLOCK = 1024    # tokens of a sequence whose logits exist at one time
 ATTN_BLOCK = 256     # queries whose scores exist at one time (the blocked path)
 KERNEL_BLOCK = 1024  # queries and keys of one tile of the fused attention kernel
 KDA_GROUP = 16       # chunks whose insides exist at one time
+KDA_KERNEL_CHUNK = 64    # the chunk and the head width (keys and values alike)
+KDA_KERNEL_WIDTH = 128   # that the fused chunk kernel is written for
 BF16 = jnp.bfloat16
 
 # Named scopes of the block (docs/telemetry.md), beside ops/train.py's
@@ -126,49 +144,42 @@ def unit_lower_inverse(A):
     return inv.reshape(lead + (C, C))
 
 
-def kda_chunked(q, k, v, a, beta, chunk: int = 64):
-    """S_t = (I - b_t k_t k_t^T) Diag(exp a_t) S_(t-1) + b_t k_t v_t^T,
-    o_t = S_t^T q_t (``q``, ``k``, ``a``: [B, T, H, dk]; ``v``: [B, T, H, dv];
-    ``beta``: [B, T, H]) in chunks of ``chunk`` tokens (a power of two). Within a chunk, with g the running sum of ``a``: the updates
-    u_t = b_t (v_t - S_(t-1)^T Diag(alpha_t) k_t) solve (I + A) U = b V -
-    (b K e^g) S_0, where A_ts = b_t sum_d k_td k_sd e^(g_td - g_sd) for
-    s < t; then o = (Q e^g) S_0 + tril((Q e^g)(K e^-g)^T) U and
-    S_C = e^(g_C) S_0 + (K e^(g_C - g))^T U. The factors e^g and e^-g are
-    taken about the chunk's middle token and clipped at e^80, which is
-    exact while half a chunk's summed log-decay stays above -80 (2.5 a
-    token at chunk 64; the initial range ends at 1.6). A ragged tail is
-    padded with tokens that leave the state as it is (b = 0, a = 0)."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    C = int(chunk)
-    pad = (-T) % C
-    if pad:
-        q, k, v, a = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for x in (q, k, v, a))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    n = (T + pad) // C
+def _kda_group_size(n: int) -> int:
+    return next(x for x in range(min(KDA_GROUP, n), 0, -1) if n % x == 0)
 
-    def chunks(x):  # [B, T, H, d] -> [n, B, H, C, d]
-        return jnp.transpose(x.reshape((B, n, C, H) + x.shape[3:]),
-                             (1, 0, 3, 2) + tuple(range(4, x.ndim + 1)))
 
-    q, k, v, a = (chunks(x) for x in (q, k, v, a))
-    beta = chunks(beta[..., None])                         # [n, B, H, C, 1]
+def _kda_grouped(x, C: int):
+    """[B, T, H, d] (or [B, T, H]) -> [n / G, G, B, H, C, d] (d = 1)."""
+    if x.ndim == 3:
+        x = x[..., None]
+    B, T, H, d = x.shape
+    n = T // C
+    x = jnp.transpose(x.reshape(B, n, C, H, d), (1, 0, 3, 2, 4))
+    return x.reshape((n // _kda_group_size(n), -1) + x.shape[1:])
+
+
+def _kda_ungrouped(x):
+    """[n / G, G, B, H, C, d] -> [B, T, H, d]."""
+    _ng, _G, B, H, C, d = x.shape
+    return jnp.transpose(x.reshape((-1,) + x.shape[2:]), (1, 0, 3, 2, 4)).reshape(B, -1, H, d)
+
+
+def _kda_group(C: int):
+    """One group's pass as a function (S, (q, k, v, a, beta) of the group's
+    chunks) -> (S after them, (o, the state each chunk started from)): what
+    is parallel over chunks for all of them at once, then the state through
+    them one by one."""
     row, col = np.arange(C)[:, None], np.arange(C)[None, :]
 
     def step(S, xs):
         w, u0, aqk, qe, kt, dc = xs
+        start = S
         u = u0 - _mm(w, S, "bhtk,bhkv->bhtv")
         o = _mm(qe, S, "bhtk,bhkv->bhtv") + _mm(aqk, u, "bhts,bhsv->bhtv")
         S = S * dc + _mm(kt, u, "bhtk,bhtv->bhkv")
-        return S, o
+        return S, (o, start)
 
-    @jax.checkpoint
     def group(S, xs):
-        """``KDA_GROUP`` chunks: what is parallel over chunks for all of
-        them at once, then the state through them one by one. Recomputed
-        in the backward pass, so only the state at group boundaries and
-        the layer's q, k, v, a, beta outlive it."""
         q, k, v, a, beta = (x.astype(F32) for x in xs)
         g = jnp.cumsum(a, axis=-2)                         # <= 0, falling
         mid = g[..., C // 2 - 1: C // 2, :]
@@ -186,11 +197,348 @@ def kda_chunked(q, k, v, a, beta, chunk: int = 64):
         decay = jnp.swapaxes(jnp.exp(g_last), -1, -2)      # [G, B, H, dk, 1]
         return jax.lax.scan(step, S, (W, U0, Aqk, q * eg, k_tail, decay))
 
-    G = next(x for x in range(min(KDA_GROUP, n), 0, -1) if n % x == 0)
-    grouped = tuple(x.reshape((n // G, G) + x.shape[1:]) for x in (q, k, v, a, beta))
-    _S, o = jax.lax.scan(group, jnp.zeros((B, H, dk, dv), F32), grouped)
-    o = jnp.transpose(o.reshape((n,) + o.shape[2:]), (1, 0, 3, 2, 4))
-    return o.reshape(B, n * C, H, dv)[:, :T]
+    return group
+
+
+def _kda_scan(q, k, v, a, beta, C: int):
+    """The chunked rule in ``jax.numpy``, a length ``C`` divides: a scan
+    over groups of ``KDA_GROUP`` chunks, each recomputed in the backward
+    pass, so that only the state at group boundaries and the layer's q, k,
+    v, a, beta outlive a group. Returns (o [B, T, H, dv] in float32, the
+    state each chunk started from [n / G, G, B, H, dk, dv])."""
+    B, _T, H, dk = q.shape
+    _S, (o, starts) = jax.lax.scan(
+        jax.checkpoint(_kda_group(C)), jnp.zeros((B, H, dk, v.shape[-1]), F32),
+        tuple(_kda_grouped(x, C) for x in (q, k, v, a, beta)))
+    return _kda_ungrouped(o), starts
+
+
+def _kda_scan_backward(q, k, v, a, beta, starts, ct, C: int):
+    """The five gradients under ``ct`` from the states the groups started
+    from [n / G, B, H, dk, dv]: the groups in reverse, each recomputed and
+    differentiated, which is what ``_kda_scan``'s own backward pass does."""
+    group = _kda_group(C)
+
+    def back(dS, xs):
+        S, inputs, d_o = xs
+        (_after, (_o, inside)), vjp = jax.vjp(group, S, inputs)
+        return vjp((dS, (d_o, jnp.zeros_like(inside))))
+
+    _dS, grads = jax.lax.scan(
+        back, jnp.zeros(starts.shape[1:], F32),
+        (starts, tuple(_kda_grouped(x, C) for x in (q, k, v, a, beta)),
+         _kda_grouped(ct.astype(F32), C)), reverse=True)
+    dq, dk, dv, da, dbeta = (_kda_ungrouped(x) for x in grads)
+    return dq, dk, dv, da, dbeta[..., 0]
+
+
+_NN, _NT, _TN = ((((1,), (0,)), ((), ())), (((1,), (1,)), ((), ())),
+                 (((0,), (0,)), ((), ())))    # x y, x y^T, x^T y
+
+
+def _kmm(x, y, dims=_NN):
+    """``_mm`` on a kernel's tiles: bfloat16 operands, a float32 sum."""
+    return jax.lax.dot_general(x.astype(BF16), y.astype(BF16), dims,
+                               preferred_element_type=F32)
+
+
+def _kmm32(x, y, dims=_NN):
+    """A float32 product at full precision on a kernel's tiles."""
+    return jax.lax.dot_general(x, y, dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=F32)
+
+
+def _side_by_side(x):
+    """A block-diagonal [2C, 2C] tile's two blocks as one [C, 2C] tile."""
+    C = KDA_KERNEL_CHUNK
+    left = jax.lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1) < C
+    return jnp.where(left, x[:C], x[C:])
+
+
+def _block_diagonal(x):
+    """``_side_by_side``'s inverse: no lane moves either way."""
+    C = KDA_KERNEL_CHUNK
+    left = jax.lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1) < C
+    return jnp.concatenate([jnp.where(left, x, 0.0), jnp.where(left, 0.0, x)], axis=0)
+
+
+def _kda_block_terms(q, k, v, a, beta):
+    """``_kda_group``'s lines before its scan for one head's block of two
+    chunks, on [2C, d] tiles in VMEM (``beta`` [2C, 1]): a product over the
+    block's tokens holds both chunks' [C, C] results as its diagonal blocks
+    (what pairs tokens of different chunks is masked away), and the
+    triangular solve runs on the two side by side in one [C, 2C] tile, so
+    that an MXU pass serves two chunks. Everything either kernel needs of
+    the block, by name."""
+    C = KDA_KERNEL_CHUNK
+    N, bits = 2 * C, C.bit_length() - 1
+    row = jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
+    same = (row >> bits) == (col >> bits)                  # tokens of one chunk
+    at = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    g = _kmm32((same & (row >= col)).astype(F32), a)       # a chunk's running sum of a
+    of_chunk = lambda r: jnp.where(at < C, g[r: r + 1], g[C + r: C + r + 1])
+    mid, g_last = of_chunk(C // 2 - 1), of_chunk(C - 1)
+    up = jnp.exp(jnp.minimum(g - mid, EXP_CLIP))
+    down = jnp.exp(jnp.minimum(mid - g, EXP_CLIP))
+    eg, tail = jnp.exp(g), jnp.exp(g_last - g)
+    kb = beta * k
+    ku, kd, ke, vb = kb * up, k * down, kb * eg, beta * v
+    A = _side_by_side(jnp.where(same & (row > col), _kmm(ku, kd, _NT), 0.0))
+    # (I + A)^-1 by ``unit_lower_inverse``'s levels on whole tiles: a level's
+    # inverse is block diagonal, so inv M inv, with M the blocks of A below
+    # the diagonal that the level pairs, is every pair's Y^-1 M X^-1.
+    r = jax.lax.broadcasted_iota(jnp.int32, (C, N), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (C, N), 1) & (C - 1)
+    inv = jnp.where(r == c, 1.0, 0.0) - jnp.where((r == c + 1) & ((r & 1) == 1), A, 0.0)
+    for level in range(1, bits):                           # blocks of 2, 4, ... C / 2
+        b = 1 << level
+        below = ((r >> (level + 1)) == (c >> (level + 1))) & ((r & b) != 0) & ((c & b) == 0)
+        inv = inv - _kmm32(_kmm32(inv, _block_diagonal(jnp.where(below, A, 0.0))),
+                           _block_diagonal(inv))
+    T = _block_diagonal(inv)
+    qu, qe, kt = q * up, q * eg, k * tail
+    Aqk = jnp.where(same & (row >= col), _kmm(qu, kd, _NT), 0.0)
+    return dict(row=row, col=col, same=same, at=at, g=g, mid=mid, up=up, down=down, eg=eg,
+                tail=tail, decay=[jnp.exp(g[C - 1: C]), jnp.exp(g[N - 1:])], kb=kb, ku=ku,
+                kd=kd, ke=ke, vb=vb, T=T, W=_kmm(T, ke), U0=_kmm(T, vb), qu=qu, qe=qe,
+                kt=kt, Aqk=Aqk)
+
+
+def _head_beta(beta_ref):
+    """The grid step's head's column [2C, 1] of the block's [2C, H] tile of beta."""
+    betas = beta_ref[0].astype(F32)
+    head = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    return jnp.sum(jnp.where(head == pl.program_id(1), betas, 0.0), axis=1, keepdims=True)
+
+
+def _kda_forward_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, *rest):
+    """A grid step of ``_kda_kernel_forward``: a head's block of two chunks.
+    ``rest``: (``start_ref``,) ``state``: the head's state (transposed:
+    [dv, dk], so that the decay is a row), carried from a block to the
+    head's next; ``start_ref``, where the call keeps them, takes it as each
+    chunk finds it."""
+    C = KDA_KERNEL_CHUNK
+    state = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    q, k, v, a = (x[0].astype(F32) for x in (q_ref, k_ref, v_ref, a_ref))
+    t = _kda_block_terms(q, k, v, a, _head_beta(beta_ref))
+    St, us = state[...], []
+    for i in range(2):                                     # ``_kda_group``'s step
+        rows = slice(i * C, (i + 1) * C)
+        for start_ref in rest[:-1]:
+            start_ref[0, 0, i] = St
+        us.append(t["U0"][rows] - _kmm(t["W"][rows], St, _NT))
+        o_ref[0, rows] = (_kmm(t["qe"][rows], St, _NT) + _kmm(
+            t["Aqk"][rows, : (i + 1) * C], jnp.concatenate(us, axis=0)))
+        St = St * t["decay"][i] + _kmm(us[i], t["kt"][rows], _TN)
+    state[...] = St
+
+
+def _kda_backward_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, ct_ref, start_ref,
+                         dq_ref, dk_ref, dv_ref, da_ref, db_ref, d_state):
+    """A grid step of ``_kda_kernel_backward``, the blocks of a head from
+    the last to the first: the block's terms again from q, k, v, a, beta
+    and the states its chunks started from, then every line of the forward
+    pass transposed. ``d_state``: the gradient of the state the block
+    leaves (transposed), carried to the block before. ``db_ref`` takes
+    beta's gradient before its sum over a head's channels."""
+    C = KDA_KERNEL_CHUNK
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    q, k, v, a, d_o = (x[0].astype(F32) for x in (q_ref, k_ref, v_ref, a_ref, ct_ref))
+    beta = _head_beta(beta_ref)
+    t = _kda_block_terms(q, k, v, a, beta)
+    row, col, same, at, T, up, down, eg, tail = (t[n] for n in (
+        "row", "col", "same", "at", "T", "up", "down", "eg", "tail"))
+    halves = [slice(0, C), slice(C, 2 * C)]
+    starts = [start_ref[0, 0, i] for i in range(2)]
+    u = jnp.concatenate([t["U0"][rows] - _kmm(t["W"][rows], St, _NT)
+                         for rows, St in zip(halves, starts)], axis=0)
+    # the step, the second chunk first: o = qe S + Aqk u;
+    # S' = decay S + kt^T u;  u = U0 - W S
+    d_u_of_o = _kmm(t["Aqk"], d_o, _TN)
+    dSt = d_state[...]
+    d_u, d_W, d_qe, d_kt, d_last = ([None, None] for _ in range(5))
+    for i in (1, 0):
+        rows, St, decay = halves[i], starts[i], t["decay"][i]
+        d_u[i] = d_u_of_o[rows] + _kmm(t["kt"][rows], dSt, _NT)
+        d_W[i], d_qe[i], d_kt[i] = -_kmm(d_u[i], St), _kmm(d_o[rows], St), _kmm(u[rows], dSt)
+        d_last[i] = jnp.sum(St * dSt, axis=0, keepdims=True) * decay
+        dSt = (dSt * decay + _kmm(d_o[rows], t["qe"][rows], _TN)
+               - _kmm(d_u[i], t["W"][rows], _TN))
+    d_state[...] = dSt
+    d_u, d_W, d_qe, d_kt = (jnp.concatenate(x, axis=0) for x in (d_u, d_W, d_qe, d_kt))
+    # W = T ke, U0 = T vb;  T = (I + A)^-1: dA = -T^T dT T^T below the diagonal
+    d_Aqk = jnp.where(same & (row >= col), _kmm(d_o, u, _NT), 0.0)
+    d_T = jnp.where(same, _kmm(d_W, t["ke"], _NT) + _kmm(d_u, t["vb"], _NT), 0.0)
+    d_ke, d_vb = _kmm(T, d_W, _TN), _kmm(T, d_u, _TN)
+    d_A = jnp.where(same & (row > col), -_kmm32(_kmm32(T, d_T, _TN), T, _NT), 0.0)
+    d_ku = _kmm(d_A, t["kd"])
+    d_kd = _kmm(d_A, t["ku"], _TN) + _kmm(d_Aqk, t["qu"], _TN)
+    d_qu = _kmm(d_Aqk, t["kd"])
+    dq_ref[0] = (d_qu * up + d_qe * eg).astype(dq_ref.dtype)
+    dk_ref[0] = (beta * (d_ku * up + d_ke * eg) + d_kd * down
+                 + d_kt * tail).astype(dk_ref.dtype)
+    dv_ref[0] = (d_vb * beta).astype(dv_ref.dtype)
+    db_ref[0] = k * (d_ku * up + d_ke * eg) + d_vb * v
+    # the factors: up and down about a chunk's middle row, eg, tail and decay
+    # about its last; g a chunk's running sum of a, so da the sum of dg from
+    # the row to the chunk's end
+    x_up = jnp.where(t["g"] - t["mid"] < EXP_CLIP, (d_ku * t["kb"] + d_qu * q) * up, 0.0)
+    x_down = jnp.where(t["mid"] - t["g"] < EXP_CLIP, d_kd * k * down, 0.0)
+    x_tail = d_kt * k * tail
+    d_g = x_up - x_down + (d_ke * t["kb"] + d_qe * q) * eg - x_tail
+    for i, rows in enumerate(halves):
+        d_mid = jnp.sum((x_down - x_up)[rows], axis=0, keepdims=True)
+        d_g = (d_g + jnp.where(at == i * C + C // 2 - 1, d_mid, 0.0)
+               + jnp.where(at == (i + 1) * C - 1, d_last[i] + jnp.sum(
+                   x_tail[rows], axis=0, keepdims=True), 0.0))
+    da_ref[0] = _kmm32((same & (col >= row)).astype(F32), d_g)
+
+
+def _kda_kernel_grid(shape, back: bool):
+    """What both kernels share: (the grid (batch, heads, blocks of two
+    chunks), a head's blocks one after the other (``back``: from the last);
+    the tile of a [B, T, H x d] array, a head's block; of beta [B, T, H], a
+    block; of the chunks' states [B, H, n, d, d], a block's two)."""
+    B, T, H, d = shape
+    N = 2 * KDA_KERNEL_CHUNK
+    at = (lambda c: T // N - 1 - c) if back else (lambda c: c)
+    return ((B, H, T // N),
+            pl.BlockSpec((1, N, d), lambda b, h, c: (b, at(c), h)),
+            pl.BlockSpec((1, N, H), lambda b, h, c: (b, at(c), 0)),
+            pl.BlockSpec((1, 1, 2, d, d), lambda b, h, c: (b, h, at(c), 0, 0)))
+
+
+_KDA_KERNEL_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _kda_kernel_forward(q, k, v, a, beta, keep: bool = True, interpret: bool = False):
+    """``_kda_scan`` as one Pallas kernel: the chunks of a head one after
+    the other, two a grid step, its state in VMEM all the while; q, k, v,
+    a, beta are read a block of a head at a time straight from
+    [B, T, H x d], and nothing but o and, with ``keep``, the state each
+    chunk started from (as the kernels keep it: [B, H, n, dv, dk]) is
+    written. ``interpret``: the tests' way to run it on the CPU."""
+    B, T, H, d = q.shape
+    grid, wide, narrow, states = _kda_kernel_grid(q.shape, back=False)
+    o, *starts = pl.pallas_call(
+        _kda_forward_kernel, grid=grid,
+        in_specs=[wide] * 4 + [narrow], out_specs=[wide] + [states] * keep,
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * d), F32)]
+        + [jax.ShapeDtypeStruct((B, H, T // KDA_KERNEL_CHUNK, d, d), F32)] * keep,
+        scratch_shapes=[pltpu.VMEM((d, d), F32)],
+        compiler_params=_KDA_KERNEL_PARAMS, name="kda_chunk_fwd", interpret=interpret,
+    )(*(x.reshape(B, T, H * d) for x in (q, k, v, a)), beta)
+    return (o.reshape(B, T, H, d), *starts)
+
+
+def _kda_kernel_backward(q, k, v, a, beta, starts, ct, interpret: bool = False):
+    """The five gradients under ``ct`` as one Pallas kernel: a head's
+    chunks from the last to the first, two a grid step, the state's
+    gradient in VMEM all the while, each chunk's insides made again in VMEM
+    from what the forward kernel read and the state it saved."""
+    B, T, H, d = q.shape
+    grid, wide, narrow, states = _kda_kernel_grid(q.shape, back=True)
+    flat = lambda x: x.reshape(B, T, H * d)
+    dq, dk, dv, da, db = pl.pallas_call(
+        _kda_backward_kernel, grid=grid,
+        in_specs=[wide] * 4 + [narrow, wide, states], out_specs=[wide] * 5,
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * d), dt)
+                   for dt in (q.dtype, k.dtype, v.dtype, a.dtype, F32)],
+        scratch_shapes=[pltpu.VMEM((d, d), F32)],
+        compiler_params=_KDA_KERNEL_PARAMS, name="kda_chunk_bwd", interpret=interpret,
+    )(flat(q), flat(k), flat(v), flat(a), beta, flat(ct.astype(F32)), starts)
+    dq, dk, dv, da, db = (x.reshape(B, T, H, d) for x in (dq, dk, dv, da, db))
+    return dq, dk, dv, da, db.sum(-1).astype(beta.dtype)
+
+
+def _kda_forward(q, k, v, a, beta, keep: bool, interpret: bool):
+    """(o, with ``keep`` the state each chunk started from as the kernels
+    keep it, 1.0 where the kernel ran): the kernel where the program is
+    lowered for a TPU, the scan elsewhere."""
+    fused = lambda *xs: _kda_kernel_forward(*xs, keep, interpret) + (jnp.float32(1.0),)
+    if interpret:
+        return fused(q, k, v, a, beta)
+
+    def scan(*xs):
+        o, starts = _kda_scan(*xs, KDA_KERNEL_CHUNK)
+        starts = starts.reshape((-1,) + starts.shape[2:])      # [n, B, H, dk, dv]
+        return (o,) + (jnp.transpose(starts, (1, 2, 0, 4, 3)),) * keep + (jnp.float32(0.0),)
+
+    return jax.lax.platform_dependent(q, k, v, a, beta, tpu=fused, default=scan)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_fused(q, k, v, a, beta, interpret=False):
+    """The chunked rule at the kernels' shapes: (o, 1.0 where the kernel
+    ran). Differentiated, the forward pass keeps q, k, v, a, beta and the
+    state each chunk started from (0.54 GB a layer at 2 x 8,192 tokens and
+    32 heads), and the backward pass is the second kernel, or the scan's
+    own from the states its groups started from."""
+    return _kda_forward(q, k, v, a, beta, False, interpret)
+
+
+def _kda_fused_fwd(q, k, v, a, beta, interpret):
+    o, starts, fused = _kda_forward(q, k, v, a, beta, True, interpret)
+    return (o, fused), (q, k, v, a, beta, starts)
+
+
+def _kda_fused_bwd(interpret, saved, cts):
+    fused = lambda *xs: _kda_kernel_backward(*xs, interpret=interpret)
+    if interpret:
+        return fused(*saved, cts[0])
+
+    def scan(q, k, v, a, beta, starts, ct):
+        G = _kda_group_size(starts.shape[2])
+        firsts = jnp.transpose(starts[:, :, ::G], (2, 0, 1, 4, 3))
+        return _kda_scan_backward(q, k, v, a, beta, firsts, ct, KDA_KERNEL_CHUNK)
+
+    return jax.lax.platform_dependent(*saved, cts[0], tpu=fused, default=scan)
+
+
+_kda_fused.defvjp(_kda_fused_fwd, _kda_fused_bwd)
+
+
+def kda_chunked(q, k, v, a, beta, chunk: int = 64):
+    """S_t = (I - b_t k_t k_t^T) Diag(exp a_t) S_(t-1) + b_t k_t v_t^T,
+    o_t = S_t^T q_t (``q``, ``k``, ``a``: [B, T, H, dk]; ``v``: [B, T, H, dv];
+    ``beta``: [B, T, H]) in chunks of ``chunk`` tokens (a power of two). Within a chunk, with g the running sum of ``a``: the updates
+    u_t = b_t (v_t - S_(t-1)^T Diag(alpha_t) k_t) solve (I + A) U = b V -
+    (b K e^g) S_0, where A_ts = b_t sum_d k_td k_sd e^(g_td - g_sd) for
+    s < t; then o = (Q e^g) S_0 + tril((Q e^g)(K e^-g)^T) U and
+    S_C = e^(g_C) S_0 + (K e^(g_C - g))^T U. The factors e^g and e^-g are
+    taken about the chunk's middle token and clipped at e^80, which is
+    exact while half a chunk's summed log-decay stays above -80 (2.5 a
+    token at chunk 64; the initial range ends at 1.6). A ragged tail is
+    padded with tokens that leave the state as it is (b = 0, a = 0).
+
+    Returns (o [B, T, H, dv] in float32, 1.0 where the fused chunk kernel
+    computed it and 0.0 where the ``jax.numpy`` scan did). The kernel runs
+    where the program is lowered for a TPU, at the shapes it is written for
+    (chunk ``KDA_KERNEL_CHUNK``, keys and values ``KDA_KERNEL_WIDTH`` wide,
+    a length two chunks divide); decided when the program is lowered, as
+    ``mla_attention`` is, and from nothing else. Every other shape, and
+    every other platform, runs the scan."""
+    T, dk, dv = q.shape[1], q.shape[-1], v.shape[-1]
+    C = int(chunk)
+    if C == KDA_KERNEL_CHUNK and dk == dv == KDA_KERNEL_WIDTH and T % (2 * C) == 0:
+        return _kda_fused(q, k, v, a, beta)
+    pad = (-T) % C
+    if pad:
+        q, k, v, a = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for x in (q, k, v, a))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    return _kda_scan(q, k, v, a, beta, C)[0][:, :T], jnp.float32(0.0)
 
 
 def causal_conv(x, w):
@@ -408,9 +756,9 @@ class _Kda(nn.Module):
             a = decay(x, w_f1, w_f2, a_log, p("dt_bias", (H * d,), dt_init))
             beta = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x.astype(F32),
                                              p("w_beta", (D, H))))
-            o = kda_chunked(q, k, v, a, beta, self.chunk)
+            o, fused = kda_chunked(q, k, v, a, beta, self.chunk)
             return output(o.astype(BF16), x, p("w_g1", (D, d)), p("w_g2", (d, H * d)),
-                          p("o_norm", (d,), nn.initializers.ones), p("w_o", (H * d, D)))
+                          p("o_norm", (d,), nn.initializers.ones), p("w_o", (H * d, D))), fused
 
 
 class _Mla(nn.Module):
@@ -494,10 +842,9 @@ class _Layer(nn.Module):
         eps = c["rms_norm_eps"]
         norm = lambda name: self.param(name, nn.initializers.ones, (h.shape[-1],))
         x = rms_norm(h, norm("norm_mixer"), eps)
-        fused = jnp.float32(0.0)
         if self.mixer == "kda":
-            m = _Kda(c["num_heads"], c["kda_head_dim"], c["short_conv_kernel_size"],
-                     c["kda_chunk"], eps, name="kda")(x)
+            m, fused = _Kda(c["num_heads"], c["kda_head_dim"], c["short_conv_kernel_size"],
+                            c["kda_chunk"], eps, name="kda")(x)
         else:
             m, fused = _Mla(c["num_heads"], c["qk_nope_head_dim"], c["qk_rope_head_dim"],
                             c["v_head_dim"], c["kv_lora_rank"], eps, name="mla")(x)
@@ -517,8 +864,8 @@ class _KimiLinear(nn.Module):
     """x [B, T] token ids -> the next token's logits after the last one
     given [B, V]; with ``hidden``, (hidden states after the final norm
     [B, T, D] in bfloat16, the untied head [D, V], rows each held expert
-    took in each layer [layers, E], the MLA layers whose attention the
-    fused kernel computed)."""
+    took in each layer [layers, E], the layers whose mixer a fused kernel
+    computed [2]: MLA, KDA)."""
 
     cfg: Any
     vocab: int
@@ -536,16 +883,16 @@ class _KimiLinear(nn.Module):
         embed = self.param("embed", _dense_init(), (self.vocab, D))
         head = self.param("head", _dense_init(), (D, self.vocab))
         h = jnp.take(embed, x, axis=0).astype(BF16)
-        loads, fused = [], jnp.float32(0.0)
+        loads, fused = [], {"mla": jnp.float32(0.0), "kda": jnp.float32(0.0)}
         layer = nn.remat(_Layer) if train else _Layer
         for i, (mixer, sparse) in enumerate(self.layer_kinds()):
             h, load, kernel = layer(self.cfg, mixer, sparse, name=f"layer_{i + 1}")(h)
             loads.append(load)
-            fused = fused + kernel
+            fused[mixer] = fused[mixer] + kernel
         h = rms_norm(h, self.param("norm_out", nn.initializers.ones, (D,)),
                      c["rms_norm_eps"]).astype(BF16)
         if hidden:
-            return h, head, jnp.stack(loads), fused
+            return h, head, jnp.stack(loads), jnp.stack([fused["mla"], fused["kda"]])
         # Serving: the next token's distribution after the last one given.
         return _mm(h[:, -1], head, "bd,dv->bv")
 
@@ -650,7 +997,7 @@ class KimiLinear(JaxModel):
             sparse for _m, sparse in module.layer_kinds())
 
         sparse = np.array([sp for _m, sp in module.layer_kinds()])
-        mla_layers = sum(mixer == "mla" for mixer, _sp in module.layer_kinds())
+        mixers = [mixer for mixer, _sp in module.layer_kinds()]
 
         def stats(params, batch, train, smoothing):
             h, head, loads, fused = module.apply({"params": params}, batch["x"],
@@ -668,8 +1015,10 @@ class KimiLinear(JaxModel):
                 "acc": hits / n,
                 "count.moe.slots_held": loads.sum(),
                 "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
-                "count.mla.fused": fused,
-                "count.mla.layers": jnp.float32(mla_layers),
+                "count.mla.fused": fused[0],
+                "count.mla.layers": jnp.float32(mixers.count("mla")),
+                "count.kda.fused": fused[1],
+                "count.kda.layers": jnp.float32(mixers.count("kda")),
                 "gauge.moe.held_load_max_over_mean": skew.mean()}
 
         def eval_count(params, batch):

@@ -1,5 +1,5 @@
-"""What the three files of tests of the language-model template share
-(tests/test_kimi_linear_layers.py, _model.py, _trials.py): the path to
+"""What the four files of tests of the language-model template share
+(tests/test_kimi_linear_layers.py, _kda.py, _model.py, _trials.py): the path to
 the benchmark's reference and tiny configuration, the small subclass, the
 seeded program and its reference parameters, and two fixtures.
 
@@ -9,6 +9,7 @@ logits) the template's matrix products are switched to float32 (``f32``) so
 that the two must agree closely; one test keeps bfloat16 and asks for
 closeness."""
 
+import functools
 import sys
 from pathlib import Path
 
@@ -91,3 +92,43 @@ def tokens(cfg, n=2, seed=5):
 def close(a, b, tol):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(b)), 1e-12)
+
+
+def value_and_grads(fn, *args):
+    """fn's result at ``args[:-1]`` and its gradients under the cotangent
+    ``args[-1]``."""
+    *xs, ct = args
+    out, vjp = jax.vjp(lambda *xs: fn(*xs).astype(jnp.float32), *xs)
+    return (out,) + vjp(ct)
+
+
+@pytest.fixture
+def interpreted():
+    """Pallas' interpreter ran in this test. With what it leaves in jax's
+    caches, a later test of this file (an eager ``lax.scan`` under the
+    ``f32`` fixture) died of a segmentation fault in this jax (0.9.0), every
+    time; with the caches cleared it does not."""
+    yield
+    jax.clear_caches()
+
+
+def kda_operands(T=256, dtype=jnp.float32, B=1, H=2, d=K.KDA_KERNEL_WIDTH):
+    """The chunk kernels' shapes: heads of 128, two blocks of two chunks of 64."""
+    ks = jax.random.split(jax.random.PRNGKey(T), 6)
+    q = (K.l2norm(jax.random.normal(ks[0], (B, T, H, d))) * d ** -0.5).astype(dtype)
+    k = K.l2norm(jax.random.normal(ks[1], (B, T, H, d))).astype(dtype)
+    v = jax.random.normal(ks[2], (B, T, H, d)).astype(dtype)
+    a = -jnp.exp(jax.random.uniform(ks[3], (B, T, H, d), minval=np.log(1e-3),
+                                    maxval=np.log(1.6)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
+    return (q, k, v, a, beta), jax.random.normal(ks[5], (B, T, H, d))
+
+
+@functools.lru_cache(maxsize=None)
+def step_metrics(seq_len, chunk=16):
+    cfg = tiny_lm(load_lm_cfg(), chunk=chunk, seq_len=seq_len)
+    _model, fns, params, _ref = program_of(cfg)
+    x, y = tokens(cfg)
+    _loss, metrics = jax.jit(fns["loss_fn"])(params, {"x": x, "y": y}, None,
+                                             {"label_smoothing": jnp.float32(0.0)})
+    return {k: float(v) for k, v in metrics.items()}

@@ -259,15 +259,34 @@ def _kimi_linear_cell():
     return model, int(cfg["vocab_size"]), int(cfg["seq_len"]), int(pinned["batch_size"])
 
 
-def _attention_kernels(text):
-    """(instruction name, op_name) of the fused attention's kernel calls in
-    a compiled text. (The printed call spans three lines: its kernel
-    metadata holds a line break.)"""
+def _kernels(text, prefix):
+    """(instruction name, op_name) of the kernel calls in a compiled text
+    whose name starts with ``prefix``. (An attention kernel's printed call
+    spans three lines: its kernel metadata holds a line break.)"""
     import re
 
     return [(m.group(1), text[m.end(): text.find("\n  %", m.end())].split('op_name="')[1]
              .split('"')[0])
-            for m in re.finditer(r"^\s*%(splash_mha[\w.]*) = ", text, re.M)]
+            for m in re.finditer(rf"^\s*%({prefix}[\w.]*) = ", text, re.M)]
+
+
+def _attention_kernels(text):
+    return _kernels(text, "splash_mha")
+
+
+def _chunk_kernels(text):
+    """The fused chunk rule's calls by kind, each under the ``kda`` scope."""
+    kernels = _kernels(text, "kda_chunk_")
+    assert all("/kda/" in op for _name, op in kernels), kernels
+    assert all("transpose(jvp(" in op for name, op in kernels if "bwd" in name), kernels
+    return sorted(name.split(".")[0] for name, _op in kernels)
+
+
+def _scan_leftovers(text):
+    """Lines of the ``jax.numpy`` chunk rule in a compiled text: the
+    triangular inverse's ``jit(diagonal)`` (gathers, scatter-adds and the
+    ``while`` loops the compiler makes of them)."""
+    return [line for line in text.split("\n") if "jit(diagonal)" in line]
 
 
 def test_the_latent_attention_layer_is_three_kernel_calls_on_v5e(one_chip):
@@ -303,13 +322,45 @@ def test_the_latent_attention_layer_is_three_kernel_calls_on_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.47e9
 
 
+def test_the_delta_rule_layer_is_two_kernel_calls_on_v5e(one_chip):
+    """``_Kda``'s value and gradients at the cell's shapes (2 x 8,192
+    tokens, 32 heads of 128, chunk 64): lowered for the described chip,
+    ``kda_chunked`` takes the fused chunk kernels, one call forward and one
+    backward, each under the ``kda`` scope (``kda_device_share.lm`` reads
+    that), and nothing of the scan's triangular inverse is left."""
+    from rafiki_tpu.models import kimi_linear as K
+
+    model, _vocab, T, B = _kimi_linear_cell()
+    c = dict(model.module_config())
+    mod = K._Kda(c["num_heads"], c["kda_head_dim"], c["short_conv_kernel_size"],
+                 c["kda_chunk"], c["rms_norm_eps"])
+    x = jax.ShapeDtypeStruct((B, T, c["hidden_size"]), jnp.float32)
+    params = jax.eval_shape(mod.init, jax.random.PRNGKey(0), x)["params"]
+
+    def loss(params, x):
+        out, fused = mod.apply({"params": params}, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2), fused
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        _on(one_chip, params), _on(one_chip, x)).compile()
+    text = compiled.as_text()
+    assert _chunk_kernels(text) == ["kda_chunk_bwd", "kda_chunk_fwd"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not _scan_leftovers(text)
+    # the scan's layer, compiled the same way at the parent commit: 3.40 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.40e9
+
+
 def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel(one_chip):
     """The benchmark cell's whole step program (602 M parameters, Adam,
     every layer recomputed) and its evaluation step: the TPU compiler has
     refused in the whole step what it took in every part (PR 27's two
     scatter-adds). The forward kernel twice (the forward pass and the
     layer's ``nn.remat``), the two backward kernels once, nothing else of
-    the attention; the evaluation takes the kernel that saves nothing."""
+    the attention; the evaluation takes the kernel that saves nothing. Of the
+    chunk rule: in each of the four KDA layers the forward kernel twice and
+    the backward kernel once, the forward kernel once in the evaluation, and
+    no line of the scan's triangular inverse."""
     from rafiki_tpu.ops.train import Program, _ShardingPlan
 
     model, vocab, T, B = _kimi_linear_cell()
@@ -327,10 +378,14 @@ def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel
         "splash_mha_fwd_residuals", "splash_mha_fwd_residuals"]
     assert all("/mla/" in op for _name, op in kernels), kernels
     assert SCORES not in text and "[2,32,256," not in text
-    # the parent's step, compiled the same way: 5.83 GB of temporaries (4.94 here)
-    assert step.memory_analysis().temp_size_in_bytes < 5.83e9
+    assert _chunk_kernels(text) == ["kda_chunk_bwd"] * 4 + ["kda_chunk_fwd"] * 8
+    assert not _scan_leftovers(text)
+    # the step with the scan, compiled the same way: 4.94 GB of temporaries
+    assert step.memory_analysis().temp_size_in_bytes < 4.94e9
     assert _peak_bytes(step) < HBM_BYTES
     evaluate = prog.eval_step.lower(state[0], batch).compile()
     assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
         "splash_mha_fwd_no_residuals"]
+    assert _chunk_kernels(evaluate.as_text()) == ["kda_chunk_fwd"] * 4
+    assert not _scan_leftovers(evaluate.as_text())
     assert _peak_bytes(evaluate) < HBM_BYTES

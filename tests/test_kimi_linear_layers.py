@@ -1,7 +1,8 @@
 """The language-model template's layers (rafiki_tpu/models/kimi_linear.py)
 against the plain reference (benchmark/references/kimi_linear.py) at a small
 size on seeded weights: the two mixers (chunked KDA, MLA and its fused
-kernel, what a step counts of it) and the expert layer. Shared fixtures:
+kernel, what a step counts of it) and the expert layer. The fused chunk
+kernels of KDA: tests/test_kimi_linear_kda.py. Shared fixtures:
 tests/kimi_linear_common.py."""
 
 import sys
@@ -12,8 +13,8 @@ import numpy as np
 import pytest
 
 from kimi_linear_common import (  # noqa: F401 (fixtures)
-    cfg, close, f32, flat, K, load_lm_cfg, program_of, R, REPO, tiny_lm,
-    tokens)
+    cfg, close, f32, flat, interpreted, K, kda_operands, load_lm_cfg, program_of, R, REPO,
+    step_metrics, value_and_grads)
 
 
 def test_reference_starts_from_the_programs_initial_parameters(cfg):
@@ -36,10 +37,11 @@ def test_chunked_kda_equals_the_recurrence(chunk, f32):
                                     maxval=np.log(1.6)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
     want = R.delta_rule(q, k, v, a, beta)
-    assert close(K.kda_chunked(q, k, v, a, beta, chunk), want, 1e-5)
+    got, fused = K.kda_chunked(q, k, v, a, beta, chunk)
+    assert close(got, want, 1e-5) and float(fused) == 0.0
     assert close(R.delta_rule(q[:, :128], k[:, :128], v[:, :128], a[:, :128],
                               beta[:, :128], fit=True), want[:, :128], 1e-6)
-    g = jax.grad(lambda a_: K.kda_chunked(q, k, v, a_, beta, chunk).sum())(a)
+    g = jax.grad(lambda a_: K.kda_chunked(q, k, v, a_, beta, chunk)[0].sum())(a)
     assert close(g, jax.grad(lambda a_: R.delta_rule(q, k, v, a_, beta).sum())(a), 1e-4)
 
 
@@ -65,10 +67,8 @@ def test_each_mixer_matches_the_reference(cfg, mixer, f32):
                      c["v_head_dim"], c["kv_lora_rank"], c["rms_norm_eps"])
         want = R.mla(ref, f"layer_{layer}", x, cfg)
         assert close(R.mla(ref, f"layer_{layer}", x, cfg, q_block=32), want, 1e-6)
-    got = mod.apply({"params": params[f"layer_{layer}"][mixer]}, x)
-    if mixer == "mla":
-        got, fused = got
-        assert float(fused) == 0.0         # 96 tokens: no kernel block divides them
+    got, fused = mod.apply({"params": params[f"layer_{layer}"][mixer]}, x)
+    assert float(fused) == 0.0    # 96 tokens, heads of 16: neither kernel's shapes
     assert close(got, want, 2e-5)
 
 
@@ -78,22 +78,6 @@ def mla_operands(T, dtype=jnp.float32, B=1, H=2):
     q, k = (jax.random.normal(ks[i], (B, T, H, 192)).astype(dtype) for i in (0, 1))
     v = jax.random.normal(ks[2], (B, T, H, 128)).astype(dtype)
     return q, k, v, jax.random.normal(ks[3], (B, T, H, 128))
-
-
-def value_and_grads(fn, q, k, v, ct):
-    """fn's result and its three gradients under the cotangent ``ct``."""
-    out, vjp = jax.vjp(lambda q, k, v: fn(q, k, v).astype(jnp.float32), q, k, v)
-    return (out,) + vjp(ct)
-
-
-@pytest.fixture
-def interpreted():
-    """Pallas' interpreter ran in this test. With what it leaves in jax's
-    caches, a later test of this file (an eager ``lax.scan`` under the
-    ``f32`` fixture) died of a segmentation fault in this jax (0.9.0), every
-    time; with the caches cleared it does not."""
-    yield
-    jax.clear_caches()
 
 
 @pytest.mark.parametrize("against", ["whole_row_softmax", "blocked_path"])
@@ -148,24 +132,26 @@ def test_which_attention_runs_is_read_from_the_length_and_the_lowering(T):
                                   np.asarray(K._blocked_attention(q, k, v), np.float32))
 
 
-def test_the_kernel_is_the_same_text_whatever_model_file_holds_it():
+@pytest.mark.parametrize("kernel", ["the_attention", "the_chunk_rule"])
+def test_the_kernel_is_the_same_text_whatever_model_file_holds_it(kernel):
     """A tenant's model file is loaded as a module whose name differs from
     process to process, and the benchmark's differs from seed to seed. jax
     writes the file names of the traceback into a Pallas kernel's serialized
     body, which the persistent compile cache hashes: were the code's file
     name the module's, every process would build the step program anew
     (110 s on the chip). Lowered for a TPU from two such files, the
-    attention is one text."""
+    attention is one text, and so is the chunk rule."""
     from drivers import sweep as sweep_driver
     from rafiki_tpu.model.base import load_model_class
 
-    q, k, v, _ct = mla_operands(K.KERNEL_BLOCK, jnp.bfloat16)
+    name, xs = (("mla_attention", mla_operands(K.KERNEL_BLOCK, jnp.bfloat16)[:3])
+                if kernel == "the_attention" else
+                ("kda_chunked", kda_operands(2 * K.KDA_KERNEL_CHUNK, jnp.bfloat16)[0]))
     texts, modules = [], []
     for seed in (1, 2):
         cls = load_model_class(sweep_driver.model_source(REPO, load_lm_cfg(), seed), "BenchModel")
         modules.append(cls.__module__)
-        attention = sys.modules[cls.__module__].mla_attention
-        texts.append(jax.jit(attention).trace(q, k, v).lower(
+        texts.append(jax.jit(getattr(sys.modules[cls.__module__], name)).trace(*xs).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True))
     assert modules[0] != modules[1]
     assert "tpu_custom_call" in texts[0] and "rafiki_model.py" in texts[0]
@@ -255,10 +241,6 @@ def test_rows_past_a_ragged_products_groups_reach_neither_values_nor_gradients(c
 
 @pytest.mark.parametrize("seq_len", [96, K.KERNEL_BLOCK])
 def test_a_step_counts_its_mla_layers_and_none_of_them_fused_on_the_cpu(seq_len):
-    cfg = tiny_lm(load_lm_cfg(), seq_len=seq_len)
-    _model, fns, params, _ref = program_of(cfg)
-    x, y = tokens(cfg)
-    _loss, metrics = jax.jit(fns["loss_fn"])(params, {"x": x, "y": y}, None,
-                                             {"label_smoothing": jnp.float32(0.0)})
-    assert float(metrics["count.mla.layers"]) == 1.0    # layers: dense, KDA, KDA, MLA, KDA
-    assert float(metrics["count.mla.fused"]) == 0.0
+    metrics = step_metrics(seq_len)
+    assert metrics["count.mla.layers"] == 1.0    # layers: dense, KDA, KDA, MLA, KDA
+    assert metrics["count.mla.fused"] == 0.0

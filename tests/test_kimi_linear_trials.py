@@ -42,6 +42,7 @@ def test_a_trial_trains_scores_counts_and_reloads(cfg):
     assert 0 < counters["moe.slots_held"] < counters["moe.slots_total"]
     assert telemetry.get_gauge("moe.held_load_max_over_mean") >= 1.0
     assert (counters["mla.layers"], counters["mla.fused"]) == (4, 0)   # a step each; the CPU
+    assert (counters["kda.layers"], counters["kda.fused"]) == (16, 0)
     blob = model.dump_parameters()
     assert counters.get("persist.blob_bytes", 0) == 0 < telemetry.get_counter("persist.blob_bytes")
     model.release_train_state()            # the CPU reports no limit: nothing staged
