@@ -13,6 +13,11 @@ examples/models/ (SURVEY.md §2 "Example models", unverified paths):
                  delta rule) / latent-attention sparse-expert language
                  model, one chip's share of an expert-parallel
                  deployment; the zoo's language-modelling citizen
+  Lfm2Moe      — no reference analog: the zoo's second language model, a
+                 hybrid of gated short convolutions and grouped-query
+                 attention with rotary positions over sparse experts
+                 selected by score plus a bias; it shares the router and
+                 the expert layer with KimiLinear
 """
 
 from rafiki_tpu.models.ff import FeedForward
@@ -35,6 +40,7 @@ MODEL_REGISTRY = {
     "PosBigramHmm": ("rafiki_tpu.models.pos_hmm", "PosBigramHmm"),
     "Transformer": ("rafiki_tpu.models.transformer", "Transformer"),
     "KimiLinear": ("rafiki_tpu.models.kimi_linear", "KimiLinear"),
+    "Lfm2Moe": ("rafiki_tpu.models.lfm2_moe", "Lfm2Moe"),
 }
 
 

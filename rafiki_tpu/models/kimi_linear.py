@@ -611,14 +611,23 @@ def _fused_attention(q, k, v, interpret: bool = False):
 def mla_attention(q, k, v):
     """Causal softmax(q k^T / sqrt(d)) v: bfloat16 operands, float32
     products and softmax, every query sees every key at or before it.
-    ``q``, ``k``: [B, T, H, d]; ``v``: [B, T, H, dv]. Returns (the result
-    [B, T, H, dv] in bfloat16, 1.0 where the fused kernel computed it and
-    0.0 where the blocked code did). The kernel runs where the program is
-    lowered for a TPU and ``KERNEL_BLOCK`` divides the length; which of the
-    two is decided when the program is lowered, from the platform it is
-    lowered for (so a compile here for a described chip takes the chip's
-    path), and from nothing else."""
-    blocked = lambda q, k, v: (_blocked_attention(q, k, v), jnp.float32(0.0))
+    ``q``: [B, T, H, d]; ``k``: [B, T, Hk, d]; ``v``: [B, T, Hk, dv], where
+    Hk is H or divides it (grouped queries: a key/value head serves H / Hk
+    query heads in a row; the kernel reads it so, the blocked code repeats
+    it). Returns (the result [B, T, H, dv] in bfloat16, 1.0 where the
+    fused kernel computed it and 0.0 where the blocked code did). The
+    kernel runs where the program is lowered for a TPU and ``KERNEL_BLOCK``
+    divides the length; which of the two is decided when the program is
+    lowered, from the platform it is lowered for (so a compile here for a
+    described chip takes the chip's path), and from nothing else. Both
+    language-model templates' attention."""
+    group = q.shape[2] // k.shape[2]
+
+    def blocked(q, k, v):
+        if group > 1:
+            k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        return _blocked_attention(q, k, v), jnp.float32(0.0)
+
     if q.shape[1] % KERNEL_BLOCK:
         return blocked(q, k, v)
     fused = lambda q, k, v: (_fused_attention(q, k, v), jnp.float32(1.0))
@@ -627,16 +636,19 @@ def mla_attention(q, k, v):
 
 # -- sparse experts ------------------------------------------------------------
 
-def route(x, w_router, bias, top_k: int, scaling: float):
+def route(x, w_router, bias, top_k: int, scaling: float, eps: float = 0.0):
     """Sigmoid scores over every expert; the top ``top_k`` of score +
-    bias are selected, and weighted by their scores renormalised over
-    the selected set, times ``scaling``. Returns (ids [N, k], weights
-    [N, k]) in float32."""
+    bias are selected, and weighted by their scores alone, renormalised
+    over the selected set (``eps`` added to their sum, where a template's
+    published router has one), times ``scaling``. Returns (ids [N, k],
+    weights [N, k]) in float32. The language-model templates' one router
+    (``lfm2_moe.py`` calls it with a bias that is not zero)."""
     s = jax.nn.sigmoid(jnp.matmul(x.astype(F32), w_router.astype(F32),
                                   precision=jax.lax.Precision.HIGHEST))
     _vals, ids = jax.lax.top_k(s + bias, top_k)
     picked = jnp.take_along_axis(s, ids, axis=-1)
-    return ids, scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    return ids, scaling * picked / (total + eps if eps else total)
 
 
 def expert_layer(x, ids, weights, held: Sequence[int], w_gate, w_up, w_down):
@@ -925,7 +937,80 @@ def blocked_logit_stats(h, head, y, smoothing, block: int = LOSS_BLOCK):
     return ce, hits, n
 
 
-class KimiLinear(JaxModel):
+class SparseExpertLm(JaxModel):
+    """What the language-model templates share of the ``JaxModel`` contract
+    (this file's ``KimiLinear``, ``lfm2_moe.py``'s ``Lfm2Moe``): token ids in,
+    a trial that fills a chip (serial lane, an epoch step by step through one
+    executable), a traced ``label_smoothing``, and a loss and counts taken a
+    block of the sequence at a time. A template's module returns, with
+    ``hidden``, (hidden states after the final norm, the head [D, V], rows
+    each held expert took in each layer, what its fused kernels computed) and
+    lists its layers as ``layer_kinds()`` -> [(mixer, sparse)]; the template
+    names its top-k knob and turns the last of the four into counters."""
+
+    TOP_K_KNOB = "num_experts_per_token"
+
+    @classmethod
+    def packable(cls) -> bool:
+        return False  # one trial fills the chip
+
+    @classmethod
+    def epoch_program(cls) -> bool:
+        return False  # a step of seconds, a compile of minutes: one step program
+
+    def _input_dtype(self):
+        return np.int32
+
+    def _dataset_arch(self, ds):
+        return int(ds.classes), tuple(ds.x.shape[1:])
+
+    def _dynamic_hyper(self, takes_dropout: bool) -> Dict[str, float]:
+        hyper = super()._dynamic_hyper(takes_dropout)
+        hyper["label_smoothing"] = float(self.knobs.get("label_smoothing", 0.0))
+        return hyper
+
+    def _kernel_counts(self, mixers: Sequence[str], fused) -> Dict[str, Any]:
+        """``count.<name>`` metrics of a step: its layers by mixer, and those
+        a fused kernel computed (``fused``: the module's fourth result)."""
+        raise NotImplementedError
+
+    def _loop_fns(self, num_classes, input_shape):
+        """The shared closures, with the templates' loss and counts in
+        place of the ones over whole logits."""
+        fns = super()._loop_fns(num_classes, input_shape)
+        module = fns["module"]
+        sparse = np.array([sp for _m, sp in module.layer_kinds()])
+        mixers = [mixer for mixer, _sp in module.layer_kinds()]
+        slots = float(self.knobs[self.TOP_K_KNOB]) * sparse.sum()
+
+        def stats(params, batch, train, smoothing):
+            h, head, loads, fused = module.apply({"params": params}, batch["x"],
+                                                 train=train, hidden=True)
+            with jax.named_scope(SCOPE_LM_LOSS):
+                ce, hits, n = blocked_logit_stats(h, head, batch["y"], smoothing)
+            return ce, hits, n, loads, fused
+
+        def loss_fn(params, batch, rng, hyper):
+            ce, hits, n, loads, fused = stats(params, batch, True, hyper["label_smoothing"])
+            n = jnp.maximum(n, 1)
+            loads = loads[sparse].astype(F32)
+            skew = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads, axis=-1), 1.0)
+            return ce / n, {
+                "acc": hits / n,
+                "count.moe.slots_held": loads.sum(),
+                "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
+                "gauge.moe.held_load_max_over_mean": skew.mean(),
+                **self._kernel_counts(mixers, fused)}
+
+        def eval_count(params, batch):
+            _ce, hits, n, _loads, _fused = stats(params, batch, False, 0.0)
+            return hits, n
+
+        fns.update(loss_fn=loss_fn, eval_count=eval_count)
+        return fns
+
+
+class KimiLinear(SparseExpertLm):
     """The template. Shape knobs default to a size a CPU trains in
     seconds; a tenant's model file pins them (the benchmark's
     configuration pins the published widths). ``expert_shard`` of
@@ -951,20 +1036,6 @@ class KimiLinear(JaxModel):
             "batch_size": fixed(2), "epochs": FixedKnob(1), "seed": FixedKnob(0),
         }
 
-    @classmethod
-    def packable(cls) -> bool:
-        return False  # one trial fills the chip
-
-    @classmethod
-    def epoch_program(cls) -> bool:
-        return False  # a step of seconds, a compile of minutes: one step program
-
-    def _input_dtype(self):
-        return np.int32
-
-    def _dataset_arch(self, ds):
-        return int(ds.classes), tuple(ds.x.shape[1:])
-
     def module_config(self) -> tuple:
         kn = self.knobs
         per = int(kn["num_experts"]) // int(kn["expert_shards"])
@@ -983,50 +1054,11 @@ class KimiLinear(JaxModel):
     def build_module(self, num_classes, input_shape):
         return _KimiLinear(cfg=self.module_config(), vocab=int(num_classes))
 
-    def _dynamic_hyper(self, takes_dropout: bool) -> Dict[str, float]:
-        hyper = super()._dynamic_hyper(takes_dropout)
-        hyper["label_smoothing"] = float(self.knobs.get("label_smoothing", 0.0))
-        return hyper
-
-    def _loop_fns(self, num_classes, input_shape):
-        """The shared closures, with this template's loss and counts in
-        place of the ones over whole logits."""
-        fns = super()._loop_fns(num_classes, input_shape)
-        module = fns["module"]
-        slots = float(self.knobs["num_experts_per_token"]) * sum(
-            sparse for _m, sparse in module.layer_kinds())
-
-        sparse = np.array([sp for _m, sp in module.layer_kinds()])
-        mixers = [mixer for mixer, _sp in module.layer_kinds()]
-
-        def stats(params, batch, train, smoothing):
-            h, head, loads, fused = module.apply({"params": params}, batch["x"],
-                                                 train=train, hidden=True)
-            with jax.named_scope(SCOPE_LM_LOSS):
-                ce, hits, n = blocked_logit_stats(h, head, batch["y"], smoothing)
-            return ce, hits, n, loads, fused
-
-        def loss_fn(params, batch, rng, hyper):
-            ce, hits, n, loads, fused = stats(params, batch, True, hyper["label_smoothing"])
-            n = jnp.maximum(n, 1)
-            loads = loads[sparse].astype(F32)
-            skew = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads, axis=-1), 1.0)
-            return ce / n, {
-                "acc": hits / n,
-                "count.moe.slots_held": loads.sum(),
-                "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
-                "count.mla.fused": fused[0],
+    def _kernel_counts(self, mixers, fused):
+        return {"count.mla.fused": fused[0],
                 "count.mla.layers": jnp.float32(mixers.count("mla")),
                 "count.kda.fused": fused[1],
-                "count.kda.layers": jnp.float32(mixers.count("kda")),
-                "gauge.moe.held_load_max_over_mean": skew.mean()}
-
-        def eval_count(params, batch):
-            _ce, hits, n, _loads, _fused = stats(params, batch, False, 0.0)
-            return hits, n
-
-        fns.update(loss_fn=loss_fn, eval_count=eval_count)
-        return fns
+                "count.kda.layers": jnp.float32(mixers.count("kda"))}
 
 
 if __name__ == "__main__":

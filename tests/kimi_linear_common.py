@@ -36,14 +36,14 @@ TRAIN = "synthetic://tokens?vocab=256&n=8&len=96&seed=20&follow=0.5"
 VAL = "synthetic://tokens?vocab=256&n=4&len=96&seed=21&follow=0.5"
 
 
-def small_class(cfg, seed=0):
+def small_class(cfg, seed=0, template=K.KimiLinear):
     pinned = {k: v["fixed"] for k, v in cfg["knobs"].items() if "fixed" in v}
     pinned["seed"] = seed
 
-    class Small(K.KimiLinear):
+    class Small(template):
         @staticmethod
         def get_knob_config():
-            base = K.KimiLinear.get_knob_config()
+            base = template.get_knob_config()
             return {k: (FixedKnob(pinned[k], affects_shape=True)
                         if k in pinned and isinstance(base[k], FixedKnob) else base[k])
                     for k in base}
@@ -69,12 +69,12 @@ def f32(monkeypatch):
         yield
 
 
-def program_of(cfg, seed=3, **free):
-    model = small_class(cfg, seed)(**template_knobs(cfg, seed=seed, **free))
+def program_of(cfg, seed=3, template=K.KimiLinear, reference=R, **free):
+    model = small_class(cfg, seed, template)(**template_knobs(cfg, seed=seed, **free))
     model._planned_steps = 4
     fns = model._loop_fns(int(cfg["vocab_size"]), (int(cfg["seq_len"]),))
     _step, init_key = check.trial_keys(seed)
-    return model, fns, fns["init_fn"](init_key), R.init(init_key, cfg)
+    return model, fns, fns["init_fn"](init_key), reference.init(init_key, cfg)
 
 
 def flat(params):

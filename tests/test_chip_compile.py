@@ -232,31 +232,36 @@ def test_stacked_top2_vgg16_serving_forward_compiles_for_v5e(one_chip):
 SCORES = "f32[2,32,256,8192]"  # a block of 256 queries' scores over 8,192 keys, in HBM
 
 
-def _kimi_linear_cell():
-    """(the template pinned to the benchmark cell's published widths,
-    vocabulary, sequence length, batch)."""
+def _pinned_cell(template, config: str, planned_steps: int):
+    """(a language-model template pinned to its benchmark cell's published
+    widths, vocabulary, sequence length, batch)."""
     import json
     from pathlib import Path
 
     from rafiki_tpu.model.knobs import FixedKnob
-    from rafiki_tpu.models import kimi_linear as K
 
     cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs"
-                      / "kimi_linear_48b_a3b_ep32.json").read_text())
+                      / f"{config}.json").read_text())
     pinned = {k: v["fixed"] for k, v in cfg["knobs"].items() if "fixed" in v}
     pinned["seed"] = 0
 
-    class Cell(K.KimiLinear):
+    class Cell(template):
         @staticmethod
         def get_knob_config():
-            base = K.KimiLinear.get_knob_config()
+            base = template.get_knob_config()
             return {k: (FixedKnob(pinned[k], affects_shape=True)
                         if k in pinned and isinstance(base[k], FixedKnob) else base[k])
                     for k in base}
 
     model = Cell(**pinned, learning_rate=1e-3, label_smoothing=0.05)
-    model._planned_steps = 8
+    model._planned_steps = planned_steps
     return model, int(cfg["vocab_size"]), int(cfg["seq_len"]), int(pinned["batch_size"])
+
+
+def _kimi_linear_cell():
+    from rafiki_tpu.models.kimi_linear import KimiLinear
+
+    return _pinned_cell(KimiLinear, "kimi_linear_48b_a3b_ep32", 8)
 
 
 def _kernels(text, prefix):
@@ -388,4 +393,47 @@ def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel
         "splash_mha_fwd_no_residuals"]
     assert _chunk_kernels(evaluate.as_text()) == ["kda_chunk_fwd"] * 4
     assert not _scan_leftovers(evaluate.as_text())
+    assert _peak_bytes(evaluate) < HBM_BYTES
+
+
+# -- the second language model (ISSUE 31) ---------------------------------------
+
+def _lfm2_moe_cell():
+    from rafiki_tpu.models.lfm2_moe import Lfm2Moe
+
+    return _pinned_cell(Lfm2Moe, "lfm2_8b_a1b_ep4", 16)
+
+
+def test_the_second_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel(one_chip):
+    """The second cell's whole step program (569 M parameters, Adam, every
+    layer recomputed) and its evaluation step at the published widths:
+    lowered for the described chip, the grouped-query attention takes the
+    fused kernel at 8 key/value heads of 64 for 32 query heads (the forward
+    kernel twice, the two backward kernels once), each call under the
+    ``lfm2.attn`` scope that ``attn_device_share.lm`` reads, and no float32
+    score array of a whole row in HBM; both fit the chip."""
+    from rafiki_tpu.ops.train import Program, _ShardingPlan
+
+    model, vocab, T, B = _lfm2_moe_cell()
+    fns = model._loop_fns(vocab, (T,))
+    prog = Program(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                   fns["optimizer"], _ShardingPlan.build(None),
+                   eval_count=fns["eval_count"])
+    state = _serial_state(fns, prog.init, one_chip)
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(state[0])) == 568_647_936
+    batch = {k: _spec((B, T), jnp.int32, one_chip) for k in ("x", "y")}
+    step = prog.train_step.lower(state, batch).compile()
+    text = step.as_text()
+    kernels = _attention_kernels(text)
+    assert sorted(name.split(".")[0] for name, _op in kernels) == [
+        "splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals",
+        "splash_mha_fwd_residuals", "splash_mha_fwd_residuals"]
+    assert all("/lfm2.attn/" in op for _name, op in kernels), kernels
+    assert "f32[2,32,8192,8192]" not in text and "[2,32,256,8192]" not in text
+    # as compiled for PR 31: 3.79 GB of temporaries beside 6.82 GB of state
+    assert step.memory_analysis().temp_size_in_bytes < 4.2e9
+    assert _peak_bytes(step) < HBM_BYTES
+    evaluate = prog.eval_step.lower(state[0], batch).compile()
+    assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
+        "splash_mha_fwd_no_residuals"]
     assert _peak_bytes(evaluate) < HBM_BYTES
