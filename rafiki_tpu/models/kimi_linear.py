@@ -39,8 +39,13 @@ What the template adds to the zoo, by mechanism:
 * ``expert_layer``: the router keeps every output and its experts per
   token; the layer is *told which expert ids it holds* and computes
   their part of the result for the tokens routed to them, without a
-  capacity limit: the routed rows are sorted by expert and multiplied as
-  one ragged product. What absent experts would add is left out.
+  capacity limit: all tokens x k token-choices are sorted once by the
+  expert they name, the live ones first, and those are taken a slab of
+  rows at a time, only while there are live ones (gather, three ragged
+  products, a scatter-add by token), so the layer moves the rows its
+  experts hold and not every token once a choice. What absent experts
+  would add is left out. ``count.moe.slots_held`` of
+  ``count.moe.rows_room`` is the live share of the rows the slabs moved.
 * the loss and the score are taken a block of the sequence at a time, so
   the ``[tokens, vocab]`` logits never exist whole; every layer is
   recomputed in the backward pass (``nn.remat``).
@@ -651,54 +656,119 @@ def route(x, w_router, bias, top_k: int, scaling: float, eps: float = 0.0):
     return ids, scaling * picked / (total + eps if eps else total)
 
 
+def _slab_rows(pairs: int, experts: int, width: int) -> int:
+    """Rows of one slab of the sorted token-choices. A slab reads its
+    experts' ``experts x width`` columns of weights whatever it holds, so its
+    rows are of that order (half of it: 4,096 at 8 experts 1,024 wide) and
+    the layer costs what its live rows do plus at most one slab."""
+    return max(1, min(pairs, experts * width // 2))
+
+
+def _slab(rows, w, sizes, wg, wu, wd):
+    """One slab: ``rows`` [S, D] of tokens in expert order, their router
+    weights ``w`` [S], the rows each expert holds of them ``sizes`` [E].
+    A ragged product leaves the rows past its groups as they were in
+    memory (zero on the CPU, anything on the TPU), in its result and in
+    the gradient it hands back for its left operand alike. Every such
+    operand and result is therefore selected by ``live`` (a select, not
+    a product: it stops a NaN), so that neither a value nor a gradient
+    of a token-choice that names an absent expert comes from there."""
+    rd = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                           preferred_element_type=F32)
+    live = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(live, rows, 0)
+    h = jnp.where(live, jax.nn.silu(rd(xs, wg)) * rd(xs, wu), 0.0).astype(BF16)
+    return jnp.where(live, rd(h, wd), 0.0) * w[:, None]
+
+
+def _slabs(x, weights, order, sizes, w_gate, w_up, w_down):
+    """What both passes over the live slabs share: how many there are (a
+    device value), the bfloat16 operands, and ``take(s)`` -> the flat
+    token-choices of slab ``s``, their tokens, rows and router weights and
+    the slab's own share of the groups."""
+    k = weights.shape[1]
+    S = _slab_rows(order.shape[0], *w_gate.shape[::2])
+    order = jnp.pad(order, (0, -order.shape[0] % S))
+    ends = jnp.cumsum(sizes)
+    xb, wflat = x.astype(BF16), weights.reshape(-1)
+
+    def take(s):
+        pairs = jax.lax.dynamic_slice(order, (s * S,), (S,))
+        tokens = pairs // k
+        cut = lambda edge: jnp.clip(edge - s * S, 0, S)
+        return (pairs, tokens, jnp.take(xb, tokens, axis=0), jnp.take(wflat, pairs),
+                cut(ends) - cut(ends - sizes))
+
+    return (ends[-1] + S - 1) // S, take, tuple(w.astype(BF16) for w in (w_gate, w_up, w_down))
+
+
+@jax.custom_vjp
+def _held_rows(x, weights, order, sizes, w_gate, w_up, w_down):
+    """The routed result [N, D] of the sorted token-choices ``order`` [N k],
+    of which the first ``sum(sizes)`` are live, grouped by expert: a slab of
+    them at a time while there are live ones (a ``while`` on the device: no
+    slab without a live row runs, in either pass, and none is cut). The
+    backward pass is written out because a loop of that kind has no
+    transpose, and so that a slab's rows are recomputed there and never
+    kept: what lives between the passes is the arguments."""
+    n, take, ws = _slabs(x, weights, order, sizes, w_gate, w_up, w_down)
+
+    def one(s, y):
+        _pairs, tokens, rows, w, held = take(s)
+        # a token's k choices name distinct experts: they simply sum
+        return y.at[tokens].add(_slab(rows, w, held, *ws))
+
+    return jax.lax.fori_loop(0, n, one, jnp.zeros(x.shape, F32))
+
+
+def _held_rows_fwd(*args):
+    return _held_rows(*args), args
+
+
+def _held_rows_bwd(args, dy):
+    x, weights, _order, _sizes, *experts = args
+    n, take, ws = _slabs(*args)
+
+    def one(s, grads):
+        dx, dw, *dws = grads
+        pairs, tokens, rows, w, held = take(s)
+        _out, vjp = jax.vjp(lambda rows, w, *ws: _slab(rows, w, held, *ws), rows, w, *ws)
+        d_rows, d_w, *d_ws = vjp(jnp.take(dy, tokens, axis=0))
+        return (dx.at[tokens].add(d_rows.astype(F32)), dw.at[pairs].add(d_w),
+                *(a + d.astype(F32) for a, d in zip(dws, d_ws)))
+
+    zeros = lambda a: jnp.zeros(a.shape, F32)
+    dx, dw, *dws = jax.lax.fori_loop(
+        0, n, one, (zeros(x), jnp.zeros((weights.size,), F32), *map(zeros, experts)))
+    return (dx.astype(x.dtype), dw.reshape(weights.shape).astype(weights.dtype), None, None,
+            *(d.astype(w.dtype) for d, w in zip(dws, experts)))
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
 def expert_layer(x, ids, weights, held: Sequence[int], w_gate, w_up, w_down):
     """The held experts' part of the routed result. ``x`` [N, D]; ``ids``
     and ``weights`` [N, k] over all experts; ``held`` the expert ids whose
     weights ``w_gate``/``w_up`` [E, D, F] and ``w_down`` [E, F, D] are here,
-    in that order. One choice of every token at a time (k passes, each
-    recomputed in the backward pass): the tokens are sorted by the local
-    expert their choice names, those that name an absent one last and in
-    no group, and the three products run ragged over the groups. A pass
-    has room for every token, so there is no capacity and no dropped
-    token, and a ragged product costs what its groups hold. Returns
-    (y [N, D], rows a held expert took [E])."""
-    N, D = x.shape
+    in that order. All N k token-choices are sorted ONCE by the local
+    expert each names, those that name an absent one last and in no group;
+    the live ones come first, grouped by expert. They are then taken a slab
+    of ``_slab_rows`` at a time, only while there are live ones: a slab
+    gathers its tokens' rows and router weights, runs the three products
+    ragged over its share of the groups, and adds the weighted rows into the
+    result by token. So the layer moves the rows its experts hold (and at
+    most a slab beyond them), not every token once a choice; there is no
+    capacity and no dropped token at any routing, skewed or full routing
+    runs more slabs. Returns (y [N, D] float32, rows a held expert took [E])."""
     E = len(held)
     top = int(max(held)) + 1
     local = np.full((top + 1,), E, np.int32)               # E: absent
     local[list(held)] = np.arange(E)
-    local = jnp.asarray(local)
-    xb = x.astype(BF16)
-    wg, wu, wd = (w.astype(BF16) for w in (w_gate, w_up, w_down))
-
-    @jax.checkpoint
-    def one_choice(carry, choice):
-        y, load = carry
-        ids_j, w_j = choice
-        expert = jnp.take(local, jnp.minimum(ids_j, top))
-        order = jnp.argsort(expert)
-        sizes = jnp.sum(expert[:, None] == jnp.arange(E)[None, :], axis=0,
-                        dtype=jnp.int32)
-        rd = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
-                               preferred_element_type=F32)
-        # A ragged product leaves the rows past its groups as they were in
-        # memory (zero on the CPU, anything on the TPU), in its result and in
-        # the gradient it hands back for its left operand alike. Every such
-        # operand and result is therefore selected by ``live`` (a select, not
-        # a product: it stops a NaN), so that neither a value nor a gradient
-        # of a token whose choice is absent comes from there.
-        live = (jnp.arange(N) < jnp.sum(sizes))[:, None]
-        xs = jnp.where(live, jnp.take(xb, order, axis=0), 0)
-        h = jnp.where(live, jax.nn.silu(rd(xs, wg)) * rd(xs, wu), 0.0).astype(BF16)
-        out = jnp.where(live, rd(h, wd), 0.0)
-        # back in token order, then the router's weight (absent: no row, 0)
-        out = jnp.take(out, jnp.argsort(order), axis=0) * w_j[:, None]
-        return (y + out, load + sizes), None
-
-    (y, load), _ = jax.lax.scan(
-        one_choice, (jnp.zeros((N, D), F32), jnp.zeros((E,), jnp.int32)),
-        (ids.T, weights.T))
-    return y, load
+    expert = jnp.take(jnp.asarray(local), jnp.minimum(ids, top)).reshape(-1)
+    order = jnp.argsort(expert)                            # stable: by expert, then by token
+    sizes = jnp.sum(expert[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
+    return _held_rows(x, weights, order, sizes, w_gate, w_up, w_down), sizes
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -981,7 +1051,7 @@ class SparseExpertLm(JaxModel):
         module = fns["module"]
         sparse = np.array([sp for _m, sp in module.layer_kinds()])
         mixers = [mixer for mixer, _sp in module.layer_kinds()]
-        slots = float(self.knobs[self.TOP_K_KNOB]) * sparse.sum()
+        top_k, width = int(self.knobs[self.TOP_K_KNOB]), int(self.knobs["moe_intermediate_size"])
 
         def stats(params, batch, train, smoothing):
             h, head, loads, fused = module.apply({"params": params}, batch["x"],
@@ -995,10 +1065,14 @@ class SparseExpertLm(JaxModel):
             n = jnp.maximum(n, 1)
             loads = loads[sparse].astype(F32)
             skew = jnp.max(loads, axis=-1) / jnp.maximum(jnp.mean(loads, axis=-1), 1.0)
+            slots = top_k * batch["x"].size
+            slab = _slab_rows(slots, loads.shape[-1], width)
             return ce / n, {
                 "acc": hits / n,
                 "count.moe.slots_held": loads.sum(),
-                "count.moe.slots_total": jnp.float32(slots * batch["x"].size),
+                # the rows of the slabs the expert layers ran: held, rounded up a layer
+                "count.moe.rows_room": (jnp.ceil(loads.sum(axis=-1) / slab) * slab).sum(),
+                "count.moe.slots_total": jnp.float32(slots * sparse.sum()),
                 "gauge.moe.held_load_max_over_mean": skew.mean(),
                 **self._kernel_counts(mixers, fused)}
 

@@ -25,6 +25,7 @@ def test_a_trial_trains_scores_counts_and_reloads(cfg):
     counters = telemetry.snapshot()["counters"]
     assert counters["moe.slots_total"] == 4 * 2 * 96 * 4 * 4
     assert 0 < counters["moe.slots_held"] < counters["moe.slots_total"]
+    assert counters["moe.slots_held"] <= counters["moe.rows_room"] < counters["moe.slots_total"]
     assert telemetry.get_gauge("moe.held_load_max_over_mean") >= 1.0
     assert (counters["attn.layers"], counters["attn.fused"]) == (4, 0)   # a step each; the CPU
     assert counters["conv.layers"] == 20
