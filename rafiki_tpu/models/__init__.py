@@ -18,6 +18,11 @@ examples/models/ (SURVEY.md §2 "Example models", unverified paths):
                  attention with rotary positions over sparse experts
                  selected by score plus a bias; it shares the router and
                  the expert layer with KimiLinear
+  Ouro         — no reference analog: the zoo's third language model, a
+                 dense stack of sandwich-normed layers run several times
+                 with shared weights, a loss at every pass weighted by a
+                 learned exit gate; it shares the attention, the blocked
+                 loss and the language-model base with the other two
 """
 
 from rafiki_tpu.models.ff import FeedForward
@@ -41,6 +46,7 @@ MODEL_REGISTRY = {
     "Transformer": ("rafiki_tpu.models.transformer", "Transformer"),
     "KimiLinear": ("rafiki_tpu.models.kimi_linear", "KimiLinear"),
     "Lfm2Moe": ("rafiki_tpu.models.lfm2_moe", "Lfm2Moe"),
+    "Ouro": ("rafiki_tpu.models.ouro", "Ouro"),
 }
 
 

@@ -979,11 +979,17 @@ class _KimiLinear(nn.Module):
         return _mm(h[:, -1], head, "bd,dv->bv")
 
 
-def blocked_logit_stats(h, head, y, smoothing, block: int = LOSS_BLOCK):
+def blocked_logit_stats(h, head, y, smoothing, block: int = LOSS_BLOCK,
+                        per_token: bool = False):
     """Over all positions, a block of the sequence at a time (each block
-    recomputed in the backward pass): summed cross entropy against ``y``
-    with label smoothing (a traced scalar), hits of the argmax, and the
-    count of labelled positions (``y`` >= 0)."""
+    recomputed in the backward pass, so the ``[tokens, vocab]`` logits never
+    exist whole): summed cross entropy against ``y`` with label smoothing (a
+    traced scalar), hits of the argmax, and the count of labelled positions
+    (``y`` >= 0). ``per_token``: the same three before they are summed, each
+    [B, T] (the cross entropy nought where ``y`` is not a label), for an
+    objective that weights a token's cross entropy by something that has a
+    gradient of its own (``ouro.py``'s exit distribution). The language-model
+    templates' one loss."""
     T = h.shape[1]
 
     @jax.checkpoint
@@ -996,29 +1002,36 @@ def blocked_logit_stats(h, head, y, smoothing, block: int = LOSS_BLOCK):
         smooth = -jnp.mean(logp, axis=-1)
         ce = (1.0 - smoothing) * nll + smoothing * smooth
         hit = (jnp.argmax(logits, axis=-1) == safe) & mask
+        if per_token:
+            return jnp.where(mask, ce, 0.0), hit, mask
         return (jnp.where(mask, ce, 0.0).sum(), hit.sum().astype(jnp.int32),
                 mask.sum().astype(jnp.int32))
 
+    if per_token:
+        # One block after the other (``lax.map``): unrolled, the compiler is free
+        # to hold several blocks' logits at once (11.5 GB of temporaries against
+        # 8.6 in ``ouro.py``'s step, compiled for a described v5e: PERF.md, PR 33).
+        # A length the block does not divide is one block.
+        blocks = T // block if T % block == 0 else 1
+        first = lambda a: jnp.moveaxis(a.reshape((a.shape[0], blocks, -1) + a.shape[2:]), 1, 0)
+        parts = jax.lax.map(lambda xs: one(*xs), (first(h), first(y)))
+        return tuple(jnp.moveaxis(a, 0, 1).reshape(y.shape) for a in parts)
+    cut = lambda start: (h[:, start: start + block], y[:, start: start + block])
     ce = jnp.zeros((), F32)
     hits = n = jnp.zeros((), jnp.int32)
     for start in range(0, T, block):
-        c1, h1, n1 = one(h[:, start: start + block], y[:, start: start + block])
+        c1, h1, n1 = one(*cut(start))
         ce, hits, n = ce + c1, hits + h1, n + n1
     return ce, hits, n
 
 
-class SparseExpertLm(JaxModel):
-    """What the language-model templates share of the ``JaxModel`` contract
-    (this file's ``KimiLinear``, ``lfm2_moe.py``'s ``Lfm2Moe``): token ids in,
-    a trial that fills a chip (serial lane, an epoch step by step through one
-    executable), a traced ``label_smoothing``, and a loss and counts taken a
-    block of the sequence at a time. A template's module returns, with
-    ``hidden``, (hidden states after the final norm, the head [D, V], rows
-    each held expert took in each layer, what its fused kernels computed) and
-    lists its layers as ``layer_kinds()`` -> [(mixer, sparse)]; the template
-    names its top-k knob and turns the last of the four into counters."""
-
-    TOP_K_KNOB = "num_experts_per_token"
+class BlockedLossLm(JaxModel):
+    """What every language-model template shares of the ``JaxModel`` contract
+    (this file's ``KimiLinear``, ``lfm2_moe.py``'s ``Lfm2Moe``, ``ouro.py``'s
+    ``Ouro``): token ids in, a trial that fills a chip (serial lane, an epoch
+    step by step through one executable), a traced ``label_smoothing``, and a
+    loss and counts of its own, taken a block of the sequence at a time
+    (``blocked_logit_stats``), in place of the ones over whole logits."""
 
     @classmethod
     def packable(cls) -> bool:
@@ -1039,16 +1052,35 @@ class SparseExpertLm(JaxModel):
         hyper["label_smoothing"] = float(self.knobs.get("label_smoothing", 0.0))
         return hyper
 
+    def _loss_and_count(self, module):
+        """(``loss_fn(params, batch, rng, hyper)`` -> (loss, metrics),
+        ``eval_count(params, batch)`` -> (hits, labelled positions)) of the
+        template's module."""
+        raise NotImplementedError
+
+    def _loop_fns(self, num_classes, input_shape):
+        fns = super()._loop_fns(num_classes, input_shape)
+        loss_fn, eval_count = self._loss_and_count(fns["module"])
+        fns.update(loss_fn=loss_fn, eval_count=eval_count)
+        return fns
+
+
+class SparseExpertLm(BlockedLossLm):
+    """What the sparse-expert templates share beyond ``BlockedLossLm``: the
+    held experts' loads as counters. A template's module returns, with
+    ``hidden``, (hidden states after the final norm, the head [D, V], rows
+    each held expert took in each layer, what its fused kernels computed) and
+    lists its layers as ``layer_kinds()`` -> [(mixer, sparse)]; the template
+    names its top-k knob and turns the last of the four into counters."""
+
+    TOP_K_KNOB = "num_experts_per_token"
+
     def _kernel_counts(self, mixers: Sequence[str], fused) -> Dict[str, Any]:
         """``count.<name>`` metrics of a step: its layers by mixer, and those
         a fused kernel computed (``fused``: the module's fourth result)."""
         raise NotImplementedError
 
-    def _loop_fns(self, num_classes, input_shape):
-        """The shared closures, with the templates' loss and counts in
-        place of the ones over whole logits."""
-        fns = super()._loop_fns(num_classes, input_shape)
-        module = fns["module"]
+    def _loss_and_count(self, module):
         sparse = np.array([sp for _m, sp in module.layer_kinds()])
         mixers = [mixer for mixer, _sp in module.layer_kinds()]
         top_k, width = int(self.knobs[self.TOP_K_KNOB]), int(self.knobs["moe_intermediate_size"])
@@ -1080,8 +1112,7 @@ class SparseExpertLm(JaxModel):
             _ce, hits, n, _loads, _fused = stats(params, batch, False, 0.0)
             return hits, n
 
-        fns.update(loss_fn=loss_fn, eval_count=eval_count)
-        return fns
+        return loss_fn, eval_count
 
 
 class KimiLinear(SparseExpertLm):

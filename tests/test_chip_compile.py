@@ -437,3 +437,51 @@ def test_the_second_language_models_step_and_evaluation_compile_for_v5e_with_the
     assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
         "splash_mha_fwd_no_residuals"]
     assert _peak_bytes(evaluate) < HBM_BYTES
+
+
+# -- the third language model (ISSUE 33) ----------------------------------------
+
+def _ouro_cell():
+    from rafiki_tpu.models.ouro import Ouro
+
+    return _pinned_cell(Ouro, "ouro_2_6b_pp8", 4)
+
+
+def test_the_looped_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel(one_chip):
+    """The third cell's whole step program (510 M parameters, Adam, every
+    layer visit recomputed) and its evaluation step at the published widths:
+    lowered for the described chip, the attention takes the fused kernel at
+    16 heads of 128, and the program holds the STACK ONCE: six layers' calls
+    inside the loop over the four passes (forward and its ``nn.remat``: twelve
+    forward kernels; six of each backward kernel), not twenty-four layers
+    unrolled; every call under the ``ouro.attn`` scope that
+    ``attn_device_share.lm`` reads; both fit the chip. (24 fused calls a
+    step forward is the loop's trip count times these six: on the chip
+    ``count.attn.fused`` reads it.)"""
+    from rafiki_tpu.ops.train import Program, _ShardingPlan
+
+    model, vocab, T, B = _ouro_cell()
+    assert (vocab, T, B) == (49152, 8192, 2)
+    fns = model._loop_fns(vocab, (T,))
+    prog = Program(fns["init_fn"], fns["apply_eval"], fns["loss_fn"],
+                   fns["optimizer"], _ShardingPlan.build(None),
+                   eval_count=fns["eval_count"])
+    state = _serial_state(fns, prog.init, one_chip)
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(state[0])) == 509_661_185
+    batch = {k: _spec((B, T), jnp.int32, one_chip) for k in ("x", "y")}
+    step = prog.train_step.lower(state, batch).compile()
+    text = step.as_text()
+    kernels = _attention_kernels(text)
+    assert sorted(name.split(".")[0] for name, _op in kernels) == (
+        ["splash_mha_dkv_no_residuals"] * 6 + ["splash_mha_dq_no_residuals"] * 6
+        + ["splash_mha_fwd_residuals"] * 12)
+    assert all("/ouro.attn/" in op and "/while/body/" in op for _name, op in kernels), kernels
+    assert "f32[2,16,8192,8192]" not in text and "[2,16,256,8192]" not in text
+    # as compiled for PR 33: 7.85 GB of temporaries beside 6.12 GB of state (11.3
+    # with float32 copies of the 24 visits' inputs; 11.5 with the loss unrolled)
+    assert step.memory_analysis().temp_size_in_bytes < 8.3e9
+    assert _peak_bytes(step) < HBM_BYTES
+    evaluate = prog.eval_step.lower(state[0], batch).compile()
+    assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
+        "splash_mha_fwd_no_residuals"] * 6
+    assert _peak_bytes(evaluate) < HBM_BYTES
