@@ -777,8 +777,28 @@ class JaxModel(BaseModel):
             loop.release_to_host(cast)
 
     def dump_parameters(self) -> bytes:
+        parts = self._parameter_parts()
+        with telemetry.span("persist.write", leaf=True):
+            blob = b"".join(parts)
+        telemetry.inc("persist.host_copy_bytes", len(blob))
+        return blob
+
+    def dump_parameter_parts(self) -> Optional[list]:
+        """The blob ``dump_parameters`` returns, as the buffers whose
+        join it is (the pickle's opcodes round the fetched leaves' own
+        memory, not copied): what ``ParamsStore.save_parts`` hashes and
+        writes as it passes. The device's side of the dump, under
+        ``persist.fetch``, is over when this returns; the parts alias
+        the host leaves. None for a subclass with a ``dump_parameters``
+        of its own: its bytes are the blob."""
+        if type(self).dump_parameters is not JaxModel.dump_parameters:
+            return None
+        return self._parameter_parts()
+
+    def _parameter_parts(self) -> list:
         from rafiki_tpu.config import get_config
-        from rafiki_tpu.utils.serial import dump_pytree
+        from rafiki_tpu.utils.serial import (
+            parts_nbytes, pickled_dict_parts, pytree_parts)
 
         if self._loop is None:
             raise RuntimeError("No parameters to dump: model not trained/loaded")
@@ -797,28 +817,21 @@ class JaxModel(BaseModel):
             if not host_copy.fetched:
                 with telemetry.span("persist.fetch", leaf=True):
                     host_copy.fetch()
-            with telemetry.span("persist.write", leaf=True):
-                return self._params_blob(dump_pytree(
-                    host_copy.member(index), cast_f32_to_bf16=False))
-        telemetry.inc("persist.members_fetched_alone")
-        cast = get_config().serving_params_dtype == "bfloat16"
-        # The device's side of a dump of one's own: the leaves (sliced
-        # out of the pack where ``params`` is a slice view's), the cast,
-        # device to host.
-        with telemetry.span("persist.fetch", leaf=True):
-            packed = dump_pytree(self._loop.params, cast_f32_to_bf16=cast)
-        # The host's side starts here; the worker's ``params_store.save``
-        # is a second ``persist.write``.
-        with telemetry.span("persist.write", leaf=True):
-            return self._params_blob(packed)
-
-    def _params_blob(self, packed: bytes) -> bytes:
-        telemetry.inc("persist.blob_bytes", len(packed))
-        return pickle.dumps({
-            "arch": self._arch,
-            "packed": packed,
-            "dataset_meta": _portable_meta(self._dataset_meta),
-        })
+            packed = pytree_parts(host_copy.member(index), cast_f32_to_bf16=False)
+        else:
+            telemetry.inc("persist.members_fetched_alone")
+            cast = get_config().serving_params_dtype == "bfloat16"
+            # The device's side of a dump of one's own: the leaves (sliced
+            # out of the pack where ``params`` is a slice view's), the cast,
+            # device to host. The host's side, ``persist.write``, is the
+            # caller's: the store's pass over the parts, or the join.
+            with telemetry.span("persist.fetch", leaf=True):
+                packed = pytree_parts(self._loop.params, cast_f32_to_bf16=cast)
+        telemetry.inc("persist.blob_bytes", parts_nbytes(packed))
+        return pickled_dict_parts(
+            {"arch": self._arch,
+             "dataset_meta": _portable_meta(self._dataset_meta)},
+            "packed", packed)
 
     def load_parameters(self, blob: bytes) -> None:
         import jax

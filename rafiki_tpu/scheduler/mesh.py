@@ -57,7 +57,8 @@ from rafiki_tpu.scheduler.wal import SweepWal
 from rafiki_tpu.store import MetaStore, ParamsStore
 from rafiki_tpu.utils.events import events
 from rafiki_tpu.worker.train import (InProcAdvisorHandle, PackAborted,
-                                     PackedTrialRunner, TrainWorker)
+                                     PackedTrialRunner, TrainWorker,
+                                     save_parameters)
 
 
 class _WalAdvisorHandle:
@@ -387,8 +388,7 @@ class GroupHandle:
             # evaluate/dump_parameters behave exactly post-serial-train).
             try:
                 score = float(model.evaluate(self.job["val_dataset_uri"]))
-                blob = model.dump_parameters()
-                params_id = self.params_store.save(blob)
+                params_id = save_parameters(self.params_store, model)
                 self.store.mark_trial_as_completed(tid, score, params_id)
                 self.params_store.delete_checkpoints(tid)  # superseded
                 events.emit("trial_completed", trial_id=tid, score=score,

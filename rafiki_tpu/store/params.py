@@ -7,9 +7,21 @@ one file per params id with sha256 integrity, plus a mid-trial
 checkpoint namespace (``<trial>/ckpt_<step>``) the reference lacks —
 used by the worker for resumable long trials.
 
-Blobs are whatever the model's ``dump_parameters`` returned (for
-JaxModel: a pickled dict holding flax msgpack bytes — a host-side
-pytree snapshot, cheap to write from one `jax.device_get`).
+Blobs are whatever the model's ``dump_parameters`` returned. For a
+``JaxModel`` that is a pickle of ``{"arch", "dataset_meta", "packed"}``
+whose ``"packed"`` is one RTPK1 buffer (utils/serial.py: a JSON header
+and the leaves' raw little-endian memory, bfloat16 by default). The
+stored file is ``<64 hex of sha256(blob)>\n<blob>``.
+
+How it gets there: a dump is streamed. ``save_parts`` takes the blob as
+the buffers whose join it is (``JaxModel.dump_parameter_parts``: the
+pickle's few opcodes round the fetched leaves' own memory), hashes and
+writes each as it passes, then writes the digest into the line kept
+free at the head: between the leaves and the page cache no copy of the
+blob is made. ``save(blob)`` is the same road for one part. The file is
+``fsync``ed before it is renamed into place, so a params id names
+durable bytes or nothing; a write that fails takes its temporary file
+with it.
 
 Chaos hook: ``store.params_write`` fires before each write — ``delay``
 simulates a slow disk, ``error`` a failing one (raises
@@ -24,9 +36,12 @@ import hashlib
 import os
 import uuid
 from pathlib import Path
-from typing import List, Optional
+from typing import Iterable, List, Optional, Union
 
 from rafiki_tpu.chaos import hook as _chaos
+
+# sha256 in hex and the newline ``load`` splits at.
+_DIGEST_LINE = 65
 
 
 class ParamsStore:
@@ -45,16 +60,31 @@ class ParamsStore:
         return self._dir / f"{params_id}.params"
 
     def save(self, blob: bytes, params_id: Optional[str] = None) -> str:
+        return self.save_parts((blob,), params_id)
+
+    def save_parts(self, parts: Iterable[Union[bytes, memoryview]],
+                   params_id: Optional[str] = None) -> str:
+        """Store the blob that is the join of ``parts`` without joining
+        them: one pass, each part hashed and written as it is."""
         params_id = params_id or uuid.uuid4().hex
         _chaos("store.params_write", params_id)  # delay=slow disk, error=failed write
         path = self._path(params_id)
         tmp = path.with_suffix(".tmp")
-        digest = hashlib.sha256(blob).hexdigest().encode()
-        with open(tmp, "wb") as f:
-            f.write(digest + b"\n" + blob)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)  # atomic: readers never see a torn file
+        sha = hashlib.sha256()
+        try:
+            with open(tmp, "wb") as f:
+                f.seek(_DIGEST_LINE)  # the digest is known last and stands first
+                for part in parts:
+                    sha.update(part)
+                    f.write(part)
+                f.seek(0)
+                f.write(sha.hexdigest().encode() + b"\n")
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, path)  # atomic: readers never see a torn file
+        except BaseException:
+            tmp.unlink(missing_ok=True)
+            raise
         return params_id
 
     def load(self, params_id: str) -> bytes:

@@ -20,7 +20,16 @@ PR 25, PERF.md section 6). So:
     (``PackedTrainLoop.stage_host_params``) and every member is dumped
     from ``host_leaf[i]`` views of that copy — the same bytes, since
     the f32 -> bf16 rounding is elementwise — with no device work;
-  * the host side writes raw little-endian buffers — no msgpack.
+  * the host side copies nothing it need not: a blob is handed on as
+    its PARTS (``pytree_parts``: magic, header length, header, then
+    each leaf's own memory as a contiguous ``memoryview``: no
+    ``tobytes``, no msgpack). ``dump_pytree`` is their join, for a
+    caller that wants bytes; the worker's dump hands the parts to
+    ``ParamsStore.save_parts``, which hashes and writes each as it
+    passes, so between the fetched leaves and the page cache no copy
+    of the blob is made (ISSUE 34: the joined road cost 6 s a
+    gigabyte, four whole-blob copies before the first byte reached
+    the disk).
 
 The bf16 cast is the DEFAULT for serving blobs and loses nothing:
 model templates compute in bfloat16 on the MXU anyway (every
@@ -38,7 +47,8 @@ concatenated little-endian buffers. Readable with numpy alone.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, Optional
+import pickle
+from typing import Any, Dict, Iterable, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +56,9 @@ import ml_dtypes
 import numpy as np
 
 MAGIC = b"RTPK1\n"
+
+# What a part of a blob may be: anything ``hashlib`` and a file take.
+Buffer = Union[bytes, memoryview]
 
 _EXTRA_DTYPES = {
     "bfloat16": ml_dtypes.bfloat16,
@@ -82,11 +95,22 @@ def _start_host_copies(leaves) -> None:
             v.copy_to_host_async()
 
 
-def dump_pytree(tree: Any, cast_f32_to_bf16: bool = True) -> bytes:
-    """Serialize a pytree of arrays: raw buffers, pipelined transfers.
-    A numpy leaf is written as it is (unless ``jnp.asarray`` would narrow
-    its 64-bit dtype): with ``cast_f32_to_bf16`` off a tree that is
-    already on the host touches no device."""
+def _leaf_bytes(v) -> memoryview:
+    """A fetched leaf's own memory, read-only and flat. A host leaf that
+    is already contiguous (every ``host_leaf[i]`` view of a stacked copy
+    is) is not copied; a custom dtype (bfloat16) has no buffer format of
+    its own, hence the view as bytes."""
+    a = np.ascontiguousarray(np.asarray(v))
+    return memoryview(a.reshape(-1).view(np.uint8)).toreadonly()
+
+
+def pytree_parts(tree: Any, cast_f32_to_bf16: bool = True) -> List[Buffer]:
+    """An RTPK1 blob as the buffers whose join it is: the layout's one
+    definition. Pipelined transfers; returns once every leaf is on the
+    host. A numpy leaf is handed on as it is (unless ``jnp.asarray``
+    would narrow its 64-bit dtype): with ``cast_f32_to_bf16`` off a tree
+    that is already on the host touches no device and is not copied.
+    The parts alias the leaves: they hold while the caller keeps them."""
     if cast_f32_to_bf16:
         tree = _cast_tree_bf16(tree)
     items = _flat_items(tree)
@@ -100,9 +124,38 @@ def dump_pytree(tree: Any, cast_f32_to_bf16: bool = True) -> bytes:
         spec.append({"k": k, "shape": list(v.shape), "dtype": v.dtype.name})
     header = json.dumps(spec).encode()
     _start_host_copies(leaves)
-    parts = [MAGIC, len(header).to_bytes(8, "little"), header]
-    parts.extend(np.ascontiguousarray(np.asarray(v)).tobytes() for v in leaves)
-    return b"".join(parts)
+    parts: List[Buffer] = [MAGIC, len(header).to_bytes(8, "little"), header]
+    parts.extend(_leaf_bytes(v) for v in leaves)
+    return parts
+
+
+def parts_nbytes(parts: Iterable[Buffer]) -> int:
+    return sum(memoryview(p).nbytes for p in parts)
+
+
+def dump_pytree(tree: Any, cast_f32_to_bf16: bool = True) -> bytes:
+    """Serialize a pytree of arrays to one RTPK1 ``bytes``: the join of
+    :func:`pytree_parts` (one copy of the blob)."""
+    return b"".join(pytree_parts(tree, cast_f32_to_bf16))
+
+
+def pickled_dict_parts(small: Dict[str, Any], key: str,
+                       value_parts: List[Buffer]) -> List[Buffer]:
+    """The parts of a pickle that loads to ``{**small, key: <the join of
+    value_parts, one bytes>}``, the value's parts handed on as they are.
+    ``pickle`` writes the small entries; the large one is put into the
+    finished dict by hand, four opcodes: its key, BINBYTES8 with the
+    length, (the bytes,) SETITEM, STOP. Protocol 3 for pickle's share
+    because it frames nothing and numbers its memo slots in the stream,
+    so what is appended disturbs neither; the header says 4, where
+    BINBYTES8 belongs and frames are optional."""
+    head = pickle.dumps(small, protocol=3)  # PROTO 3, the dict, STOP
+    k = key.encode()
+    return [pickle.PROTO + b"\x04" + head[2:-1]
+            + pickle.BINUNICODE + len(k).to_bytes(4, "little") + k
+            + pickle.BINBYTES8 + parts_nbytes(value_parts).to_bytes(8, "little"),
+            *value_parts,
+            pickle.SETITEM + pickle.STOP]
 
 
 class StackedHostCopy:

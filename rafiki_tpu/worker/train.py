@@ -466,13 +466,11 @@ class TrainWorker:
         async persistence is on)."""
         t0 = time.monotonic()
         try:
-            # Encloses the leaf phases persist.fetch / persist.write
-            # (JaxModel.dump_parameters has the first of each) and
-            # persist.mark; on the saver thread when persistence is async.
+            # Encloses the leaf phases persist.fetch (the model's),
+            # persist.write and persist.mark; on the saver thread when
+            # persistence is async.
             with telemetry.span("trial.persist", trial_id=tid):
-                blob = model.dump_parameters()
-                with telemetry.span("persist.write", leaf=True):
-                    params_id = self.params_store.save(blob)
+                params_id = save_parameters(self.params_store, model)
                 with telemetry.span("persist.mark", leaf=True):
                     self.store.mark_trial_as_completed(tid, score, params_id)
                     self.params_store.delete_checkpoints(tid)  # superseded
@@ -1056,6 +1054,24 @@ class PackedTrialRunner:
         # Charged to the bound pack entity (the sink runs inside it).
         # lint: disable=RF007 — checkpoint_s ledger charge, not a span
         ledger.add("checkpoint_s", time.monotonic() - t0)
+
+
+def save_parameters(params_store: ParamsStore, model: BaseModel) -> str:
+    """A trained model's parameters into the store, durable on return;
+    the params id. Streamed where the model offers its blob in parts
+    (``JaxModel.dump_parameter_parts``): one pass from the fetched
+    leaves to the file, hashed while it is written, no copy of the blob.
+    A model with only ``dump_parameters() -> bytes`` takes the bytes
+    road. ``persist.write`` is the host's side of either."""
+    offer = getattr(model, "dump_parameter_parts", None)
+    parts = offer() if offer is not None else None
+    if parts is None:
+        telemetry.inc("persist.buffered")
+        parts = (model.dump_parameters(),)
+    else:
+        telemetry.inc("persist.streamed")
+    with telemetry.span("persist.write", leaf=True):
+        return params_store.save_parts(parts)
 
 
 def _round_copy_of(model: BaseModel):

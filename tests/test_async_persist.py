@@ -53,10 +53,11 @@ def test_sync_and_async_agree(env):
 def test_persist_failure_marks_trial_errored(env, monkeypatch):
     store, params, job, sub, cls, advisor = env
 
-    def boom(blob, params_id=None):
+    def boom(parts, params_id=None):
         raise OSError("disk full")
 
-    monkeypatch.setattr(params, "save", boom)
+    # every road into the store ends here: ``save`` is one part of it
+    monkeypatch.setattr(params, "save_parts", boom)
     worker = TrainWorker(store, params, sub["id"], cls, advisor,
                          TRAIN, VAL, {"MODEL_TRIAL_COUNT": 1},
                          async_persist=True)

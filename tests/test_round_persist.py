@@ -264,7 +264,7 @@ def test_without_a_saver_the_rounds_copy_serves_the_workers_own_thread(tmp_path)
             assert r["thread"] == me
             by_name[r["name"]] = by_name.get(r["name"], 0) + 1
     assert by_name == {"persist.dispatch": 2, "persist.fetch": 2,
-                       "persist.write": 4 * PACK, "persist.mark": 2 * PACK}
+                       "persist.write": 2 * PACK, "persist.mark": 2 * PACK}
 
 
 # -- (c) what keeps the per-member fetch ----------------------------------------
@@ -330,17 +330,17 @@ def _wait_for(cond, timeout=60.0):
 
 
 def _blocking_store(tmp_path):
-    """A ParamsStore whose ``save`` says when it is entered and then waits
+    """A ParamsStore whose ``save_parts`` says when it is entered and then waits
     for the gate."""
     from rafiki_tpu.store import ParamsStore
 
     gate, entered = threading.Event(), threading.Event()
 
     class Blocking(ParamsStore):
-        def save(self, blob, params_id=None):
+        def save_parts(self, parts, params_id=None):
             entered.set()
             assert gate.wait(120)
-            return super().save(blob, params_id)
+            return super().save_parts(parts, params_id)
 
     return Blocking(tmp_path / "params"), gate, entered
 
@@ -425,11 +425,11 @@ def test_a_failed_copy_or_write_errors_the_trials_it_touched_and_no_others(
     saves = []
 
     class Failing(ParamsStore):
-        def save(self, blob, params_id=None):
+        def save_parts(self, parts, params_id=None):
             saves.append(params_id)
             if what == "write" and len(saves) == 3:
                 raise OSError("disk full")
-            return super().save(blob, params_id)
+            return super().save_parts(parts, params_id)
 
     store, params, worker, _adv, sub = _mk_worker(
         tmp_path, 2 * PACK, params_store=Failing(tmp_path / "params"))
@@ -456,9 +456,9 @@ def test_flush_returns_when_every_row_of_the_round_is_durable(tmp_path):
     from rafiki_tpu.worker.train import PackedTrialRunner
 
     class Slow(ParamsStore):
-        def save(self, blob, params_id=None):
+        def save_parts(self, parts, params_id=None):
             time.sleep(0.15)
-            return super().save(blob, params_id)
+            return super().save_parts(parts, params_id)
 
     store, params, worker, _adv, sub = _mk_worker(
         tmp_path, PACK, params_store=Slow(tmp_path / "params"))
