@@ -8,7 +8,8 @@ series into many (per-id cardinality explosions) or rename it out from
 under every consumer; nothing fails, the SLO just stops seeing data.
 
 The rule: the name argument to ``telemetry.inc`` / ``observe`` /
-``set_gauge`` / ``add_gauge`` / ``span`` must be *statically known* —
+``set_gauge`` / ``add_gauge`` / ``span`` / ``record_span`` must be
+*statically known* —
 a string literal, an UPPER_CASE registry constant (bare or dotted),
 or a conditional between such values (the train loop's
 ``"train.cold_epoch_s" if cold else "train.epoch_s"`` split names two
@@ -32,7 +33,8 @@ from rafiki_tpu.analysis.checkers._ast_util import dotted_name
 _EXEMPT_PREFIXES = ("rafiki_tpu.telemetry", "rafiki_tpu.obs")
 
 #: Telemetry entry points whose first argument is a series name.
-_METHODS = ("inc", "observe", "set_gauge", "add_gauge", "span")
+_METHODS = ("inc", "observe", "set_gauge", "add_gauge", "span",
+            "record_span")
 
 
 def _metric_call_names(tree: ast.Module) -> Set[str]:

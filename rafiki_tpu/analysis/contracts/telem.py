@@ -1,6 +1,6 @@
 """Telemetry-name registry extraction.
 
-Write sites are ``telemetry.inc/set_gauge/add_gauge/observe/span``
+Write sites are ``telemetry.inc/set_gauge/add_gauge/observe/span/record_span``
 calls with a constant first argument; an f-string name records a
 *dynamic site* with its constant prefix (``gateway.shed_{reason}`` →
 ``gateway.shed_``). Collector registrations
@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from rafiki_tpu.analysis.checkers._ast_util import dotted_name
 
 _APIS = {"inc": "counter", "set_gauge": "gauge", "add_gauge": "gauge",
-         "observe": "histogram", "span": "span"}
+         "observe": "histogram", "span": "span", "record_span": "span"}
 _SAN_RE = re.compile(r"[^a-zA-Z0-9_]")
 _TYPE_LINE = re.compile(r"^# TYPE rafiki_(\w+) (counter|gauge|summary)$")
 _BACKTICK = re.compile(r"`([^`]+)`")

@@ -40,6 +40,8 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from rafiki_tpu import telemetry
+
 
 @dataclass
 class Dataset:
@@ -402,7 +404,14 @@ class DatasetUtils:
                 if ds is not None:
                     self._cache[key] = self._cache.pop(key)  # refresh LRU
                     return ds
-        ds = self._load(uri)
+        # A miss is set-up's: generating or decoding the data set (a plain
+        # span: the leaf phase that first asks for the data set encloses
+        # it); a hit records nothing.
+        scheme = urllib.parse.urlparse(uri).scheme or "file"
+        with telemetry.span("data.load", uri_scheme=scheme) as sp:
+            ds = self._load(uri)
+            sp.tags["bytes"] = int(sum(
+                a.nbytes for a in (ds.x, ds.y, ds.mask) if a is not None))
         if key is not None:
             with self._lock:
                 self._cache[key] = ds

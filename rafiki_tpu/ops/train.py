@@ -27,6 +27,7 @@ TPU-shaped:
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 import threading
 import time
@@ -48,6 +49,101 @@ from rafiki_tpu.obs.health import sentinel as _sentinel
 # span then shows on its thread's line of a running jax.profiler trace;
 # with no session open TraceAnnotation is a flag test.
 telemetry.install_annotator(jax.profiler.TraceAnnotation)
+
+# -- compile stages as span records (docs/telemetry.md) ------------------------
+#
+# jax times every stage of a compile and says which function it was for
+# (``jax.monitoring``, from dispatch.py, pjit.py, pxla.py, compiler.py).
+# Each duration event becomes a finished span record, a child of whatever
+# span is open on the thread: the listeners fire on jax's slow path alone
+# (a trace, a lowering, a compile); a call through an executable or a
+# jitted function's C++ fast path emits nothing. Trace events NEST (an
+# inner jitted function is traced inside its caller's trace and reported
+# first), so a sum of ``compile.*`` seconds is the union of their intervals
+# on a thread (``_union_s``), never the sum of their durations. A stage
+# under a millisecond (jax re-traces hundreds of tiny inner functions in
+# one set-up) is folded into the open span's one ``compile.small`` record.
+
+_JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAXPR_TO_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _CompileWatch(threading.local):
+    """A thread's view of the compile in flight."""
+
+    #: what the persistent cache said of it (the cache's events precede the
+    #: ``backend_compile_duration`` they belong to, on the same thread):
+    #: the tags of the next ``compile.backend`` record
+    cache: Optional[Dict[str, Any]] = None
+    #: (start, end) on time.monotonic() of each stage recorded while an
+    #: epoch span is open on this thread (``_compile_seconds``); else None
+    stages: Optional[list] = None
+
+
+_watch = _CompileWatch()
+
+
+def _on_compile_event(event: str, **_kw: Any) -> None:
+    if event == _CACHE_ASKED:
+        _watch.cache = {"cache_hit": False}
+    elif event == _CACHE_HIT:
+        _watch.cache = {"cache_hit": True}
+
+
+def _on_compile_duration(event: str, secs: float, **kw: Any) -> None:
+    if event == _CACHE_RETRIEVAL:
+        if _watch.cache is not None:
+            _watch.cache["retrieval_s"] = round(float(secs), 6)
+        return
+    fun = str(kw.get("fun_name", ""))
+    if event == _JAXPR_TRACE:
+        telemetry.record_span("compile.trace", secs, "compile.small", fun=fun)
+    elif event == _JAXPR_TO_MLIR:
+        telemetry.record_span("compile.lower", secs, "compile.small", fun=fun)
+    elif event == _BACKEND_COMPILE:
+        cache, _watch.cache = _watch.cache or {}, None
+        telemetry.record_span("compile.backend", secs, "compile.small",
+                              fun=fun, **cache)
+    else:
+        return
+    if _watch.stages is not None:
+        end = time.monotonic()
+        _watch.stages.append((end - max(0.0, float(secs)), end))
+
+
+def _union_s(intervals) -> float:
+    """Seconds that the (start, end) intervals cover, each counted once."""
+    total, at = 0.0, float("-inf")
+    for lo, hi in sorted(intervals):
+        total += max(0.0, hi - max(lo, at))
+        at = max(at, hi)
+    return total
+
+
+@contextlib.contextmanager
+def _compile_seconds(span: telemetry.Span):
+    """Tags an open epoch span ``compile_s``: the seconds of the compile
+    stages recorded on this thread while it was open. 0.0 in every epoch
+    of a window; in a cold epoch, how much of the span was not training."""
+    _watch.stages = []
+    try:
+        yield
+    finally:
+        stages, _watch.stages = _watch.stages, None
+        span.tags["compile_s"] = round(_union_s(stages), 6)
+
+
+# Once a process, however often this module is imported or reloaded: a
+# reload runs this body again in the namespace that holds the flag, and the
+# pair registered first goes on reading that namespace.
+if not globals().get("_compile_listeners_registered"):
+    jax.monitoring.register_event_listener(_on_compile_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    _compile_listeners_registered = True
 
 Batch = Dict[str, np.ndarray]
 Params = Any
@@ -534,7 +630,11 @@ def get_device_dataset(dataset) -> Tuple[jax.Array, jax.Array]:
     cache = dataset.__dict__.setdefault("_device_arrays", {})
     key = _default_device_key()
     if key not in cache:
-        cache[key] = (jnp.asarray(dataset.x), jnp.asarray(dataset.y))
+        # A miss is set-up's (a plain span: it happens inside the leaf
+        # phase that first touches the data set); a hit records nothing.
+        with telemetry.span("data.upload",
+                            bytes=int(dataset.x.nbytes + dataset.y.nbytes)):
+            cache[key] = (jnp.asarray(dataset.x), jnp.asarray(dataset.y))
     return cache[key]
 
 
@@ -608,12 +708,17 @@ class TrainLoop:
         if initial_state is not None:
             self.state = self.plan.put_state(initial_state)
             return
-        hyper_dev = {k: jnp.float32(v) for k, v in (hyper or {}).items()}
-        rng = jax.random.PRNGKey(seed)
-        rng, init_rng = jax.random.split(rng)
-        params, opt_state = self.program.init(init_rng)
-        self.state = self.plan.put_state(
-            (params, opt_state, jnp.zeros((), jnp.int32), rng, hyper_dev))
+        # The host's side of a serial trial's initialisation (the packed
+        # lane's ``trial_pack.init`` is this and more): tracing, building
+        # or loading and enqueueing the init program. Nothing waits for
+        # the device here: its side is inside the first step's wait.
+        with telemetry.span("train.init", leaf=True):
+            hyper_dev = {k: jnp.float32(v) for k, v in (hyper or {}).items()}
+            rng = jax.random.PRNGKey(seed)
+            rng, init_rng = jax.random.split(rng)
+            params, opt_state = self.program.init(init_rng)
+            self.state = self.plan.put_state(
+                (params, opt_state, jnp.zeros((), jnp.int32), rng, hyper_dev))
 
     @property
     def params(self):
@@ -714,7 +819,7 @@ class TrainLoop:
             # The epoch as the host waits for it, dispatch to metrics on
             # the host: the serial lane's twin of ``train.packed_epoch``.
             with telemetry.span("train.epoch", leaf=True, cold=cold,
-                                steps=n_steps):
+                                steps=n_steps) as sp, _compile_seconds(sp):
                 self.state, metrics = self.program.train_epoch(
                     self.state, X, Y, idx, poison)
                 out = {k: float(v) for k, v in jax.device_get(metrics).items()}
@@ -729,8 +834,8 @@ class TrainLoop:
         # One-slot prefetch (double buffering): batch i+1's host→device
         # put is issued right after step i is DISPATCHED — jit dispatch
         # is async, so the transfer overlaps the device step instead of
-        # serializing with it (train.host_feed_s stops adding to
-        # train.step_s on datasets that miss the device-resident path).
+        # serializing with it (on datasets that miss the device-resident
+        # path the feed stops adding to the step).
         batches = dataset.batches(batch_size, shuffle=True, seed=epoch_seed,
                                   drop_remainder=True)
 
@@ -750,7 +855,8 @@ class TrainLoop:
         cold = not getattr(self, "_warm", False)
         step = self.program.train_step
         steps = []      # each step's metric dict, device scalars
-        with telemetry.span("train.epoch", leaf=True, cold=cold, steps=n_steps):
+        with telemetry.span("train.epoch", leaf=True, cold=cold,
+                            steps=n_steps) as sp, _compile_seconds(sp):
             while dev_batch is not None:
                 if poison is not None and count < n_steps:
                     pz = jnp.float32(poison[count])
@@ -865,9 +971,7 @@ class TrainLoop:
         self._warm = True
         telemetry.observe("train.cold_epoch_s" if cold else "train.epoch_s", dt)
         if feed_s > 0.0:
-            telemetry.inc("train.host_feed_s", feed_s)
             ledger.add("feed_s", feed_s)
-        telemetry.inc("train.step_s", max(dt - feed_s, 0.0))
         ledger.add("compile_s" if cold else "step_s", max(dt - feed_s, 0.0))
         # Perf sentinel: step sampling + EWMA/MAD anomaly detection per
         # program, and an SLO evaluation tick (both cheap when idle).
@@ -1066,8 +1170,11 @@ class PackedTrainLoop:
 
         # Per-trial rng derivation matches TrainLoop exactly: key(seed)
         # split once; row 0 carries on as the step rng, row 1 seeds init.
-        keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-        split = jax.vmap(jax.random.split)(keys)  # (k, 2, key)
+        # A member at a time, as TrainLoop does it: through a vmap over the
+        # keys jax traced the split anew in every round (a compile stage
+        # in each hand-over of a window); the bits are the same.
+        split = jnp.stack([jax.random.split(jax.random.PRNGKey(int(s)))
+                           for s in seeds])  # (k, 2, key)
         rngs, init_rngs = split[:, 0], split[:, 1]
         params, opt_state = self.program.init(init_rngs)
         hyper_dev = {name: jnp.asarray([float(h[name]) for h in hypers],
@@ -1221,7 +1328,7 @@ class PackedTrainLoop:
         # device_get is the dispatch). A leaf phase: the hand-over
         # between two rounds ends where this span starts.
         with telemetry.span("train.packed_epoch", leaf=True, cold=cold,
-                            k=self.k, steps=n_steps):
+                            k=self.k, steps=n_steps) as sp, _compile_seconds(sp):
             # Same in-timed-region chaos site as the serial loop: injected
             # delays here are visible to the anomaly detector.
             from rafiki_tpu import chaos as _chaos

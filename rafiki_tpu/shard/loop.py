@@ -315,7 +315,6 @@ class ShardedTrainLoop:
         self._warm = True
         telemetry.observe("train.cold_epoch_s" if cold else "train.epoch_s",
                           dt)
-        telemetry.inc("train.step_s", dt)
         telemetry.set_gauge("shard.group_width", self.width)
         ledger.add("compile_s" if cold else "step_s", dt)
         profiler.note_epoch(self._perf_key, dt, cold=cold, kind="sharded",

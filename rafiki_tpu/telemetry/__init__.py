@@ -15,6 +15,8 @@ Write API (cheap, thread-safe, never raises into callers):
     with span("trial.train", trial_id=t): ...   nestable timed phases
     with span("trial.log", leaf=True): ...      a leaf phase: also an event
                                          in a running profiler trace
+    record_span("compile.lower", 0.8, fun=f)    a phase that ended now and
+                                         that someone else timed
 
 Read API:
     snapshot()        -> one JSON-able dict (registry + span aggregates
@@ -30,14 +32,14 @@ the reader's job (each process exposes/dumps its own state).
 from __future__ import annotations
 
 import json
-from typing import Any, Callable, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 from rafiki_tpu.telemetry.registry import Histogram, Registry
 from rafiki_tpu.telemetry.spans import Span, Tracer
 
 __all__ = [
     "Histogram", "Registry", "Span", "Tracer",
-    "inc", "set_gauge", "add_gauge", "observe", "span",
+    "inc", "set_gauge", "add_gauge", "observe", "span", "record_span",
     "get_counter", "get_gauge", "get_registry", "get_tracer",
     "register_collector", "snapshot", "span_records", "dump_jsonl",
     "reset", "current_span_id", "install_annotator",
@@ -78,6 +80,15 @@ def span(name: str, leaf: bool = False, **tags: Any) -> Span:
     """``leaf=True``: a phase that encloses no other leaf phase on its
     thread; it is bridged into a running profiler trace (spans.py)."""
     return _tracer.span(name, leaf=leaf, **tags)
+
+
+def record_span(name: str, dur_s: float, small: Optional[str] = None,
+                **tags: Any) -> None:
+    """A span record for a phase of ``dur_s`` seconds that ends now, a
+    child of the span open on this thread (spans.py); never a leaf. With
+    ``small``, a phase under a millisecond is added to the open span's one
+    record of that name instead."""
+    _tracer.record_span(name, dur_s, small, **tags)
 
 
 def install_annotator(factory) -> None:

@@ -10,6 +10,19 @@ Costs: two ``time`` calls plus one locked deque append per span — spans
 wrap phases (compile, epoch, persist, gather), never per-step device
 work.
 
+A phase that someone else timed and that has already ended (a stage of a
+jax compile, reported by ``jax.monitoring`` with its duration) is written
+with ``Tracer.record_span(name, dur_s, **tags)``: the same record, ring,
+aggregates and journal, its start reckoned back from now on both clocks,
+its parent the span open on the calling thread. Such a record is never a
+leaf (a finished phase cannot enter the profiler) and may lie inside one.
+jax reports a thousand traces of a few microseconds in one set-up (every
+inner jitted function of a model, once a caller), so a phase under a
+millisecond that is given a ``small`` name is not written by itself: the
+span open on its thread adds it up and writes ONE record of that name,
+tagged ``n``, when it closes. Nothing is dropped and the ring keeps its
+room for phases.
+
 Leaf phases and the profiler's clock: ``span(name, leaf=True)`` marks a
 phase that encloses no other leaf phase on its thread. Such a span also
 enters the process's *annotator* (``Tracer.install_annotator``) — in a
@@ -46,7 +59,7 @@ class Span:
 
     __slots__ = ("name", "tags", "leaf", "_tracer", "_t0", "_start_ts",
                  "_parent", "_span_id", "_parent_id", "_trace_id",
-                 "_annotation")
+                 "_annotation", "_small")
 
     def __init__(self, tracer: "Tracer", name: str, tags: Dict[str, Any],
                  leaf: bool = False):
@@ -55,6 +68,9 @@ class Span:
         self.tags = tags
         self.leaf = leaf
         self._annotation = None
+        # name -> [count, seconds] of the finished phases under a
+        # millisecond folded into this span (``Tracer.record_span``)
+        self._small: Optional[Dict[str, List[float]]] = None
         self._t0 = 0.0
         self._start_ts = 0.0
         self._parent: Optional[str] = None
@@ -69,10 +85,10 @@ class Span:
     def __enter__(self) -> "Span":
         stack = self._tracer._stack()
         if stack:
-            self._parent, self._parent_id = stack[-1]
+            self._parent, self._parent_id = stack[-1].name, stack[-1]._span_id
         self._span_id = uuid.uuid4().hex[:16]
         self._trace_id = _trace_context.current_trace_id()
-        stack.append((self.name, self._span_id))
+        stack.append(self)
         self._start_ts = time.time()
         self._t0 = time.monotonic()
         annotator = self._tracer._annotator if self.leaf else None
@@ -95,9 +111,16 @@ class Span:
                 pass
         dur = time.monotonic() - self._t0
         stack = self._tracer._stack()
-        if stack and stack[-1][0] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
-        self._tracer._record(self, dur, error=exc_type is not None)
+        for name, (n, secs) in (self._small or {}).items():
+            self._tracer._record(
+                name, self._start_ts + dur - secs, self._t0 + dur - secs, secs,
+                self.name, self._span_id, self._trace_id, {"n": int(n)})
+        self._tracer._record(
+            self.name, self._start_ts, self._t0, dur, self._parent,
+            self._parent_id, self._trace_id, self.tags, span_id=self._span_id,
+            leaf=self.leaf, error=exc_type is not None)
         return False  # never swallow
 
 
@@ -120,8 +143,11 @@ class Tracer:
         None uninstalls."""
         self._annotator = factory
 
+    #: a finished phase shorter than this is folded (``record_span``)
+    _SMALL_S = 1e-3
+
     def _stack(self) -> list:
-        """Per-thread stack of (name, span_id) tuples for open spans."""
+        """Per-thread stack of the open spans."""
         stack = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
@@ -131,46 +157,81 @@ class Tracer:
         """The innermost open span's id on this thread (trace
         propagation: the bus envelope carries it as parent_span)."""
         stack = self._stack()
-        return stack[-1][1] if stack else None
+        return stack[-1]._span_id if stack else None
 
     def span(self, name: str, leaf: bool = False, **tags: Any) -> Span:
         return Span(self, name, tags, leaf)
 
-    def _record(self, span: Span, dur_s: float, error: bool) -> None:
+    def record_span(self, name: str, dur_s: float,
+                    small: Optional[str] = None, **tags: Any) -> None:
+        """A finished phase of ``dur_s`` seconds that ends now: a span
+        record like any other (module docstring). Such records may nest
+        (jax traces an inner jitted function inside its caller's trace and
+        reports the inner one first), so whoever sums them by name takes
+        the union of their intervals on a thread, never the sum of their
+        durations. With ``small`` given, a phase under a millisecond inside
+        an open span is added to that span's one record of that name.
+        Never raises into its caller."""
+        try:
+            dur = max(0.0, float(dur_s))
+            stack = self._stack()
+            if small is not None and dur < self._SMALL_S and stack:
+                top = stack[-1]
+                if top._small is None:
+                    top._small = {}
+                fold = top._small.setdefault(small, [0, 0.0])
+                fold[0] += 1
+                fold[1] += dur
+                return
+            parent, parent_id = ((stack[-1].name, stack[-1]._span_id)
+                                 if stack else (None, None))
+            # (the phase's START on both clocks, reckoned back from now)
+            ts, mono = time.time(), time.monotonic()
+            self._record(name, ts - dur, mono - dur, dur, parent, parent_id,
+                         _trace_context.current_trace_id(), tags)
+        except Exception:
+            pass
+
+    def _record(self, name: str, start_ts: float, t0: float, dur_s: float,
+                parent: Optional[str], parent_id: Optional[str],
+                trace_id: Optional[str], tags: Dict[str, Any],
+                span_id: Optional[str] = None, leaf: bool = False,
+                error: bool = False) -> None:
+        span_id = span_id or uuid.uuid4().hex[:16]
         rec: Dict[str, Any] = {
             "type": "span",
-            "name": span.name,
-            "ts": span._start_ts,
+            "name": name,
+            "ts": start_ts,
             # ``ts`` is time.time() and may step; ``mono`` is the same
             # start on time.monotonic(), so one thread's spans can be
             # laid end to end (``mono`` + ``dur_s`` is the end).
-            "mono": span._t0,
+            "mono": t0,
             "thread": threading.current_thread().name,
             "dur_s": round(dur_s, 6),
-            "parent": span._parent,
-            "span_id": span._span_id,
-            "parent_id": span._parent_id,
+            "parent": parent,
+            "span_id": span_id,
+            "parent_id": parent_id,
         }
-        if span._trace_id:
-            rec["trace_id"] = span._trace_id
-        if span.leaf:
+        if trace_id:
+            rec["trace_id"] = trace_id
+        if leaf:
             rec["leaf"] = True
-        if span.tags:
-            rec["tags"] = span.tags
+        if tags:
+            rec["tags"] = tags
         if error:
             rec["error"] = True
         # Durable copy first (journal has its own lock; no-op when the
         # process hasn't opted in via RAFIKI_LOG_DIR).
         _journal.record(
-            "span", span.name, ts=span._start_ts,
-            dur_s=rec["dur_s"], span_id=span._span_id,
-            parent_id=span._parent_id, trace_id=span._trace_id,
-            **({"tags": span.tags} if span.tags else {}),
+            "span", name, ts=start_ts,
+            dur_s=rec["dur_s"], span_id=span_id,
+            parent_id=parent_id, trace_id=trace_id,
+            **({"tags": tags} if tags else {}),
             **({"error": True} if error else {}))
         with self._lock:
-            agg = self._agg.get(span.name)
+            agg = self._agg.get(name)
             if agg is None:
-                self._agg[span.name] = [1, dur_s, dur_s, dur_s]
+                self._agg[name] = [1, dur_s, dur_s, dur_s]
             else:
                 agg[0] += 1
                 agg[1] += dur_s

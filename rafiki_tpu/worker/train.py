@@ -271,7 +271,10 @@ class TrainWorker:
                     ledger.entity(f"trial:{tid}"), \
                     logger.capture(sink), self._device_scope(), \
                     self._profile_scope(tid):
-                with telemetry.span("trial.build", trial_id=tid):
+                # A leaf, except where a checkpoint's restore builds the
+                # loop inside it (``train.init`` is the leaf then).
+                with telemetry.span("trial.build", leaf=not resume,
+                                    trial_id=tid):
                     model = self.model_class(**knobs)
                     if self.devices is not None and len(self.devices) > 1 and hasattr(model, "set_mesh"):
                         from rafiki_tpu.parallel.mesh import data_parallel_mesh

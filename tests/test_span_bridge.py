@@ -21,6 +21,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from sweep_common import run_sweep
+
 from rafiki_tpu import telemetry
 from rafiki_tpu.telemetry.spans import Tracer
 
@@ -139,26 +141,6 @@ def test_the_train_path_installs_the_profilers_annotation():
 
 # -- a packed sweep under the profiler ----------------------------------------
 
-MODEL_SRC = b"""
-from rafiki_tpu.model.base import JaxModel
-from rafiki_tpu.model.knobs import FixedKnob, FloatKnob
-from rafiki_tpu.models.ff import _Mlp
-
-class BridgeFF(JaxModel):
-    @staticmethod
-    def get_knob_config():
-        return {
-            "learning_rate": FloatKnob(1e-3, 3e-2, is_exp=True),
-            "batch_size": FixedKnob(64),
-            "epochs": FixedKnob(1),
-            "seed": FixedKnob(0),
-        }
-
-    def build_module(self, num_classes, input_shape):
-        return _Mlp(hidden_layers=1, hidden_units=32, num_classes=num_classes)
-"""
-TRAIN = "synthetic://images?classes=4&n=256&w=8&h=8&c=1&seed=0"
-VAL = "synthetic://images?classes=4&n=128&w=8&h=8&c=1&seed=1"
 PACK, ROUNDS = 4, 3
 
 #: The hand-over phases of the worker's thread (ISSUE 25's table, and the
@@ -178,38 +160,15 @@ def sweep(tmp_path_factory):
     """Three packed rounds of four through ``LocalScheduler`` (the
     benchmark's entry), traced as the benchmark traces: the python tracer
     off, the host tracer at its default."""
-    import jax
+    return run_sweep(tmp_path_factory.mktemp("bridge"), PACK * ROUNDS, PACK,
+                     traced=True)
 
-    from rafiki_tpu.config import Config, get_config, set_config
-    from rafiki_tpu.scheduler import LocalScheduler
-    from rafiki_tpu.store import MetaStore, ParamsStore
 
-    work = tmp_path_factory.mktemp("bridge")
-    prev = get_config()
-    set_config(Config(data_dir=work / "data").ensure_dirs())
-    store = MetaStore(work / "meta.sqlite3")
-    params = ParamsStore(work / "params")
-    model = store.create_model("BridgeFF", "IMAGE_CLASSIFICATION", None,
-                               MODEL_SRC, "BridgeFF")
-    job = store.create_train_job("bridge", "IMAGE_CLASSIFICATION", None,
-                                 TRAIN, VAL,
-                                 {"MODEL_TRIAL_COUNT": PACK * ROUNDS})
-    store.create_sub_train_job(job["id"], model["id"])
-    telemetry.reset()
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    jax.profiler.start_trace(str(work / "trace"), profiler_options=opts)
-    try:
-        result = LocalScheduler(store, params).run_train_job(
-            job["id"], n_workers=1, advisor_kind="gp", trial_pack=PACK)
-    finally:
-        jax.profiler.stop_trace()
-        set_config(prev)
-    records = telemetry.span_records()
-    store.close()
-    assert result.status == "COMPLETED"
-    assert [t["status"] for t in result.trials] == ["COMPLETED"] * (PACK * ROUNDS)
-    return {"records": records, "trace_dir": str(work / "trace")}
+@pytest.fixture(scope="module")
+def serial_sweep(tmp_path_factory):
+    """Three serial trials through the same entry (ISSUE 35: the serial
+    lane's leaves ``train.init`` and ``trial.build`` belong to the walk)."""
+    return run_sweep(tmp_path_factory.mktemp("bridge-serial"), 3, 1)
 
 
 def _bench_module(name):
@@ -245,8 +204,17 @@ def test_every_phase_of_the_table_was_recorded_on_its_thread(sweep):
     assert by_thread[workers[0]] == WORKER_PHASES
 
 
-def test_no_leaf_phase_nests_in_another_on_its_thread(sweep):
-    records = sweep["records"]
+@pytest.mark.parametrize("lane", ["sweep", "serial_sweep"])
+def test_no_leaf_phase_nests_in_another_on_its_thread(lane, request):
+    records = request.getfixturevalue(lane)["records"]
+    if lane == "serial_sweep":
+        leaves = {r["name"] for r in records if r.get("leaf")}
+        assert {"train.init", "trial.build", "train.epoch",
+                "trial.evaluate"} <= leaves
+        # finished phases (a compile stage, a data set's load) are never
+        # leaves, wherever they lie
+        assert not [r["name"] for r in records if r.get("leaf")
+                    and r["name"].startswith(("compile.", "data."))]
     by_id = {r["span_id"]: r for r in records}
     for r in records:
         if not r.get("leaf"):
@@ -345,6 +313,8 @@ def test_packed_epoch_is_recorded_after_its_metrics_are_on_the_host(
     (span,) = [r for r in telemetry.span_records()
                if r["name"] == "train.packed_epoch"]
     assert span["leaf"] is True
+    # (``compile_s``, ISSUE 35: the compile stages inside this cold epoch)
+    assert 0 < span["tags"].pop("compile_s") <= span["dur_s"] + 1e-3
     assert span["tags"] == {"cold": True, "k": 2, "steps": 4}
     # the observers are handed the epoch the span measured, not its enqueue
     (dt, kw), = noted
