@@ -95,6 +95,8 @@ KERNEL_BLOCK = 1024  # queries and keys of one tile of the fused attention kerne
 KDA_GROUP = 16       # chunks whose insides exist at one time
 KDA_KERNEL_CHUNK = 64    # the chunk and the head width (keys and values alike)
 KDA_KERNEL_WIDTH = 128   # that the fused chunk kernel is written for
+KDA_BRANCH_BLOCK = 1024  # tokens of a head's block in the branch kernels
+KDA_BRANCH_HALO = 16     # rows read on either side of it: a bfloat16 tile
 BF16 = jnp.bfloat16
 
 # Named scopes of the block (docs/telemetry.md), beside ops/train.py's
@@ -777,6 +779,214 @@ def swiglu(x, w_gate, w_up, w_down):
     return _mm(h, w_down, "...f,fd->...d")
 
 
+# -- KDA: a branch's tail (convolution, SiLU, l2norm) ------------------------------
+
+def _branch_tail(y, w, heads: int, normed: bool, scale: float):
+    """The ``jax.numpy`` tail of a KDA branch: ``y`` [B, T, heads x d] (the
+    projection), ``w`` [K, heads x d] (the taps) -> [B, T, heads, d] in
+    bfloat16, each head l2-normed and scaled where ``normed``."""
+    B, T, E = y.shape
+    u = jax.nn.silu(causal_conv(y.astype(F32), w)).reshape(B, T, heads, E // heads)
+    return ((l2norm(u) * scale) if normed else u).astype(BF16)
+
+
+def _branch_taps(win, w, rows: int, first: int):
+    """``causal_conv`` on ``rows`` rows of a VMEM window whose row ``first``
+    holds the first row's own token (the rows before it its history): the
+    taps in ``causal_conv``'s order, the oldest first."""
+    K = w.shape[0]
+    u = win[pl.ds(first - K + 1, rows), :] * w[0: 1]
+    for j in range(1, K):
+        u = u + win[pl.ds(first - K + 1 + j, rows), :] * w[j: j + 1]
+    return u
+
+
+def _branch_forward_kernel(y_ref, before_ref, w_ref, o_ref, win, *, normed, scale):
+    """A grid step of ``_branch_kernel_forward``: a head's block of tokens,
+    with the ``KDA_BRANCH_HALO`` rows before it as its history (none in a
+    sequence's first block)."""
+    N, halo = y_ref.shape[1], KDA_BRANCH_HALO
+    win[pl.ds(0, halo), :] = before_ref[0].astype(F32)
+    win[pl.ds(halo, N), :] = y_ref[0].astype(F32)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        win[pl.ds(0, halo), :] = jnp.zeros((halo, win.shape[1]), F32)
+
+    s = jax.nn.silu(_branch_taps(win, w_ref[...], N, halo))
+    if normed:
+        s = s * jax.lax.rsqrt(jnp.sum(s * s, axis=-1, keepdims=True) + L2_EPS) * scale
+    o_ref[0] = s.astype(o_ref.dtype)
+
+
+def _branch_backward_kernel(y_ref, before_ref, after_ref, ct_ref, ct_after_ref, w_ref,
+                            dy_ref, dw_ref, win, d_win, *, normed, scale):
+    """A grid step of ``_branch_kernel_backward``: the block's forward values
+    again, and those of the ``KDA_BRANCH_HALO`` rows after it (whose
+    gradients reach back into the block through the taps), then the tail
+    transposed: the projection's gradient, and the taps' summed over the
+    block's rows."""
+    N, halo, K = y_ref.shape[1], KDA_BRANCH_HALO, w_ref.shape[0]
+    rows = N + halo
+    win[pl.ds(0, halo), :] = before_ref[0].astype(F32)
+    win[pl.ds(halo, N), :] = y_ref[0].astype(F32)
+    win[pl.ds(halo + N, halo), :] = after_ref[0].astype(F32)
+    d_win[pl.ds(0, N), :] = ct_ref[0].astype(F32)
+    d_win[pl.ds(N, halo), :] = ct_after_ref[0].astype(F32)
+    zeros = jnp.zeros((halo, win.shape[1]), F32)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        win[pl.ds(0, halo), :] = zeros
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        d_win[pl.ds(N, halo), :] = zeros
+
+    w = w_ref[...]
+    u = _branch_taps(win, w, rows, halo)
+    g = d_win[...]
+    sig = jax.nn.sigmoid(u)
+    if normed:                        # o = s r scale, r = (sum s^2 + eps)^-1/2
+        s = u * sig
+        z = jnp.sum(s * s, axis=-1, keepdims=True) + L2_EPS
+        r, g = jax.lax.rsqrt(z), g * scale
+        g = g * r - s * (jnp.sum(g * s, axis=-1, keepdims=True) * r / z)
+    d_win[...] = g * sig * (1.0 + u * (1.0 - sig))          # through SiLU: du
+    dy = d_win[pl.ds(K - 1, N), :] * w[0: 1]
+    for j in range(1, K):
+        dy = dy + d_win[pl.ds(K - 1 - j, N), :] * w[j: j + 1]
+    dy_ref[0] = dy.astype(dy_ref.dtype)
+    du = d_win[pl.ds(0, N), :]
+    for j in range(K):
+        dw_ref[0, 0, j: j + 1, :] = jnp.sum(
+            win[pl.ds(halo - K + 1 + j, N), :] * du, axis=0, keepdims=True)
+
+
+def _branch_kernel_grid(shape, K: int, N: int):
+    """What both kernels share: (the grid (batch, heads, blocks of ``N``
+    tokens), every step on its own; the tile of a [B, T, H x d] array, a
+    head's block; the ``KDA_BRANCH_HALO`` rows before it and after it (at a
+    sequence's ends its own, and ignored); the taps of a head [K, d])."""
+    B, T, E = shape
+    halo, d = KDA_BRANCH_HALO, KDA_KERNEL_WIDTH
+    per, last = N // halo, T // halo - 1
+    return ((B, E // d, T // N),
+            pl.BlockSpec((1, N, d), lambda b, h, c: (b, c, h)),
+            pl.BlockSpec((1, halo, d), lambda b, h, c: (b, jnp.maximum(c * per - 1, 0), h)),
+            pl.BlockSpec((1, halo, d), lambda b, h, c: (b, jnp.minimum((c + 1) * per, last), h)),
+            pl.BlockSpec((K, d), lambda b, h, c: (0, h)))
+
+
+_BRANCH_KERNEL_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"))
+
+
+# Both kernels are jitted: a step calls them 36 times at three settings, each
+# traced and lowered once (``kda_branch`` below).
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _branch_kernel_forward(y, w, normed: bool, scale: float, N: int, interpret: bool = False):
+    """``_branch_tail`` as one Pallas kernel (``kda_branch_fwd``) in blocks of
+    ``N`` tokens: ``y`` read a head's block at a time straight from
+    [B, T, H x d], the arithmetic in float32 in VMEM, nothing but the result
+    [B, T, H x d] written, in ``y``'s dtype."""
+    B, T, E = y.shape
+    grid, block, before, _after, taps = _branch_kernel_grid(y.shape, w.shape[0], N)
+    return pl.pallas_call(
+        functools.partial(_branch_forward_kernel, normed=normed, scale=scale), grid=grid,
+        in_specs=[block, before, taps], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((B, T, E), y.dtype),
+        scratch_shapes=[pltpu.VMEM((N + KDA_BRANCH_HALO, KDA_KERNEL_WIDTH), F32)],
+        compiler_params=_BRANCH_KERNEL_PARAMS, name="kda_branch_fwd", interpret=interpret,
+    )(y, y, w)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _branch_kernel_backward(y, w, ct, normed: bool, scale: float, N: int,
+                            interpret: bool = False):
+    """The tail's gradients under ``ct`` [B, T, H x d] as one Pallas kernel
+    (``kda_branch_bwd``): the projection's in its own dtype, the taps' as a
+    float32 sum a block ([B, blocks, K, H x d]) that XLA adds up."""
+    B, T, E = y.shape
+    K = w.shape[0]
+    grid, block, before, after, taps = _branch_kernel_grid(y.shape, K, N)
+    halo, d = KDA_BRANCH_HALO, KDA_KERNEL_WIDTH
+    dy, dw = pl.pallas_call(
+        functools.partial(_branch_backward_kernel, normed=normed, scale=scale), grid=grid,
+        in_specs=[block, before, after, block, after, taps],
+        out_specs=[block, pl.BlockSpec((1, 1, K, d), lambda b, h, c: (b, c, 0, h))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, E), y.dtype),
+                   jax.ShapeDtypeStruct((B, T // N, K, E), F32)],
+        scratch_shapes=[pltpu.VMEM((N + 2 * halo, d), F32), pltpu.VMEM((N + halo, d), F32)],
+        compiler_params=_BRANCH_KERNEL_PARAMS, name="kda_branch_bwd", interpret=interpret,
+    )(y, y, y, ct, ct, w)
+    return dy, dw.sum((0, 1)).astype(w.dtype)
+
+
+def _branch_forward(y, w, normed: bool, scale: float, interpret: bool):
+    """(the tail [B, T, H, d], 1.0 where the kernel computed it): the kernel
+    where the program is lowered for a TPU, ``_branch_tail`` elsewhere."""
+    B, T, E = y.shape
+    heads = E // KDA_KERNEL_WIDTH
+    fused = lambda y, w: (_branch_kernel_forward(y, w, normed, scale, KDA_BRANCH_BLOCK, interpret)
+                          .reshape(B, T, heads, KDA_KERNEL_WIDTH).astype(BF16), jnp.float32(1.0))
+    if interpret:
+        return fused(y, w)
+    plain = lambda y, w: (_branch_tail(y, w, heads, normed, scale), jnp.float32(0.0))
+    return jax.lax.platform_dependent(y, w, tpu=fused, default=plain)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _branch_fused(y, w, normed: bool, scale: float, interpret: bool = False):
+    """The tail at the kernels' shapes: (the tail, 1.0 where the kernel ran).
+    Differentiated, it keeps ``y`` and the taps and nothing else; the
+    backward pass is the second kernel, or ``jax.vjp`` of ``_branch_tail``."""
+    return _branch_forward(y, w, normed, scale, interpret)
+
+
+def _branch_fused_fwd(y, w, normed, scale, interpret):
+    return _branch_forward(y, w, normed, scale, interpret), (y, w)
+
+
+def _branch_fused_bwd(normed, scale, interpret, saved, cts):
+    fused = lambda y, w, ct: _branch_kernel_backward(y, w, ct.reshape(y.shape), normed, scale,
+                                                     KDA_BRANCH_BLOCK, interpret)
+    if interpret:
+        return fused(*saved, cts[0])
+
+    def plain(y, w, ct):                                   # ct [B, T, H, d]
+        tail = lambda y, w: _branch_tail(y, w, ct.shape[2], normed, scale)
+        return jax.vjp(tail, y, w)[1](ct)
+
+    return jax.lax.platform_dependent(*saved, cts[0], tpu=fused, default=plain)
+
+
+_branch_fused.defvjp(_branch_fused_fwd, _branch_fused_bwd)
+
+
+# Jitted, as the kernels are: a step's twelve branches trace and lower three
+# settings once each, where each call traced both paths and both rules anew
+# (+7 s of the cell's ``setup_s`` on the chip's host: PERF.md, PR 36).
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def kda_branch(y, w, heads: int, normed: bool, scale: float):
+    """A KDA branch's tail: ``causal_conv`` of the projection ``y``
+    [B, T, heads x d] with the taps ``w`` [K, heads x d] in float32, SiLU,
+    and with ``normed`` each head's l2norm times ``scale``. Returns (the
+    tail [B, T, heads, d] in bfloat16, 1.0 where the branch kernels computed
+    it and 0.0 where ``_branch_tail`` did). The kernels run where the
+    program is lowered for a TPU, at the shapes they are written for (heads
+    ``KDA_KERNEL_WIDTH`` wide, a length ``KDA_BRANCH_BLOCK`` divides, at most
+    ``KDA_BRANCH_HALO`` + 1 taps); decided as ``kda_chunked`` decides. The
+    forward kernel reads ``y`` once and writes the tail once, where the
+    ``jax.numpy`` code makes a float32 copy of ``y``, four shifted ones and
+    as many more in its gradient (PERF.md, PR 36)."""
+    _B, T, E = y.shape
+    if (E == heads * KDA_KERNEL_WIDTH and T % KDA_BRANCH_BLOCK == 0
+            and w.shape[0] <= KDA_BRANCH_HALO + 1):
+        return _branch_fused(y, w, normed, scale)
+    return _branch_tail(y, w, heads, normed, scale), jnp.float32(0.0)
+
+
 # -- the module ----------------------------------------------------------------
 
 def _dense_init(scale: float = 0.02):
@@ -787,7 +997,9 @@ class _Kda(nn.Module):
     """The KDA mixer. Each wide branch (q, k, v, the decay, the gate, the
     output) is a function of its own that is recomputed in the backward
     pass, so that what lives through the layer's backward is the core's
-    five inputs and not every [tokens, heads x d] intermediate."""
+    five inputs and not every [tokens, heads x d] intermediate. Returns
+    (the mixed [B, T, D], [1.0 where the chunk kernels ran, the branches
+    of q, k, v whose tail a kernel computed])."""
 
     heads: int
     head_dim: int
@@ -804,9 +1016,7 @@ class _Kda(nn.Module):
 
         @functools.partial(jax.checkpoint, static_argnums=(3, 4))
         def branch(x, w, conv_w, normed: bool, scale: float):
-            y = causal_conv(_mm(x, w, "btd,de->bte", BF16).astype(F32), conv_w)
-            y = jax.nn.silu(y).reshape(B, T, H, d)
-            return ((l2norm(y) * scale) if normed else y).astype(BF16)
+            return kda_branch(_mm(x, w, "btd,de->bte", BF16), conv_w, H, normed, scale)
 
         @jax.checkpoint
         def decay(x, w1, w2, a_log, dt_bias):
@@ -822,10 +1032,11 @@ class _Kda(nn.Module):
 
         with jax.named_scope(SCOPE_KDA):
             conv_init = nn.initializers.normal(1.0 / np.sqrt(self.conv))
-            q, k, v = (branch(x, p(f"w_{n}", (D, H * d)),
-                              p(f"conv_{n}", (self.conv, H * d), conv_init), normed, scale)
-                       for n, normed, scale in (("q", True, d ** -0.5), ("k", True, 1.0),
-                                                ("v", False, 1.0)))
+            (q, fq), (k, fk), (v, fv) = (
+                branch(x, p(f"w_{n}", (D, H * d)),
+                       p(f"conv_{n}", (self.conv, H * d), conv_init), normed, scale)
+                for n, normed, scale in (("q", True, d ** -0.5), ("k", True, 1.0),
+                                         ("v", False, 1.0)))
             w_f1, w_f2 = p("w_f1", (D, d)), p("w_f2", (d, H * d))
             # A in [1, 16], dt in [1e-3, 1e-1] (the Mamba-2 ranges FLA uses)
             a_log = p("A_log", (H,), lambda key, s: jnp.log(
@@ -839,8 +1050,9 @@ class _Kda(nn.Module):
             beta = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x.astype(F32),
                                              p("w_beta", (D, H))))
             o, fused = kda_chunked(q, k, v, a, beta, self.chunk)
-            return output(o.astype(BF16), x, p("w_g1", (D, d)), p("w_g2", (d, H * d)),
-                          p("o_norm", (d,), nn.initializers.ones), p("w_o", (H * d, D))), fused
+            return (output(o.astype(BF16), x, p("w_g1", (D, d)), p("w_g2", (d, H * d)),
+                           p("o_norm", (d,), nn.initializers.ones), p("w_o", (H * d, D))),
+                    jnp.stack([fused, fq + fk + fv]))
 
 
 class _Mla(nn.Module):
@@ -946,8 +1158,8 @@ class _KimiLinear(nn.Module):
     """x [B, T] token ids -> the next token's logits after the last one
     given [B, V]; with ``hidden``, (hidden states after the final norm
     [B, T, D] in bfloat16, the untied head [D, V], rows each held expert
-    took in each layer [layers, E], the layers whose mixer a fused kernel
-    computed [2]: MLA, KDA)."""
+    took in each layer [layers, E], what fused kernels computed [3]: MLA
+    layers, KDA layers, KDA branches)."""
 
     cfg: Any
     vocab: int
@@ -965,7 +1177,7 @@ class _KimiLinear(nn.Module):
         embed = self.param("embed", _dense_init(), (self.vocab, D))
         head = self.param("head", _dense_init(), (D, self.vocab))
         h = jnp.take(embed, x, axis=0).astype(BF16)
-        loads, fused = [], {"mla": jnp.float32(0.0), "kda": jnp.float32(0.0)}
+        loads, fused = [], {"mla": jnp.float32(0.0), "kda": jnp.zeros((2,), F32)}
         layer = nn.remat(_Layer) if train else _Layer
         for i, (mixer, sparse) in enumerate(self.layer_kinds()):
             h, load, kernel = layer(self.cfg, mixer, sparse, name=f"layer_{i + 1}")(h)
@@ -974,7 +1186,7 @@ class _KimiLinear(nn.Module):
         h = rms_norm(h, self.param("norm_out", nn.initializers.ones, (D,)),
                      c["rms_norm_eps"]).astype(BF16)
         if hidden:
-            return h, head, jnp.stack(loads), jnp.stack([fused["mla"], fused["kda"]])
+            return h, head, jnp.stack(loads), jnp.concatenate([fused["mla"][None], fused["kda"]])
         # Serving: the next token's distribution after the last one given.
         return _mm(h[:, -1], head, "bd,dv->bv")
 
@@ -1163,7 +1375,8 @@ class KimiLinear(SparseExpertLm):
         return {"count.mla.fused": fused[0],
                 "count.mla.layers": jnp.float32(mixers.count("mla")),
                 "count.kda.fused": fused[1],
-                "count.kda.layers": jnp.float32(mixers.count("kda"))}
+                "count.kda.layers": jnp.float32(mixers.count("kda")),
+                "count.kda.branch_fused": fused[2]}
 
 
 if __name__ == "__main__":

@@ -287,6 +287,15 @@ def _chunk_kernels(text):
     return sorted(name.split(".")[0] for name, _op in kernels)
 
 
+def _branch_kernels(text):
+    """The branch kernels' calls by kind (``kda_branch``: a q, k or v
+    branch's convolution, SiLU and l2norm), each under the ``kda`` scope."""
+    kernels = _kernels(text, "kda_branch_")
+    assert all("/kda/" in op for _name, op in kernels), kernels
+    assert all("transpose(jvp(" in op for name, op in kernels if "bwd" in name), kernels
+    return sorted(name.split(".")[0] for name, _op in kernels)
+
+
 def _scan_leftovers(text):
     """Lines of the ``jax.numpy`` chunk rule in a compiled text: the
     triangular inverse's ``jit(diagonal)`` (gathers, scatter-adds and the
@@ -327,12 +336,16 @@ def test_the_latent_attention_layer_is_three_kernel_calls_on_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.47e9
 
 
-def test_the_delta_rule_layer_is_two_kernel_calls_on_v5e(one_chip):
+def test_the_delta_rule_layer_is_its_chunk_and_branch_kernels_on_v5e(one_chip):
     """``_Kda``'s value and gradients at the cell's shapes (2 x 8,192
-    tokens, 32 heads of 128, chunk 64): lowered for the described chip,
-    ``kda_chunked`` takes the fused chunk kernels, one call forward and one
-    backward, each under the ``kda`` scope (``kda_device_share.lm`` reads
-    that), and nothing of the scan's triangular inverse is left."""
+    tokens, 32 heads of 128, chunk 64, 4 taps): lowered for the described
+    chip, ``kda_chunked`` takes the fused chunk kernels, one call forward and
+    one backward, and each of the q, k, v branches' tails the branch kernels,
+    one call forward and one backward (the branch's ``jax.checkpoint``
+    recomputes its projection alone: the backward kernel reads nothing else),
+    each under the ``kda`` scope (``kda_device_share.lm`` reads that);
+    nothing of the scan's triangular inverse is left, and no SiLU of a
+    branch runs in XLA's code."""
     from rafiki_tpu.models import kimi_linear as K
 
     model, _vocab, T, B = _kimi_linear_cell()
@@ -350,10 +363,12 @@ def test_the_delta_rule_layer_is_two_kernel_calls_on_v5e(one_chip):
         _on(one_chip, params), _on(one_chip, x)).compile()
     text = compiled.as_text()
     assert _chunk_kernels(text) == ["kda_chunk_bwd", "kda_chunk_fwd"]
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert not _scan_leftovers(text)
-    # the scan's layer, compiled the same way at the parent commit: 3.40 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.40e9
+    assert _branch_kernels(text) == ["kda_branch_bwd"] * 3 + ["kda_branch_fwd"] * 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    assert not _scan_leftovers(text) and "jit(silu)" not in text
+    # the scan's layer, compiled the same way at PR 29: 3.40 GB; PR 30's kernels:
+    # 3.39 GB; with the branch kernels (PR 36): 2.50 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.75e9
 
 
 def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel(one_chip):
@@ -365,7 +380,10 @@ def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel
     the attention; the evaluation takes the kernel that saves nothing. Of the
     chunk rule: in each of the four KDA layers the forward kernel twice and
     the backward kernel once, the forward kernel once in the evaluation, and
-    no line of the scan's triangular inverse."""
+    no line of the scan's triangular inverse. Of the branches' tails: in each
+    KDA layer the forward branch kernel twice and the backward once for each
+    of q, k and v, the forward once in the evaluation (``count.kda.branch_fused``
+    reads 12 a step: four layers of three branches)."""
     from rafiki_tpu.ops.train import Program, _ShardingPlan
 
     model, vocab, T, B = _kimi_linear_cell()
@@ -384,6 +402,7 @@ def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel
     assert all("/mla/" in op for _name, op in kernels), kernels
     assert SCORES not in text and "[2,32,256," not in text
     assert _chunk_kernels(text) == ["kda_chunk_bwd"] * 4 + ["kda_chunk_fwd"] * 8
+    assert _branch_kernels(text) == ["kda_branch_bwd"] * 12 + ["kda_branch_fwd"] * 24
     assert not _scan_leftovers(text)
     # the step with the scan, compiled the same way: 4.94 GB of temporaries
     assert step.memory_analysis().temp_size_in_bytes < 4.94e9
@@ -392,6 +411,7 @@ def test_the_language_models_step_and_evaluation_compile_for_v5e_with_the_kernel
     assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
         "splash_mha_fwd_no_residuals"]
     assert _chunk_kernels(evaluate.as_text()) == ["kda_chunk_fwd"] * 4
+    assert _branch_kernels(evaluate.as_text()) == ["kda_branch_fwd"] * 12
     assert not _scan_leftovers(evaluate.as_text())
     assert _peak_bytes(evaluate) < HBM_BYTES
 
