@@ -23,6 +23,11 @@ examples/models/ (SURVEY.md §2 "Example models", unverified paths):
                  with shared weights, a loss at every pass weighted by a
                  learned exit gate; it shares the attention, the blocked
                  loss and the language-model base with the other two
+  KeyeVl2      — no reference analog: the zoo's fourth language model,
+                 grouped-query attention over the keys a learned indexer
+                 selects for each query (three Pallas kernels), a softmax
+                 router over sparse experts; it shares the router, the
+                 expert layer and the base with the first two
 """
 
 from rafiki_tpu.models.ff import FeedForward
@@ -47,6 +52,7 @@ MODEL_REGISTRY = {
     "KimiLinear": ("rafiki_tpu.models.kimi_linear", "KimiLinear"),
     "Lfm2Moe": ("rafiki_tpu.models.lfm2_moe", "Lfm2Moe"),
     "Ouro": ("rafiki_tpu.models.ouro", "Ouro"),
+    "KeyeVl2": ("rafiki_tpu.models.keye_vl2", "KeyeVl2"),
 }
 
 

@@ -643,14 +643,14 @@ def mla_attention(q, k, v):
 
 # -- sparse experts ------------------------------------------------------------
 
-def route(x, w_router, bias, top_k: int, scaling: float, eps: float = 0.0):
-    """Sigmoid scores over every expert; the top ``top_k`` of score +
-    bias are selected, and weighted by their scores alone, renormalised
-    over the selected set (``eps`` added to their sum, where a template's
-    published router has one), times ``scaling``. Returns (ids [N, k],
-    weights [N, k]) in float32. The language-model templates' one router
-    (``lfm2_moe.py`` calls it with a bias that is not zero)."""
-    s = jax.nn.sigmoid(jnp.matmul(x.astype(F32), w_router.astype(F32),
+def route(x, w_router, bias, top_k: int, scaling: float, eps=0.0, score_function=jax.nn.sigmoid):
+    """Scores over every expert (``score_function``: sigmoid, or a softmax over
+    all of them, ``jax.nn.softmax``); the top ``top_k`` of score + bias selected,
+    and weighted by their scores alone, renormalised over the selected set
+    (``eps`` added to their sum, where a published router has one), times
+    ``scaling``. Returns (ids [N, k], weights [N, k]) in float32. The
+    language-model templates' one router (``lfm2_moe.py``: a bias not zero)."""
+    s = score_function(jnp.matmul(x.astype(F32), w_router.astype(F32),
                                   precision=jax.lax.Precision.HIGHEST))
     _vals, ids = jax.lax.top_k(s + bias, top_k)
     picked = jnp.take_along_axis(s, ids, axis=-1)

@@ -505,3 +505,41 @@ def test_the_looped_language_models_step_and_evaluation_compile_for_v5e_with_the
     assert [name.split(".")[0] for name, _op in _attention_kernels(evaluate.as_text())] == [
         "splash_mha_fwd_no_residuals"] * 6
     assert _peak_bytes(evaluate) < HBM_BYTES
+
+
+# -- the fourth language model ----------------------------------------------------
+
+def test_the_sparse_attention_kernels_compile_for_v5e_at_the_cells_widths(one_chip):
+    """The sparse attention's value and gradients at the Keye cell's shapes
+    (32,768 tokens, 32 query heads on 4 key/value heads of 128, an indexer of
+    16 heads of 64) lowered for the described chip: its three kernels, each
+    under the ``dsa.attn`` scope that ``dsa_device_share.lm`` reads, and no
+    [T, T] float32 array; the selection of every query fits too (its int8
+    mask of 1 GiB is the largest array)."""
+    from rafiki_tpu.models import keye_vl2 as M
+
+    B, T, H, Hk, d, HI, dI = 1, 32768, 32, 4, 128, 16, 64
+    bf = lambda *s: _spec(s, jnp.bfloat16, one_chip)
+    q, k, v, qI, kI = bf(B, T, H, d), bf(B, T, Hk, d), bf(B, T, Hk, d), bf(B, T, HI, dI), bf(B, T, dI)
+    w = _spec((B, T, HI), jnp.float32, one_chip)
+    mask = _spec((B, T, T), jnp.int8, one_chip)
+    counts = _spec((B, T // M.DSA_QUERY_TILE, T // M.DSA_KEY_TILE), jnp.int32, one_chip)
+
+    def value_and_grads(q, k, v, mask, counts, qI, kI, w):
+        def f(q, k, v, qI, kI, w):
+            with jax.named_scope(M.SCOPE_ATTN):
+                o, kl, fused = M.dsa_attention(q, k, v, mask, counts, qI, kI, w)
+            return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl), fused
+        return jax.grad(f, argnums=tuple(range(6)), has_aux=True)(q, k, v, qI, kI, w)
+
+    compiled = jax.jit(value_and_grads).lower(q, k, v, mask, counts, qI, kI, w).compile()
+    text = compiled.as_text()
+    kernels = _kernels(text, "dsa_")
+    assert sorted(name.split(".")[0] for name, _op in kernels) == ["dsa_dkv", "dsa_dq", "dsa_fwd"]
+    assert all("dsa.attn" in op for _name, op in kernels), kernels
+    assert f"f32[1,{T},{T}]" not in text and f"f32[{T},{T}]" not in text
+    assert _peak_bytes(compiled) < HBM_BYTES
+    select = jax.jit(lambda qI, kI, w: M.dsa_select(qI, kI, w, 2048)).lower(qI, kI, w).compile()
+    # the mask out, a block of 128 queries' scores and their search inside
+    assert select.memory_analysis().temp_size_in_bytes < 0.2e9
+    assert _peak_bytes(select) < 1.3e9
