@@ -39,8 +39,10 @@ What the template adds to the zoo, by mechanism:
   has them; the selection kept as an int8 mask [B, T, T] and the selected
   keys counted a tile of the kernels at a time.
 * ``dsa_attention``: softmax attention over the mask, the indexer's scores
-  again and the terms of its loss, in one pass of ``custom_vjp``. Lowered
-  for a TPU, at heads of 128 and 64 and a length ``DSA_KEY_TILE`` divides:
+  again and the terms of its loss, forward in one pass, then a
+  ``custom_vjp`` that takes the forward's output and log-sum-exps for its
+  backward rule. Lowered for a TPU, at heads of 128 and 64 and a length
+  ``DSA_KEY_TILE`` divides:
   three Pallas kernels of this repo (``dsa_fwd``: per query tile, a first
   sweep of the key tiles for every head's log-sum-exp and the indexer's,
   a second for the output, the head-summed probabilities p and the loss's
@@ -56,7 +58,10 @@ What the template adds to the zoo, by mechanism:
 TPU notes as ``kimi_linear.py``'s: bfloat16 operands and float32
 accumulation in matrix products; parameters, norms, rotations, the router,
 the indexer's scores and every softmax in float32; every layer recomputed
-in the backward pass (``nn.remat``), the selection with it. Which path runs
+in the backward pass (``nn.remat``), the selection with it, but for the
+sparse attention's output and log-sum-exps, which the layer keeps
+(``KEEP_FORWARD``; ``count.dsa.forward_kept`` layers a step), so that a
+step runs ``dsa_fwd`` once a layer. Which path runs
 is decided when a program is lowered, from the platform it is lowered for;
 no environment variable or knob enters. A trial at the published widths
 fills a chip by itself; what it shares of the ``JaxModel`` contract with the
@@ -72,6 +77,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,6 +97,10 @@ ROPE_SPLIT = 256       # a position t is ROPE_SPLIT * a + b in the rotary tables
 # ``moe.route``, ``moe.experts`` and ``lm.loss``, which this template shares.
 SCOPE_INDEX, SCOPE_SELECT = "dsa.index", "dsa.select"
 SCOPE_ATTN, SCOPE_KL = "dsa.attn", "dsa.kl"
+# What a layer's rematerialisation keeps of the sparse attention's forward:
+# the output and the log-sum-exps the backward kernels read (``_dsa_fused``).
+DSA_KEPT = ("dsa.o", "dsa.lse", "dsa.lse_i")
+KEEP_FORWARD = jax.checkpoint_policies.save_only_these_names(*DSA_KEPT)
 
 
 def rope(x, theta: float):
@@ -562,57 +572,79 @@ def _dsa_kernel_backward(q, k, v, mask, table, qI, kI, w, o, lse, lse_i, do, dkl
 
 
 def _dsa_forward(q, k, v, mask, table, qI, kI, w, interpret: bool):
-    """(o, kl, lse, lse_i as the kernels keep them, 1.0 where the kernel
-    ran): the kernel where the program is lowered for a TPU, the blocked
-    path elsewhere."""
-    fused = lambda *xs: _dsa_kernel_forward(*xs, interpret=interpret) + (jnp.float32(1.0),)
+    """(o, kl, lse [B, H, T], lse_i [B, T], 1.0 where the kernel ran): the
+    kernel where the program is lowered for a TPU, the blocked path
+    elsewhere. The kernel writes a log-sum-exp in every lane; lane 0 is
+    kept."""
+    def fused(*xs):
+        o, kl, lse, lse_i = _dsa_kernel_forward(*xs, interpret=interpret)
+        return o, kl, lse[..., 0], lse_i[..., 0], jnp.float32(1.0)
+
     if interpret:
         return fused(q, k, v, mask, table, qI, kI, w)
 
     def blocked(q, k, v, mask, _table, qI, kI, w):
-        o, kl, lse, lse_i = _dsa_blocked(q, k, v, mask, qI, kI, w)
-        return o, kl, _lanes(lse), _lanes(lse_i), jnp.float32(0.0)
+        return _dsa_blocked(q, k, v, mask, qI, kI, w) + (jnp.float32(0.0),)
 
     return jax.lax.platform_dependent(q, k, v, mask, table, qI, kI, w,
                                       tpu=fused, default=blocked)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _dsa_fused(q, k, v, mask, table, qI, kI, w, interpret=False):
-    """(o, kl, 1.0 where the kernel ran) at the kernels' widths.
-    Differentiated, the forward pass keeps its inputs, o and the two
-    log-sum-exps; the backward pass is the two backward kernels, or
-    ``jax.vjp`` of the blocked path."""
-    o, kl, _lse, _lse_i, fused = _dsa_forward(q, k, v, mask, table, qI, kI, w, interpret)
-    return o, kl, fused
+@functools.partial(jax.custom_vjp, nondiff_argnums=(12,))
+def _dsa_attend(q, k, v, mask, table, qI, kI, w, o, kl, lse, lse_i, interpret=False):
+    """(o, kl) that ``_dsa_forward`` computed from these inputs, with their
+    gradients: the two backward kernels from o and the log-sum-exps, or
+    ``jax.vjp`` of the blocked path. No gradient reaches the forward's
+    outputs themselves."""
+    return o, kl
 
 
-def _dsa_fused_fwd(q, k, v, mask, table, qI, kI, w, interpret):
-    o, kl, lse, lse_i, fused = _dsa_forward(q, k, v, mask, table, qI, kI, w, interpret)
-    return (o, kl, fused), (q, k, v, mask, table, qI, kI, w, o, lse, lse_i)
+def _dsa_attend_fwd(q, k, v, mask, table, qI, kI, w, o, kl, lse, lse_i, interpret):
+    return (o, kl), (q, k, v, mask, table, qI, kI, w, o, lse, lse_i)
 
 
-def _dsa_fused_bwd(interpret, saved, cts):
-    do, dkl, _ = cts
+def _dsa_attend_bwd(interpret, saved, cts):
+    do, dkl = cts
     q, k, v, mask, table, qI, kI, w, o, lse, lse_i = saved
-    fused = lambda *xs: _dsa_kernel_backward(*xs, interpret=interpret)
-    args = (q, k, v, mask, table, qI, kI, w, o, lse, lse_i[..., 0], do, dkl)
+
+    def fused(q, k, v, mask, table, qI, kI, w, o, lse, lse_i, do, dkl):
+        return _dsa_kernel_backward(q, k, v, mask, table, qI, kI, w, o, _lanes(lse),
+                                    _lanes(lse_i), do, dkl, interpret=interpret)
 
     def blocked(q, k, v, mask, _table, qI, kI, w, _o, _lse, _lse_i, do, dkl):
         run = lambda q, k, v, qI, kI, w: _dsa_blocked(q, k, v, mask, qI, kI, w)[:2]
-        dq, dk, dv, dqI, dkI, dw = jax.vjp(run, q, k, v, qI, kI, w)[1]((do, dkl))
-        return dq, dk, dv, dqI, dkI, dw
+        return jax.vjp(run, q, k, v, qI, kI, w)[1]((do, dkl))
 
+    args = (q, k, v, mask, table, qI, kI, w, o, lse, lse_i, do, dkl)
     if interpret:
-        grads = fused(*args[:10], lse_i, do, dkl)
+        grads = fused(*args)
     else:
-        grads = jax.lax.platform_dependent(
-            *args[:10], lse_i, do, dkl, tpu=fused, default=blocked)
+        grads = jax.lax.platform_dependent(*args, tpu=fused, default=blocked)
     dq, dk, dv, dqI, dkI, dw = grads
-    return dq, dk, dv, None, None, dqI, dkI, dw.astype(w.dtype)
+    return (dq, dk, dv, None, None, dqI, dkI, dw.astype(w.dtype)) + (None,) * 4
 
 
-_dsa_fused.defvjp(_dsa_fused_fwd, _dsa_fused_bwd)
+_dsa_attend.defvjp(_dsa_attend_fwd, _dsa_attend_bwd)
+
+
+def _dsa_fused(q, k, v, mask, table, qI, kI, w, interpret=False):
+    """(o, kl, 1.0 where the kernel ran) at the kernels' widths. The forward
+    runs by itself on inputs that carry no gradient, and its o and
+    log-sum-exps are named (``DSA_KEPT``) before ``_dsa_attend`` takes them
+    for the backward pass: a layer rematerialised under ``KEEP_FORWARD``
+    keeps them and does not run the forward again."""
+    o, kl, lse, lse_i, fused = _dsa_forward(
+        *(jax.lax.stop_gradient(x) for x in (q, k, v, mask, table, qI, kI, w)), interpret)
+    o, lse, lse_i = (checkpoint_name(x, name) for x, name in zip((o, lse, lse_i), DSA_KEPT))
+    o, kl = _dsa_attend(q, k, v, mask, table, qI, kI, w, o, kl, lse, lse_i, interpret)
+    return o, kl, fused
+
+
+def kernel_path(T: int, d: int, dI: int) -> bool:
+    """Whether ``dsa_attention`` takes ``_dsa_fused`` at a length ``T``,
+    heads ``d`` wide and indexer heads ``dI`` wide (the kernels where the
+    program is lowered for a TPU)."""
+    return T % DSA_KEY_TILE == 0 and d == DSA_HEAD_DIM and dI == DSA_INDEX_DIM
 
 
 @jax.jit
@@ -636,7 +668,7 @@ def dsa_attention(q, k, v, mask, counts, qI, kI, w):
     args = (heads_major(q), heads_major(k.astype(BF16)), heads_major(v.astype(BF16)), mask,
             (counts > 0).astype(jnp.int32), heads_major(qI.astype(BF16)), kI.astype(BF16),
             w.astype(F32))
-    if T % DSA_KEY_TILE == 0 and d == DSA_HEAD_DIM and dI == DSA_INDEX_DIM:
+    if kernel_path(T, d, dI):
         o, kl, fused = _dsa_fused(*args)
     else:
         o, kl, _lse, _lse_i = _dsa_blocked(*args[:4], *args[5:])
@@ -758,7 +790,10 @@ class _KeyeVl2(nn.Module):
     [B, T, D] in bfloat16, the untied head [D, V], rows each held expert
     took in each layer [layers, E], {"kl": the indexer's loss of every token
     summed over the layers [B, T], "fused": layers the kernels computed,
-    "stats": selected keys, tiles visited, tiles, summed over the layers})."""
+    "stats": selected keys, tiles visited, tiles, summed over the layers,
+    "kept": layers whose attention output and log-sum-exps the backward pass
+    keeps, ``KEEP_FORWARD``: all of them in training at the kernels' widths
+    and length, fixed when the program is traced})."""
 
     cfg: Any
     vocab: int
@@ -774,7 +809,8 @@ class _KeyeVl2(nn.Module):
         head = self.param("head", K._dense_init(), (D, self.vocab))
         h = jnp.take(embed, x, axis=0).astype(F32)
         loads, kl, fused, stats = [], 0.0, jnp.float32(0.0), jnp.zeros((3,), F32)
-        layer = nn.remat(_Layer) if train else _Layer
+        layer = nn.remat(_Layer, policy=KEEP_FORWARD) if train else _Layer
+        kept = train and kernel_path(x.shape[1], c["head_dim"], c["indexer_head_dim"])
         for i in range(c["num_hidden_layers"]):
             h, load, kl_i, fused_i, stats_i = layer(self.cfg, name=f"layer_{i + 1}")(h)
             loads.append(load)
@@ -782,7 +818,9 @@ class _KeyeVl2(nn.Module):
         h = K.rms_norm(h, self.param("norm_out", nn.initializers.ones, (D,)),
                        c["rms_norm_eps"]).astype(BF16)
         if hidden:
-            return h, head, jnp.stack(loads), {"kl": kl, "fused": fused, "stats": stats}
+            return h, head, jnp.stack(loads), {
+                "kl": kl, "fused": fused, "stats": stats,
+                "kept": jnp.float32(c["num_hidden_layers"] if kept else 0)}
         # Serving: the next token's distribution after the last one given.
         return K._mm(h[:, -1], head, "bd,dv->bv")
 
@@ -830,6 +868,7 @@ class KeyeVl2(K.SparseExpertLm):
         stats = fused["stats"]
         return {"count.dsa.layers": jnp.float32(len(mixers)),
                 "count.dsa.fused": fused["fused"],
+                "count.dsa.forward_kept": fused["kept"],
                 "count.dsa.keys_selected": stats[0],
                 "count.dsa.tiles_visited": stats[1],
                 "count.dsa.tiles_total": stats[2]}

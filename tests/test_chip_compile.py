@@ -543,3 +543,30 @@ def test_the_sparse_attention_kernels_compile_for_v5e_at_the_cells_widths(one_ch
     # the mask out, a block of 128 queries' scores and their search inside
     assert select.memory_analysis().temp_size_in_bytes < 0.2e9
     assert _peak_bytes(select) < 1.3e9
+
+
+def test_the_sparse_attention_forward_kernel_runs_once_a_layer_in_a_step_on_v5e(one_chip):
+    """A two-layer stack's value and gradients in training (``nn.remat`` a
+    layer, keeping the attention's output and log-sum-exps) at the kernels'
+    widths and a short length: ``dsa_fwd`` once a layer, not a second time
+    in the backward pass, and the two backward kernels once a layer, all
+    under ``dsa.attn``."""
+    from rafiki_tpu.models import keye_vl2 as M
+
+    cfg = dict(hidden_size=256, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+               rope_theta=1e7, indexer_num_heads=2, indexer_head_dim=64, topk=256,
+               moe_intermediate_size=64, num_experts=4, num_experts_per_tok=2,
+               num_hidden_layers=2, rms_norm_eps=1e-6, experts_held=(0, 1, 2, 3))
+    module = M._KeyeVl2(cfg=tuple(sorted(cfg.items())), vocab=512)
+    x = _spec((1, 1024), jnp.int32, one_chip)
+    params = _on(one_chip, jax.eval_shape(lambda x: module.init(jax.random.PRNGKey(0), x), x))
+
+    def loss(params, x):
+        h, _head, _loads, extra = module.apply(params, x, train=True, hidden=True)
+        return jnp.sum(h.astype(jnp.float32)) + jnp.sum(extra["kl"])
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, x).compile().as_text()
+    kernels = _kernels(text, "dsa_")
+    names = [name.split(".")[0] for name, _op in kernels]
+    assert {n: names.count(n) for n in set(names)} == {"dsa_fwd": 2, "dsa_dq": 2, "dsa_dkv": 2}
+    assert all("dsa.attn" in op for _name, op in kernels), kernels

@@ -5,9 +5,14 @@ and the indexer's log-sum-exp and the indexer's loss forward, and all six
 gradients backward, at tilings where a tile holds the whole sequence and
 where key tiles above the diagonal and tiles with no selected key are
 skipped; with every key selected, the same attention as dense causal
-softmax; and which path runs is read from the lowering. Shared fixtures:
+softmax; which path runs is read from the lowering; and a layer whose
+``nn.remat`` keeps the forward's output and log-sum-exps has the gradients
+of one that runs the forward again. Shared fixtures:
 tests/keye_vl2_common.py."""
 
+import functools
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +41,10 @@ def test_the_kernels_match_the_blocked_path_forward_and_backward(tiling, monkeyp
                                                   interpret=True)
     assert close(o2, o1, 1e-2) and close(kl2, kl1, 1e-5)
     assert close(lse2[..., 0], lse1, 1e-5) and close(lse_i2[..., 0], lse_i1, 1e-5)
+    # lane 0 is what the forward hands on, and widened again it is every lane
+    kept = M._dsa_forward(q, k, v, mask, table, qI, kI, w, True)
+    np.testing.assert_array_equal(M._lanes(kept[2]), lse2)
+    np.testing.assert_array_equal(M._lanes(kept[3]), lse_i2)
 
     def grads(interpret):
         def f(q, k, v, qI, kI, w):
@@ -84,3 +93,49 @@ def test_which_path_runs_is_read_from_the_lowering(T):
         assert "tpu_custom_call" in text and "dsa_fwd" in text
     _o, _kl, fused = M.dsa_attention(*call)
     assert float(fused) == 0.0
+
+
+@pytest.mark.parametrize("path", ["kernels", "blocked"])
+def test_keeping_the_forward_leaves_a_layers_gradients_as_they_were(path, monkeypatch,
+                                                                    interpreted):
+    """A layer at the kernels' widths, rematerialised keeping the attention's
+    output and log-sum-exps (``KEEP_FORWARD``), against a plain ``nn.remat``
+    that runs the forward again: the same value, indexer loss and gradients
+    by the input and every parameter, to float32 rounding, with the kernels
+    (Pallas' interpreter) and with the blocked code the CPU's lowering takes;
+    and the forward kernel staged once where it was staged twice."""
+    monkeypatch.setattr(M, "DSA_QUERY_TILE", 16)
+    monkeypatch.setattr(M, "DSA_KEY_TILE", 32)
+    monkeypatch.setattr(M, "SELECT_BLOCK", 16)
+    if path == "kernels":
+        monkeypatch.setattr(M, "_dsa_fused", functools.partial(M._dsa_fused, interpret=True))
+    jax.clear_caches()
+    cfg = dict(hidden_size=64, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+               rope_theta=1e7, indexer_num_heads=2, indexer_head_dim=64, topk=12,
+               moe_intermediate_size=32, num_experts=4, num_experts_per_tok=2,
+               num_hidden_layers=1, rms_norm_eps=1e-6, experts_held=(0, 1, 2, 3))
+    cfg = tuple(sorted(cfg.items()))
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    h = jax.random.normal(ks[0], (1, 64, 64))
+    ct, ct_kl = jax.random.normal(ks[1], h.shape), jax.random.normal(ks[2], (1, 64))
+    params = M._Layer(cfg).init(jax.random.PRNGKey(0), h)
+
+    def grads(policy):
+        layer = nn.remat(M._Layer, policy=policy)(cfg)
+
+        def f(params, h):
+            out, _load, kl, fused, _stats = layer.apply(params, h)
+            return jnp.sum(out * ct) + jnp.sum(kl * ct_kl), fused
+
+        vg = jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+        staged = str(vg.trace(params, h).jaxpr).count("name=dsa_fwd")
+        (value, fused), g = vg(params, h)
+        return staged, float(fused), value, jax.tree.leaves(g)
+
+    kept, plain = grads(M.KEEP_FORWARD), grads(None)
+    assert (kept[0], plain[0]) == (1, 2)
+    assert kept[1] == plain[1] == (1.0 if path == "kernels" else 0.0)
+    assert close(kept[2], plain[2], 1e-6)
+    for a, b in zip(kept[3], plain[3], strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert close(a, b, 1e-6)

@@ -43,6 +43,8 @@ def test_loss_counts_and_every_gradient_leaf(cfg, f32):
     T = int(cfg["seq_len"])
     assert float(metrics["count.dsa.keys_selected"]) == 2 * 2 * R._selected_keys(T, 16)
     assert (float(metrics["count.dsa.layers"]), float(metrics["count.dsa.fused"])) == (2.0, 0.0)
+    # heads of 16: the blocked code, differentiated as a whole; nothing named to keep
+    assert float(metrics["count.dsa.forward_kept"]) == 0.0
     assert float(metrics["count.dsa.tiles_visited"]) == float(metrics["count.dsa.tiles_total"]) == 4
     assert float(metrics["count.moe.slots_total"]) == x.size * 4 * 2
 
